@@ -1,13 +1,16 @@
 # Build/test/bench entry points (reference parity: Makefile).
 PY ?= python
 
-.PHONY: test test-fast bench bench-smoke mesh-smoke trace-smoke trace-net-smoke statesync-smoke chaos-smoke disk-smoke scale-smoke bls-smoke bls-ext load-smoke lite-smoke forensics-smoke finality-smoke rotation-smoke localnet lint fmt csrc clean abci-cli signer-harness
+.PHONY: test test-fast chip-smoke bench bench-smoke mesh-smoke trace-smoke trace-net-smoke statesync-smoke chaos-smoke disk-smoke scale-smoke bls-smoke bls-ext load-smoke lite-smoke forensics-smoke finality-smoke rotation-smoke localnet lint fmt csrc clean abci-cli signer-harness
 
 test:            ## full suite (virtual 8-device CPU mesh)
 	$(PY) -m pytest tests/ -q
 
 test-fast:       ## the quick tiers only
 	$(PY) -m pytest tests/ -q -x --ignore=tests/test_tools.py
+
+chip-smoke:      ## the node's verify path end to end on the TPU this process holds (refuses a CPU); stdout: a {"report": ...} line, then last the bare {"ok", "device"} verdict
+	$(PY) chip_smoke.py
 
 bench:           ## BASELINE benchmarks on the attached chip -> one JSON line
 	$(PY) bench.py
@@ -77,7 +80,7 @@ localnet:        ## 4-validator net as OS processes (no docker)
 	$(PY) networks/local/run_localnet.py ./build
 
 lint:            ## syntax + import sanity over the package
-	$(PY) -m compileall -q tendermint_tpu tests bench.py __graft_entry__.py
+	$(PY) -m compileall -q tendermint_tpu tests bench.py chip_smoke.py __graft_entry__.py
 
 csrc:            ## force-rebuild the C host-prep extension
 	rm -f tendermint_tpu/csrc/*.so
@@ -90,5 +93,5 @@ signer-harness:  ## remote signer acceptance tests (listens on :31559)
 	$(PY) -m tendermint_tpu.tools.signer_harness
 
 clean:
-	rm -rf build .pytest_cache tendermint_tpu/csrc/*.so
+	rm -rf build .pytest_cache .jax_cache chiprun_out tendermint_tpu/csrc/*.so
 	find . -name __pycache__ -type d -exec rm -rf {} +

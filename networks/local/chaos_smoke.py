@@ -133,8 +133,6 @@ def main() -> int:
     ports = [args.base_port + 10 * i + 1 for i in range(4)]
 
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache_tendermint_tpu")
-    env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
     procs = [spawn(h, env) for h in homes]
 
     checker = InvariantChecker(4, liveness_exempt=[0])  # twin halts by design

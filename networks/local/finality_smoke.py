@@ -235,8 +235,6 @@ def main() -> int:
     build = os.path.abspath(args.build_dir)
 
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache_tendermint_tpu")
-    env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
 
     # one checker per arm: each arm is a fresh chain from genesis, so a
     # shared checker would see the height reset as a regression

@@ -203,8 +203,6 @@ def main() -> int:
         save_config(cfg, path)
 
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache_tendermint_tpu")
-    env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
     procs = [spawn(h, env) for h in homes]
 
     checker = InvariantChecker(4)

@@ -59,9 +59,6 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
 
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache_tendermint_tpu")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
-
 # node roles (indices into the rig's node list)
 GENESIS_VALS = [0, 1, 2, 3]
 JOINER_A = 5          # bonds in directly via the rig (latency measurement)

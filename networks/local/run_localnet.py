@@ -151,10 +151,6 @@ def main() -> int:
     rpc_ports = [rpc_port_of(home) for home in homes]
 
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    # all nodes compile identical XLA kernels — share one persistent cache
-    # so only the first process (ever) pays each compile
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache_tendermint_tpu")
-    env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
     procs = [
         subprocess.Popen(
             [sys.executable, "-m", "tendermint_tpu.cli", "--home", home, "node"],

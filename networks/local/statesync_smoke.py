@@ -98,8 +98,6 @@ def main() -> int:
         rpc_ports.append(int(cfg.rpc.laddr.rsplit(":", 1)[1]))
 
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache_tendermint_tpu")
-    env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
 
     procs = [spawn(home, env) for home in homes]
     joiner_proc = None

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import hashlib
 import hmac as _hmac
+import logging
 import os
 import struct
 from typing import Optional, Tuple
@@ -86,6 +87,9 @@ def active_tier() -> int:
 # --------------------------------------------------------------------------
 
 
+MESH_PROBE_FAILED = "mesh probe failed"
+
+
 def resolve_mesh(
     mode: str = "auto", max_devices: int = 0, batch_axis: str = "batch"
 ) -> Tuple[object, int, str]:
@@ -107,9 +111,11 @@ def resolve_mesh(
       "off"  — never shard.
 
     `max_devices` (tpu.mesh_devices) caps the shard count; 0 = all visible.
-    Any probe failure degrades to single-device with the failure in the
-    reason string — a broken device plane must never stop the node (the
-    host path still verifies)."""
+    The reason names the jax backend and device kind the engine will run
+    on.  A probe failure degrades to single-device — a broken device plane
+    must never stop the node (the host path still verifies) — but not
+    quietly: it is logged at error level and the reason starts with
+    MESH_PROBE_FAILED."""
     if mode == "off":
         return None, 1, "mesh off (config)"
     try:
@@ -117,21 +123,24 @@ def resolve_mesh(
         import numpy as _np
         from jax.sharding import Mesh
 
+        from .. import ops  # noqa: F401 — places the compile cache before any compile
+
         devs = jax.devices()
-        backend = jax.default_backend()
+        on = f"{jax.default_backend()} {devs[0].device_kind}"
         cap = max_devices if max_devices > 0 else len(devs)
         cap = min(cap, len(devs))
         if cap <= 1:
-            return None, 1, f"single device ({len(devs)} visible, {backend})"
-        if mode == "auto" and backend == "cpu" and max_devices <= 1:
+            return None, 1, f"single device ({len(devs)} visible, {on})"
+        if mode == "auto" and jax.default_backend() == "cpu" and max_devices <= 1:
             return None, 1, (
                 f"{len(devs)} virtual cpu devices ignored by mesh=auto "
                 "(set mesh=on or mesh_devices to shard)"
             )
         mesh = Mesh(_np.array(devs[:cap]), (batch_axis,))
-        return mesh, cap, f"sharded over {cap}/{len(devs)} {backend} devices"
-    except Exception as e:  # probe failure: the host path must still serve
-        return None, 1, f"mesh probe failed: {e!r}"
+        return mesh, cap, f"sharded over {cap}/{len(devs)} devices ({on})"
+    except Exception as e:  # node start: the host path must still serve
+        logging.getLogger(__name__).exception("verify engine: device mesh probe failed")
+        return None, 1, f"{MESH_PROBE_FAILED}: {e!r}"
 
 
 # --------------------------------------------------------------------------

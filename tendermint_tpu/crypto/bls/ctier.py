@@ -1,13 +1,11 @@
 """C fast tier for the BLS12-381 pairing hot path.
 
-Loads csrc/bls12_381.c via ctypes with the exact discipline proven by
-`crypto/hostprep.py`: compiled on demand with the system toolchain,
-`.so` named by source hash + machine arch (a stale or cross-arch binary
-is a cache miss and gets rebuilt; like hostprep, -march=native codegen
-assumes the artifact stays on the host that built it — don't bake the
-csrc dir into images shipped across CPU generations), nothing committed
-to git, graceful fallback to the pure-Python reference tier when no
-compiler is present (one warning, once).
+Loads csrc/bls12_381.c via ctypes through `crypto/hostprep.py`'s
+build_native_lib: compiled on demand with the system toolchain, `.so`
+named by the building CPU's identity + source hash (a stale binary, or
+one built on another machine, is a cache miss and gets rebuilt), nothing
+committed to git, graceful fallback to the pure-Python reference tier
+when no compiler is present (one warning, once).
 
 The boundary representation is the affine "blob": big-endian field bytes,
 96 B for G1 (x‖y) and 192 B for G2 (x.c0‖x.c1‖y.c0‖y.c1), with the group
@@ -31,12 +29,8 @@ scheme-side hash_to_g2 memo.
 from __future__ import annotations
 
 import ctypes
-import hashlib
 import logging
 import os
-import platform
-import subprocess
-import tempfile
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -82,29 +76,11 @@ def _load_lib() -> Optional[ctypes.CDLL]:
             return _lib
         lib = None
         try:
-            src = os.path.join(_csrc_path(), "bls12_381.c")
-            with open(src, "rb") as f:
-                src_hash = hashlib.sha256(f.read()).hexdigest()[:16]
-            arch = platform.machine() or "unknown"
-            so = os.path.join(_csrc_path(), f"bls12_381-{arch}-{src_hash}.so")
-            if not os.path.exists(so):
-                fd, tmp = tempfile.mkstemp(suffix=".so", dir=_csrc_path())
-                os.close(fd)
-                try:
-                    base = ["cc", "-O3", "-shared", "-fPIC", "-o", tmp, src]
-                    try:
-                        subprocess.run(
-                            base[:2] + ["-march=native"] + base[2:],
-                            check=True, capture_output=True, timeout=120,
-                        )
-                    except Exception:
-                        subprocess.run(
-                            base, check=True, capture_output=True, timeout=120
-                        )
-                    os.replace(tmp, so)
-                finally:
-                    if os.path.exists(tmp):  # failed compile: no orphan temp
-                        os.unlink(tmp)
+            from .. import hostprep
+
+            so = hostprep.build_native_lib(
+                os.path.join(_csrc_path(), "bls12_381.c"), "bls12_381", timeout=120
+            )
             cdll = ctypes.CDLL(so)
             cdll.bls381_ready.restype = ctypes.c_int
             u8 = ctypes.c_char_p

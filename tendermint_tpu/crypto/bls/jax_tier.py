@@ -66,6 +66,8 @@ def _limbs_to_int(a) -> int:
 def _build(bucket: int, mesh=None, batch_axis: str = "batch"):
     """Construct the jitted [bucket]-point G1 and G2 aggregators."""
     import jax
+
+    from ... import ops  # noqa: F401 — places the compile cache before any compile
     import jax.numpy as jnp
     from jax import lax
 

@@ -24,7 +24,9 @@ from __future__ import annotations
 
 import ctypes
 import hashlib
+import logging
 import os
+import platform
 import subprocess
 import tempfile
 from typing import List, Optional, Sequence
@@ -33,47 +35,86 @@ import numpy as np
 
 from . import ed25519_math as em
 
+logger = logging.getLogger(__name__)
+
 _N = 20
 _BITS = 13
 
 _lib: Optional[ctypes.CDLL] = None
 _lib_tried = False
+_lib_error: Optional[str] = None
 
 
 def _csrc_path() -> str:
     return os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "csrc")
 
 
+def cpu_identity() -> str:
+    """Names the CPU a `-march=native` artifact may run on: the machine
+    arch plus a hash of the model name and ISA feature flags the kernel
+    reports.  The flags decide whether native codegen from one host is
+    legal on another (an illegal instruction is a SIGILL, not an
+    exception), so they — not just the arch — belong in the artifact name."""
+    ident = {"processor": platform.processor()}
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:  # the first core's entries; the rest repeat them
+                key, _, value = line.partition(":")
+                if key.strip() in ("model name", "flags", "Features"):
+                    ident.setdefault(key.strip(), " ".join(sorted(value.split())))
+    except OSError:
+        pass  # no procfs: arch + platform.processor() is all there is
+    digest = hashlib.sha256(repr(sorted(ident.items())).encode()).hexdigest()[:8]
+    return f"{platform.machine() or 'unknown'}-{digest}"
+
+
+def build_native_lib(
+    src: str, stem: str, extra_flags: Sequence[str] = (), timeout: float = 60
+) -> str:
+    """Path of the shared object compiled from the committed C source
+    `src` for THIS cpu, building it first when absent.  The artifact name
+    embeds the building CPU's identity and the source SHA-256, so a stale
+    binary, or one built on another machine (artifacts are never committed
+    to git, but a copied tree carries them), is a cache miss and gets
+    rebuilt.  Raises when the toolchain is missing or the compile fails."""
+    with open(src, "rb") as f:
+        src_hash = hashlib.sha256(f.read()).hexdigest()[:16]
+    out_dir = os.path.dirname(src)
+    so = os.path.join(out_dir, f"{stem}-{cpu_identity()}-{src_hash}.so")
+    if os.path.exists(so):
+        return so
+    fd, tmp = tempfile.mkstemp(suffix=".so", dir=out_dir)
+    os.close(fd)
+    try:
+        base = ["cc", "-O3", "-shared", "-fPIC", *extra_flags, "-o", tmp, src]
+        # -march=native buys ~20% on the SHA-512 compression loop; fall
+        # back for toolchains that reject it
+        try:
+            subprocess.run(
+                base[:2] + ["-march=native"] + base[2:],
+                check=True, capture_output=True, timeout=timeout,
+            )
+        except subprocess.CalledProcessError:
+            subprocess.run(base, check=True, capture_output=True, timeout=timeout)
+        os.replace(tmp, so)
+    finally:
+        if os.path.exists(tmp):  # failed compile: no orphan temp
+            os.unlink(tmp)
+    return so
+
+
 def _load_lib() -> Optional[ctypes.CDLL]:
-    """Compile from the committed C source and load via ctypes; None when no
-    toolchain is available.  The artifact name embeds the source SHA-256, so
-    only a binary built from exactly this source can ever be loaded — a
-    stale, foreign, or wrong-arch .so (never committed to git) is simply a
-    cache miss and gets rebuilt."""
-    global _lib, _lib_tried
+    """Compile csrc/sha512_batch.c (build_native_lib) and load it via
+    ctypes; None when that fails — no toolchain, a compile error — with
+    the reason logged and kept in `lib_error()`."""
+    global _lib, _lib_tried, _lib_error
     if _lib_tried:
         return _lib
     _lib_tried = True
-    src = os.path.join(_csrc_path(), "sha512_batch.c")
     try:
-        with open(src, "rb") as f:
-            src_hash = hashlib.sha256(f.read()).hexdigest()[:16]
-        so = os.path.join(_csrc_path(), f"sha512_batch-{src_hash}.so")
-        if not os.path.exists(so):
-            fd, tmp = tempfile.mkstemp(suffix=".so", dir=_csrc_path())
-            os.close(fd)
-            base = ["cc", "-O3", "-shared", "-fPIC", "-pthread", "-o", tmp, src]
-            # -march=native buys ~20% on the SHA-512 compression loop; fall
-            # back for toolchains that reject it.  The artifact is per-host
-            # (hash-named, never committed), so native codegen is safe.
-            try:
-                subprocess.run(
-                    base[:2] + ["-march=native"] + base[2:],
-                    check=True, capture_output=True, timeout=60,
-                )
-            except Exception:
-                subprocess.run(base, check=True, capture_output=True, timeout=60)
-            os.replace(tmp, so)
+        so = build_native_lib(
+            os.path.join(_csrc_path(), "sha512_batch.c"), "sha512_batch", ["-pthread"]
+        )
         lib = ctypes.CDLL(so)
         u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
         u64p = np.ctypeslib.ndpointer(np.uint64, flags="C_CONTIGUOUS")
@@ -117,9 +158,16 @@ def _load_lib() -> Optional[ctypes.CDLL]:
         lib.chacha20poly1305_open.argtypes = aead_args
         lib.chacha20poly1305_open.restype = ctypes.c_int
         _lib = lib
-    except Exception:
-        _lib = None
+    except (OSError, subprocess.SubprocessError, AttributeError) as exc:
+        _lib_error = repr(exc)
+        logger.error("C host-prep extension unavailable, numpy prep in use: %s", exc)
     return _lib
+
+
+def lib_error() -> Optional[str]:
+    """Why the C extension did not load (None when it did, or before the
+    first _load_lib call)."""
+    return _lib_error
 
 
 _PREP_THREADS = min(os.cpu_count() or 1, 8)

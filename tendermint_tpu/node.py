@@ -283,18 +283,25 @@ class Node(Service):
 
             # Mesh probe ([tpu] mesh = auto|on|off, mesh_devices caps the
             # shard count): sharding degrades to single-device — never a
-            # startup failure — and the decision is attributed right next
-            # to the host-crypto tier so an operator can read one log line
-            # and know which engine this node actually runs.
+            # startup failure — and the decision (with the jax backend and
+            # device kind it found) is attributed right next to the
+            # host-crypto tier, so an operator can read one log line, and a
+            # tool one `verify.engine` event, and know which engine this
+            # node actually runs.
             mesh, shards, mesh_reason = _crypto_backend.resolve_mesh(
                 cfg.tpu.mesh, cfg.tpu.mesh_devices
             )
             self.metrics_provider.verify.shards.set(shards)
-            self.log.info(
-                "verify engine",
+            engine = dict(
                 shards=shards,
                 mesh=mesh_reason,
                 host_tier=_crypto_backend.active_tier(),
+            )
+            self.log.info("verify engine", **engine)
+            self.flight_recorder.record(
+                "verify.engine",
+                ok=not mesh_reason.startswith(_crypto_backend.MESH_PROBE_FAILED),
+                **engine,
             )
             self.batch_verifier = BatchVerifier(
                 mesh=mesh,

@@ -3,21 +3,28 @@
 All field arithmetic is native int32 (13-bit limbs) — TPUs have no native
 int64, so the round-1 int64 design paid several emulated ops per multiply.
 
-A persistent compile cache is enabled: the curve kernels are expensive to
-compile (especially on the single-core CPU test host); the cache survives
-across processes so test/bench reruns skip recompilation.
+The persistent compile cache is placed HERE and nowhere else (the curve
+kernels take tens of seconds to compile; the cache lets reruns and sibling
+processes skip that).  Every module that touches jax imports this package
+first.  Where JAX_COMPILATION_CACHE_DIR is set, JAX reads it itself and
+nothing is overridden; otherwise the cache is `.jax_cache` in the checkout
+that holds this package.  The directory is part of the cache key, so it
+must be the same path on every run.
 """
 
 import os
 
 import jax
 
-_cache_dir = os.environ.get("TM_TPU_JAX_CACHE", "/root/repo/.jax_cache")
-try:
-    jax.config.update("jax_compilation_cache_dir", _cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-except Exception:  # older jax without the option — compile cache is best-effort
-    pass
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.config.update(
+        "jax_compilation_cache_dir",
+        os.path.join(
+            os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+            ".jax_cache",
+        ),
+    )
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 
 from . import fe  # noqa: E402
 from . import ed25519 as ed25519_kernel  # noqa: E402

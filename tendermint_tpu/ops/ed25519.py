@@ -112,10 +112,9 @@ def expand_digits(packed_le: jnp.ndarray) -> jnp.ndarray:
     MSB first — the device-side twin of batch_verifier._msb_digits.
 
     Kept as a kernel-level op so dispatch paths can ship 32 packed bytes
-    per scalar instead of 64 digit bytes: on remote-attached devices the
-    single-shot latency is transfer-bound, and halving the h/s payload is
-    free VPU work (two shifts and an interleave, fused into the verify
-    kernel's prologue by XLA)."""
+    per scalar instead of 64 digit bytes: halving the h/s payload costs
+    two shifts and an interleave, fused into the verify kernel's prologue
+    by XLA."""
     lo = packed_le & 15
     hi = packed_le >> 4
     dig = jnp.stack([lo, hi], axis=-1).reshape(packed_le.shape[0], 64)

@@ -18,18 +18,20 @@ ops — ~2.4x less VPU work for the steady-state commit-verification path
 the adds + inversion + canonical compare run in one Pallas kernel with a
 VMEM accumulator (grid = batch tiles × window chunks, k-loop pattern).
 
-MEASURED (v5e-1, round 5): 85 ms steady-state per 10k batch vs 31 ms for
-the VMEM-resident Straus ladder (ops/ed25519_pallas.py).  The VPU saving
-is real but the 128 random 160 B row gathers per signature plus the
-[B,128,4,20]→[128,4,20,B] relayout are HBM-bound and dominate.  Kept as
-an opt-in (PubkeyTable(tabulated=True)) with full test coverage; making
-the gather sequential (sorting signatures by validator, fusing the gather
-into the pallas grid) is the open avenue if this path is to win.
+MEASURED (one v5e chip, PR 21, PERF.md): 79 ms per 10k-committee dispatch
+vs 23 ms for the VMEM-resident Straus ladder (ops/ed25519_pallas.py), and
+slower at 256, 1024 and 4096 validators too.  The VPU saving is real but
+the 128 random 160 B row gathers per signature plus the
+[B,128,4,20]→[128,4,20,B] relayout (1.5 GB of temporaries at 10k) are
+HBM-bound and dominate.  Engaged only where PubkeyTable's run-time profile
+measures a win; making the gather sequential (sorting signatures by
+validator, fusing the gather into the pallas grid) is the open avenue if
+this path is to win.
 
 Tables store canonical limbs as int16 ([V, 64, 16, 4, 20] = 160 KB per
-validator, 1.6 GB for 10k) and are built on-device in one jitted scan —
-~seconds once per validator-set change, amortized over every subsequent
-commit at that height range.
+validator, 1.6 GB for 10k) and are built on-device by a jitted scan over
+slices of BUILD_CHUNK validators — once per validator-set change,
+amortized over every subsequent commit at that height range.
 
 Reference contrast: crypto/ed25519/ed25519.go:151 verifies one signature
 at a time with a fresh double-and-add each call; nothing is amortized.
@@ -105,9 +107,30 @@ def _build_tables_jit(neg_a: jnp.ndarray) -> jnp.ndarray:
     return tab.transpose(4, 0, 1, 2, 3).reshape(v * N_WINDOWS * N_DIGITS, 4, N)
 
 
+# Validators per build dispatch.  The build's XLA temporaries are ~9x its
+# output (compiler memory analysis on a v5e, libtpu 0.0.34: 14.8 GB of
+# temp for the 1.64 GB tables of 10,000 validators — all of a 16 GB chip,
+# and invisible to memory_stats' peak counter), so a committee is built a
+# slice at a time: ~1.5 GB of temp whatever its size.
+BUILD_CHUNK = 1024
+
+
 def build_window_tables(neg_a_rows) -> jnp.ndarray:
-    """Public entry: [V, 4, 20] (any int dtype) -> flat device tables."""
-    return _build_tables_jit(jnp.asarray(neg_a_rows))
+    """Public entry: [V, 4, 20] (any int dtype) -> flat device tables.
+    Above BUILD_CHUNK validators the rows are padded with the identity
+    point to whole chunks (one compiled shape) and the tables carry the
+    padding's rows at the end, which no validator index reaches."""
+    rows = jnp.asarray(neg_a_rows)
+    v = rows.shape[0]
+    if v <= BUILD_CHUNK:
+        return _build_tables_jit(rows)
+    pad = -v % BUILD_CHUNK
+    if pad:
+        identity = jnp.zeros((pad, 4, N), rows.dtype).at[:, 1:3, 0].set(1)
+        rows = jnp.concatenate([rows, identity])
+    return jnp.concatenate(
+        [_build_tables_jit(rows[i : i + BUILD_CHUNK]) for i in range(0, v, BUILD_CHUNK)]
+    )
 
 
 def _build_base_windows() -> np.ndarray:

@@ -282,7 +282,7 @@ class AddrBook:
         the trust score: once any candidate is meaningfully trusted, peers
         whose score has decayed below half the best score stop winning
         selection (they stay in the book and recover as their history
-        fades — p2p/trust parity, the VERDICT-missing wiring)."""
+        fades — p2p/trust parity)."""
         if self.is_empty():
             return None
         candidates_old = [ka for ka in self.addrs.values() if ka.is_old() and not ka.is_bad()]

@@ -1,8 +1,7 @@
 """Time-decaying peer trust metric.
 
 Reference parity: p2p/trust/metric.go (TrustMetric with proportional +
-historic components over fixed intervals) and trust/store.go — the piece
-VERDICT flagged as missing.  A peer's conduct (successful connections,
+historic components over fixed intervals) and trust/store.go.  A peer's conduct (successful connections,
 behaviour reports, dial failures, protocol errors) feeds a per-peer
 score in [0, 1]; the score decays toward its history over time, the
 history itself fades, and the address book consults the score for dial

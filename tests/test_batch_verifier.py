@@ -297,6 +297,22 @@ class TestTabulated:
         got = table.verify_indexed(idxs, ms, ss)
         assert got == [True, True, False, True, False, True]
 
+    def test_chunked_table_build_equals_whole(self, verifier, monkeypatch):
+        """Committees above BUILD_CHUNK are built a slice at a time (the
+        build's temporaries are ~9x its output): same rows as one build,
+        identity padding behind them."""
+        from tendermint_tpu.ops import ed25519_table
+
+        pubkeys, _, _ = make_sigs(10)
+        rows = PubkeyTable(pubkeys, verifier, tabulated=False).neg_a_rows
+        whole = np.asarray(ed25519_table.build_window_tables(rows))
+        monkeypatch.setattr(ed25519_table, "BUILD_CHUNK", 4)
+        sliced = np.asarray(ed25519_table.build_window_tables(rows))
+        per_validator = ed25519_table.N_WINDOWS * ed25519_table.N_DIGITS
+        assert whole.shape[0] == 10 * per_validator
+        assert sliced.shape[0] == 12 * per_validator
+        np.testing.assert_array_equal(sliced[: whole.shape[0]], whole)
+
     def test_table_cache_routes_verify_commit(self, verifier):
         """verify_commit uses the installed indexed hook (device-resident
         pubkey rows) and falls back cleanly when the cache declines."""

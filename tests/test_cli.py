@@ -1,6 +1,6 @@
 """CLI + testnet-generator tests (reference: cmd/tendermint/commands).
 
-The localnet test is the VERDICT #9 criterion: a 4-node net launches from
+The localnet test's criterion: a 4-node net launches from
 CLI-generated config trees (no hand-written Python wiring) and commits
 blocks.
 """

@@ -1,4 +1,4 @@
-"""Crash-recovery rigs (VERDICT #5; reference: consensus/replay_test.go
+"""Crash-recovery rigs (reference: consensus/replay_test.go
 crashingWAL + test/persist/test_failure_indices.sh + byzantine_test.go:27).
 
 (a) crashing-WAL: kill consensus at every WAL record index, restart on the

@@ -1,7 +1,7 @@
 """privval tests: FilePV double-sign protection + remote signer socket.
 
 Reference parity: privval/file_test.go (sign/re-sign/regression cases),
-privval/signer_client_test.go.  The crash-safety test is the VERDICT #4
+privval/signer_client_test.go.  The crash-safety test's
 criterion: state persists BEFORE the signature escapes, so killing the
 process after signing but before any other durable write cannot lead to a
 conflicting re-sign after restart.
