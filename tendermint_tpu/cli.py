@@ -427,6 +427,14 @@ def cmd_trace(args) -> int:
         else:
             print(tracing.format_net_budget(budget))
         return 0 if budget is not None else 1
+    if args.replay:
+        # a joining node's blocks by stage, from the fastsync.block span chain
+        budget = tracing.replay_budget(events)
+        if args.json:
+            print(json.dumps({"replay_budget": budget}))
+        else:
+            print(tracing.format_replay_budget(budget))
+        return 0 if budget is not None else 1
     if args.budget:
         # per-stage latency budget: propose→prevote→precommit→
         # commit(persist)→finalize(deliver)→next-propose + c2c percentiles
@@ -1092,6 +1100,12 @@ def build_parser() -> argparse.ArgumentParser:
         "--budget",
         action="store_true",
         help="per-stage latency budget table (propose→…→finalize→next-propose)",
+    )
+    sp.add_argument(
+        "--replay",
+        action="store_true",
+        help="a fast-syncing node's blocks by stage, from its fastsync.block span chain "
+        "(wait, part sets, commit verification, store, apply_block's stages, the engine's calls)",
     )
     sp.add_argument(
         "--net-budget",

@@ -678,51 +678,54 @@ class BatchVerifier:
         step = 1024 * m // _math.gcd(1024, m)
         return ((n + step - 1) // step) * step
 
+    def _dispatch_span(self, n: int, bucket: int, path: str) -> "tracing.Span":
+        """The `verify.dispatch` span of one engine call.  Its laps tile the
+        call: `host_prep_ms` + `device_ms` is its wall time on every path,
+        but for `rows_ms` on the table paths (the row list built from the
+        caller's indices, which these two never covered)."""
+        return self.recorder.begin(
+            "verify.dispatch", n=n, bucket=bucket, path=path, shards=self.shards,
+            host_prep_ms=0.0, device_ms=0.0,
+        )
+
+    def _end_device_span(self, span: "tracing.Span") -> None:
+        """Close a device path's span: `device_ms` is what followed host
+        prep (pack, transfer and launch, kernel and copy back), and the
+        Prometheus histogram takes the same reading."""
+        f = span.fields
+        f["device_ms"] = f["pack_ms"] + f["launch_ms"] + f["fetch_ms"]
+        self.metrics.device_seconds.observe(f["device_ms"] / 1e3)
+        span.end()
+
     def verify(
         self, pubkeys: Sequence[bytes], msgs: Sequence[bytes], sigs: Sequence[bytes]
     ) -> List[bool]:
-        import time as _time
-
         n = len(sigs)
         if n == 0:
             return []
         self.metrics.batch_size.observe(n)
-        if n < self.min_device_batch:
-            t0 = _time.perf_counter()
-            out = batch_hook.host_batch_verify(pubkeys, msgs, sigs)
-            self.recorder.record(
-                "verify.dispatch", n=n, bucket=0, path="host",
-                host_prep_ms=0.0,
-                device_ms=round((_time.perf_counter() - t0) * 1000, 3),
-                shards=self.shards,
-            )
+        b = 0 if n < self.min_device_batch else self._bucket(n)
+        if b == 0 or not self._bucket_ready(b):
+            with self._dispatch_span(n, b, "host" if b == 0 else "host-cold") as span:
+                out = batch_hook.host_batch_verify(pubkeys, msgs, sigs)
+                span.lap("device_ms")
             return out
-        b = self._bucket(n)
-        if not self._bucket_ready(b):
-            self.recorder.record("verify.dispatch", n=n, bucket=b, path="host-cold",
-                                 host_prep_ms=0.0, device_ms=0.0,
-                                 shards=self.shards)
-            return batch_hook.host_batch_verify(pubkeys, msgs, sigs)
-        t0 = _time.perf_counter()
+        span = self._dispatch_span(n, b, "device")
         neg_a, h_digits, s_digits, r_y, r_sign, valid = prepare_batch(pubkeys, msgs, sigs)
-        prep_s = _time.perf_counter() - t0
-        self.metrics.host_prep_seconds.observe(prep_s)
+        self.metrics.host_prep_seconds.observe(span.lap("host_prep_ms") / 1e3)
         if not valid.any():
+            span.drop()
             return [False] * n
         if b > n:
             neg_a = np.concatenate([neg_a, np.tile(neg_a[-1:], (b - n, 1, 1))])
         h_digits, s_digits, r_y, r_sign = _pad_scalar_rows(b, h_digits, s_digits, r_y, r_sign)
-        t1 = _time.perf_counter()
-        ok = np.asarray(self._jitted()(neg_a, h_digits, s_digits, r_y, r_sign))[:n]
-        dev_s = _time.perf_counter() - t1
-        self.metrics.device_seconds.observe(dev_s)
-        self.recorder.record(
-            "verify.dispatch", n=n, bucket=b, path="device",
-            host_prep_ms=round(prep_s * 1000, 3),
-            device_ms=round(dev_s * 1000, 3),
-            shards=self.shards,
-        )
-        return list(np.logical_and(ok, valid))
+        span.lap("pack_ms")
+        dev_ok = self._jitted()(neg_a, h_digits, s_digits, r_y, r_sign)
+        span.lap("launch_ms")
+        out = list(np.logical_and(np.asarray(dev_ok)[:n], valid))
+        span.lap("fetch_ms")
+        self._end_device_span(span)
+        return out
 
     def install(self) -> "BatchVerifier":
         """Become the process-wide batch-verify hook used by
@@ -976,8 +979,6 @@ class PubkeyTable:
         self, idxs: Sequence[int], msgs: Sequence[bytes], sigs: Sequence[bytes]
     ) -> List[bool]:
         """Verify msgs[i]/sigs[i] against table row idxs[i]."""
-        import time as _time
-
         n = len(sigs)
         if n == 0:
             return []
@@ -992,6 +993,24 @@ class PubkeyTable:
                 msgs,
                 sigs,
             )
+        # the one-time decisions (a profile, a probe: seconds) come before
+        # the span, which times a dispatch and nothing else
+        tab = self._tabulated_active(n)
+        cs = self.verifier.effective_chunk()
+        use_chunked = self.chunked_single_shot
+        chunk_eligible = not tab and n >= 2 * cs
+        if use_chunked is None and chunk_eligible:
+            use_chunked = self.verifier.chunked_auto()
+        chunked = bool(use_chunked and chunk_eligible)
+        if chunked:
+            path, b = "chunked", cs
+        elif tab:
+            tile = min(_PALLAS_TILE, 256)
+            path, b = "tabulated", ((n + tile - 1) // tile) * tile
+        else:
+            path, b = "indexed", self.verifier._bucket(n)
+        span = self.verifier._dispatch_span(n, b, path)
+
         idx_arr = np.asarray(idxs, dtype=np.int32)
         # Host prep for everything except pubkey limbs (gathered on device);
         # entries with bad indices are marked invalid up front.
@@ -1000,24 +1019,18 @@ class PubkeyTable:
         for i, (idx, msg, sig) in enumerate(zip(idx_list, msgs, sigs)):
             if 0 <= idx < pk_count and self.row_valid[idx]:
                 items[i] = (self.pubkeys[idx], msg, sig)
+        span.lap("rows_ms")
 
-        tab = self._tabulated_active(n)
-
-        cs = self.verifier.effective_chunk()
-        use_chunked = self.chunked_single_shot
-        chunk_eligible = not tab and n >= 2 * cs
-        if use_chunked is None and chunk_eligible:
-            use_chunked = self.verifier.chunked_auto()
-        if use_chunked and chunk_eligible:
+        if chunked:
             # Double-buffered single-shot: device dispatch (and the
             # pre-partitioned device_put) is async, so prepping chunk k+1
             # on the host while the device runs chunk k hides most of the
             # host prep inside device time — single-shot latency ≈
             # prep(chunk 1) + device(total) instead of prep(total) +
-            # device(total).
+            # device(total).  Prep and device time interleave by design;
+            # every stage's laps are summed over the chunks.
             fn = self._chunked()
             depth = max(1, self.verifier.chunk_depth)
-            t0 = _time.perf_counter()
             pending: "_collections.deque" = _collections.deque()
             out: List[bool] = []
 
@@ -1030,96 +1043,61 @@ class PubkeyTable:
             for start in range(0, n, cs):
                 end = min(start + cs, n)
                 h, s, ry, rs, valid_c = _scalar_rows(items[start:end])
+                span.lap("host_prep_ms")
                 cnt = end - start
                 h, s, ry, rs = _pad_scalar_rows(cs, h, s, ry, rs)
                 idx_c = idx_arr[start:end]
                 if cnt < cs:
                     idx_c = np.concatenate([idx_c, np.zeros(cs - cnt, np.int32)])
                 idx_c = np.clip(idx_c, 0, pk_count - 1)
+                h, s = _pack_digits(h), _pack_digits(s)
+                span.lap("pack_ms")
                 # Bound in-flight chunks: fetching the oldest result here
                 # blocks until the device drains it, so donated buffers in
                 # flight stay at O(depth·chunk) and the host never races
                 # more than chunk_depth dispatches ahead of the device.
                 while len(pending) >= depth:
                     _collect()
-                dev = self._put_chunk(
-                    idx_c, _pack_digits(h), _pack_digits(s), ry, rs
-                )
+                span.lap("fetch_ms")
+                dev = self._put_chunk(idx_c, h, s, ry, rs)
                 pending.append((fn(self.neg_a_rows, *dev), valid_c, cnt))
+                span.lap("launch_ms")
             while pending:
                 _collect()
-            # prep and device time interleave by design here; report the
-            # overlapped wall time as device_ms and mark the path
-            self.verifier.recorder.record(
-                "verify.dispatch", n=n, bucket=cs, path="chunked",
-                host_prep_ms=0.0,
-                device_ms=round((_time.perf_counter() - t0) * 1000, 3),
-                shards=self.verifier.shards,
-            )
+            span.lap("fetch_ms")
+            self.verifier.metrics.host_prep_seconds.observe(span.fields["host_prep_ms"] / 1e3)
+            self.verifier._end_device_span(span)
             return out
 
-        t0 = _time.perf_counter()
         h_digits, s_digits, r_y, r_sign, valid = _scalar_rows(items)
-        prep_s = _time.perf_counter() - t0
-        self.verifier.metrics.host_prep_seconds.observe(prep_s)
+        self.verifier.metrics.host_prep_seconds.observe(span.lap("host_prep_ms") / 1e3)
         if not valid.any():
+            span.drop()
             return [False] * n
 
-        if tab:
-            from ..ops import ed25519_table
-
-            tile = min(_PALLAS_TILE, 256)
-            b = ((n + tile - 1) // tile) * tile
-            h_digits, s_digits, r_y, r_sign = _pad_scalar_rows(
-                b, h_digits, s_digits, r_y, r_sign
-            )
-            if b > n:
-                idx_arr = np.concatenate([idx_arr, np.zeros(b - n, dtype=np.int32)])
-            idx_arr = np.clip(idx_arr, 0, pk_count - 1)
-            t1 = _time.perf_counter()
-            ok = np.asarray(
-                ed25519_table.verify_tabulated(
-                    self.build_tables(),
-                    idx_arr,
-                    h_digits,
-                    s_digits,
-                    r_y,
-                    r_sign,
-                    tile=tile,
-                    interpret=self._interpret,
-                )
-            )[:n]
-            dev_s = _time.perf_counter() - t1
-            self.verifier.metrics.device_seconds.observe(dev_s)
-            self.verifier.recorder.record(
-                "verify.dispatch", n=n, bucket=b, path="tabulated",
-                host_prep_ms=round(prep_s * 1000, 3),
-                device_ms=round(dev_s * 1000, 3),
-                shards=self.verifier.shards,
-            )
-            return list(np.logical_and(ok, valid))
-
-        b = self.verifier._bucket(n)
         h_digits, s_digits, r_y, r_sign = _pad_scalar_rows(b, h_digits, s_digits, r_y, r_sign)
         if b > n:
             idx_arr = np.concatenate([idx_arr, np.zeros(b - n, dtype=np.int32)])
         idx_arr = np.clip(idx_arr, 0, pk_count - 1)
-        t1 = _time.perf_counter()
-        ok = np.asarray(
-            self._fused()(
-                self.neg_a_rows, idx_arr,
-                _pack_digits(h_digits), _pack_digits(s_digits), r_y, r_sign,
+        if tab:
+            from ..ops import ed25519_table
+
+            tables = self.build_tables()
+            span.lap("pack_ms")
+            dev_ok = ed25519_table.verify_tabulated(
+                tables, idx_arr, h_digits, s_digits, r_y, r_sign,
+                tile=tile, interpret=self._interpret,
             )
-        )[:n]
-        dev_s = _time.perf_counter() - t1
-        self.verifier.metrics.device_seconds.observe(dev_s)
-        self.verifier.recorder.record(
-            "verify.dispatch", n=n, bucket=b, path="indexed",
-            host_prep_ms=round(prep_s * 1000, 3),
-            device_ms=round(dev_s * 1000, 3),
-            shards=self.verifier.shards,
-        )
-        return list(np.logical_and(ok, valid))
+        else:
+            fused = self._fused()
+            h_digits, s_digits = _pack_digits(h_digits), _pack_digits(s_digits)
+            span.lap("pack_ms")
+            dev_ok = fused(self.neg_a_rows, idx_arr, h_digits, s_digits, r_y, r_sign)
+        span.lap("launch_ms")
+        out = list(np.logical_and(np.asarray(dev_ok)[:n], valid))
+        span.lap("fetch_ms")
+        self.verifier._end_device_span(span)
+        return out
 
 
 class TableCache:
@@ -1434,7 +1412,6 @@ class AsyncBatchVerifier(Service):
         msgs = [it[1] for it in items]
         sigs = [it[2] for it in items]
         loop = asyncio.get_event_loop()
-        self.verifier.recorder.record("verify.direct_batch", n=len(items))
         return await loop.run_in_executor(
             self._executor, self.verifier.verify, pubkeys, msgs, sigs
         )

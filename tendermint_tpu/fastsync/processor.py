@@ -17,10 +17,20 @@ from ..types import Block, BlockID, Commit
 class Processor:
     def __init__(self, height: int):
         self.height = height  # next height to apply
-        self.blocks: Dict[int, Tuple[Block, str]] = {}  # height -> (block, peer)
+        # height -> (block, peer, what the receive path measured on the way in)
+        self.blocks: Dict[int, Tuple[Block, str, dict]] = {}
 
-    def add_block(self, height: int, block: Block, peer_id: str) -> None:
-        self.blocks.setdefault(height, (block, peer_id))
+    def add_block(
+        self, height: int, block: Block, peer_id: str, received: Optional[dict] = None
+    ) -> None:
+        self.blocks.setdefault(height, (block, peer_id, received or {}))
+
+    def received(self, height: int) -> dict:
+        """What the reactor noted as the block at `height` arrived (its
+        bytes, decode and download times, the peer): fields of that block's
+        `fastsync.block` span."""
+        entry = self.blocks.get(height)
+        return {} if entry is None else entry[2]
 
     def peek_two(self) -> Optional[Tuple[Block, Block]]:
         """The v0 trySync pair: block H and block H+1 (whose LastCommit

@@ -13,6 +13,7 @@ import time
 from typing import Optional
 
 from ..encoding import codec
+from ..libs import tracing
 from ..libs.log import get_logger
 from ..libs.service import wait_event
 from ..p2p import ChannelDescriptor, Reactor
@@ -63,6 +64,8 @@ class BlockchainReactor(Reactor):
         self.blocks_synced = 0
         self._started_at = 0.0
         self._wake: Optional[asyncio.Event] = None
+        self.recorder = tracing.NOP  # node swaps in its FlightRecorder
+        self._block_done_ns = 0  # when the last block's span closed
         self.statesync_metrics = None  # node wires StateSyncMetrics (phase gauge)
         # self-healing refill: quarantined (corrupt) heights to re-fetch
         # from peers — runs in EVERY mode, not just fast sync; the store
@@ -156,18 +159,26 @@ class BlockchainReactor(Reactor):
                 # steady state with nothing pending: an unsolicited block
                 # must not cost a multi-MB deserialize on the event loop
                 return
+            t_ns = time.monotonic_ns()
             try:
                 block = Block.deserialize(msg["block"])
             except Exception:
                 await self._report(behaviour.bad_message(peer.id, "undecodable block response"))
                 return
+            received_ns = time.monotonic_ns()
             if block.height in self.refill_heights:
                 await self._try_refill(peer, block)
                 return
             if not self.fast_sync:
                 return
+            _, requested_at = self.scheduler.pending.get(block.height, (None, None))
             if self.scheduler.block_received(peer.id, block.height):
-                self.processor.add_block(block.height, block, peer.id)
+                self.processor.add_block(block.height, block, peer.id, {
+                    "peer": peer.id[:8], "bytes": len(msg["block"]),
+                    "decode_ms": (received_ns - t_ns) / 1e6,
+                    "download_ms": (time.monotonic() - requested_at) * 1e3,
+                    "received_ns": received_ns,
+                })
                 self._wake_pool()
             else:
                 await self._report(
@@ -295,19 +306,31 @@ class BlockchainReactor(Reactor):
             self._wake.clear()
 
     async def _try_sync(self) -> None:
-        """Verify + apply contiguous pairs (v0 reactor.go:244 trySync)."""
+        """Verify + apply contiguous pairs (v0 reactor.go:244 trySync).
+        Each block applied leaves one `fastsync.block` span, its stages as
+        fields (libs/tracing.py has the list)."""
         while True:
             pair = self.processor.peek_two()
             if pair is None:
                 return
             first, second = pair
+            received = dict(self.processor.received(first.height))
+            span = self.recorder.begin(
+                "fastsync.block", id=first.height, pending=self.processor.pending_range()
+            )
+            if self._block_done_ns:
+                span.set(wait_ms=(span.t0_ns - self._block_done_ns) / 1e6)
+            if received:
+                span.set(queued_ms=(span.t0_ns - received.pop("received_ns")) / 1e6, **received)
             first_id = BlockID(first.hash(), first.make_part_set(BLOCK_PART_SIZE_BYTES).header())
+            span.lap("parts_ms")
             try:
                 # verify first with second's LastCommit (batched over V sigs)
                 self.state.validators.verify_commit(
                     self.state.chain_id, first_id, first.height, second.last_commit
                 )
             except Exception as e:
+                span.drop()  # not a block applied
                 self.log.error("invalid block in fast sync", height=first.height, err=str(e))
                 for h in self.processor.drop_invalid():
                     # block_invalid clears scheduler.received[h], removes the
@@ -319,12 +342,20 @@ class BlockchainReactor(Reactor):
                     if pid:
                         await self._report(behaviour.bad_message(pid, "sent invalid block"))
                 return
-            self.block_store.save_block(
-                first, first.make_part_set(BLOCK_PART_SIZE_BYTES), second.last_commit
-            )
-            self.state, _ = await self.block_exec.apply_block(self.state, first_id, first)
+            span.lap("verify_ms")
+            try:
+                self.block_store.save_block(
+                    first, first.make_part_set(BLOCK_PART_SIZE_BYTES), second.last_commit
+                )
+                span.lap("store_ms")
+                self.state, _ = await self.block_exec.apply_block(self.state, first_id, first)
+                span.lap("apply_ms")
+            except BaseException:
+                span.drop()
+                raise
             self.processor.pop_processed()
             self.scheduler.block_processed(first.height)
+            self._block_done_ns = span.t0_ns + span.end()
             self.blocks_synced += 1
             if self.blocks_synced % 100 == 0:
                 self.log.info("fast sync", height=self.processor.height, synced=self.blocks_synced)

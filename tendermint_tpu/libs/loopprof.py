@@ -18,11 +18,16 @@ flight-recorder stream production telemetry uses:
 
   task time      every task spawned through `Service.spawn` is wrapped in
                  a resume-timing trampoline and accounted to a CATEGORY
-                 (consensus / gossip / p2p-conn / verify / mempool / rpc /
-                 other) derived from its service + task name — the spawn
+                 (consensus / gossip / p2p-conn / fastsync / verify / mempool /
+                 rpc / other) derived from its service + task name — the spawn
                  path already names everything, so categorization is free.
                  Per-interval deltas are emitted as `loop.busy` events and
-                 `tendermint_loop_task_busy_seconds{category=...}`.
+                 `tendermint_loop_task_busy_seconds{category=...}`.  A
+                 resume is accounted when it ends, and the probe cannot
+                 tick while one runs, so an event's `interval_ms` is the
+                 time since the previous `loop.busy` event, not the nominal
+                 probe interval: the events tile the loop's time and a
+                 category's share of it cannot pass 100%.
 
   GC pauses      gc.callbacks hooks accumulate collection pause time;
                  the probe tick emits `loop.gc_pause` (count, total, max)
@@ -64,7 +69,9 @@ from typing import Callable, Dict, List, Optional
 
 #: Attribution categories, in reporting order.  `other` catches tasks the
 #: rules below don't place (cli helpers, tests) so shares still sum.
-CATEGORIES = ("consensus", "gossip", "p2p-conn", "verify", "mempool", "rpc", "other")
+CATEGORIES = (
+    "consensus", "gossip", "p2p-conn", "fastsync", "verify", "mempool", "rpc", "other",
+)
 
 # (substring of "<service>/<task>" lowercased) -> category; first match
 # wins, so the more specific gossip rules precede the consensus ones.
@@ -73,6 +80,8 @@ _RULES = (
     ("maj23-", "gossip"),
     ("bcast-", "gossip"),
     ("batch-verifier", "verify"),
+    # the replay loop and block serving ("blockchain-reactor/pool", ...)
+    ("blockchain-reactor", "fastsync"),
     ("mconn", "p2p-conn"),
     ("peer", "p2p-conn"),
     ("switch", "p2p-conn"),
@@ -270,6 +279,7 @@ class LoopProfiler:
     async def _probe_loop(self) -> None:
         loop = asyncio.get_event_loop()
         rec = self.recorder
+        busy_since = time.perf_counter_ns()  # the last loop.busy event, or the start
         while True:
             scheduled = loop.time() + self.interval
             await asyncio.sleep(self.interval)
@@ -288,11 +298,13 @@ class LoopProfiler:
                 for cat, total in self.busy_ns.items():
                     self.metrics.task_busy_seconds.labels(category=cat).set(total / 1e9)
             if rec is not None and deltas:
+                now = time.perf_counter_ns()
                 rec.record(
                     "loop.busy",
-                    interval_ms=round(self.interval * 1000, 1),
+                    interval_ms=round((now - busy_since) / 1e6, 3),
                     **{f"{c}_ms": round(ns / 1e6, 3) for c, ns in deltas.items()},
                 )
+                busy_since = now
             # gc pauses accumulated since the last tick
             pauses, self._gc_pauses = self._gc_pauses, 0
             pause_ns, self._gc_pause_ns = self._gc_pause_ns, 0

@@ -24,10 +24,36 @@ Event kinds currently emitted:
     verify.enqueue    pending                  vote entered the batcher
     verify.enqueue_batch  n, pending           whole vote_batch entered as one arrival
     verify.flush      batch, wait_ms, quantum_ms   batcher coalesced a flush
-    verify.dispatch   n, bucket, path, host_prep_ms, device_ms
+    verify.dispatch   n, bucket, path, shards, host_prep_ms, device_ms,
+                      pack_ms, launch_ms, fetch_ms, rows_ms   SPAN, one per engine
+                                               call: host_prep_ms (hash/reduce
+                                               per signature) + device_ms (the
+                                               rest: pack, transfer, launch,
+                                               kernel, copy back) = the call's
+                                               wall time on every path, less
+                                               rows_ms on the table paths (the
+                                               row list built from the caller's
+                                               indices); pack_ms + launch_ms
+                                               (until the jitted call returns) +
+                                               fetch_ms (blocked in np.asarray)
+                                               split device_ms on device paths
     verify.bucket_compile  bucket, ms, ok      background XLA compile done
     verify.chunked    selected, rtt_ms, prep_ms    RTT-probe decision
     verify.table      hit, n                   TableCache lookup
+  commit verify hook (types/validator.py, inside a caller's span only):
+    verify.commit     height, n, sign_bytes_ms, engine_ms, tally_ms   SPAN
+                                               around verify_commit /
+                                               verify_commit_trusting
+  fast sync (fastsync/reactor.py, state/execution.py):
+    fastsync.block    SPAN, one per block applied, id = height: peek_two to
+                      block_processed.  In-span stages parts_ms, verify_ms,
+                      store_ms, apply_ms (sum <= dur_ns); from apply_block
+                      validate_ms, abci_req_ms (BeginBlock's request built),
+                      deliver_ms (BeginBlock's call to Commit's return),
+                      mempool_ms, save_state_ms, events_ms; carried with the
+                      block bytes, decode_ms, download_ms (request to receipt),
+                      queued_ms (receipt to peek_two), peer; wait_ms (since the
+                      previous block's end), pending (blocks queued)
   gossip (consensus/reactor.py, event-driven path):
     gossip.wakeup     peer                     routine woken by an event (not the
                                                fallback sleep cap); HIGH-RATE —
@@ -55,9 +81,12 @@ Event kinds currently emitted:
     loop.lag          lag_ms                   scheduled-vs-actual probe wakeup
                                                delta, once per probe interval
     loop.busy         interval_ms, <category>_ms...   per-category on-CPU task
-                                               time accounted this interval
-                                               (consensus/gossip/p2p-conn/
-                                               verify/mempool/rpc/other)
+                                               time accounted since the last
+                                               loop.busy event, interval_ms
+                                               being the time elapsed since
+                                               then (consensus/gossip/p2p-conn/
+                                               fastsync/verify/mempool/rpc/
+                                               other)
     loop.gc_pause     n, ms, max_ms            GC pauses accumulated this
                                                interval (gc.callbacks hooks)
     loop.queue        <name>=depth...          sampled queue depths (consensus
@@ -89,7 +118,17 @@ Event kinds currently emitted:
     chaos.partition / chaos.kill / chaos.restart ...  scenario events as
                                                executed by the runner
 
-Events are flat dicts: {"seq", "t_ns", "kind", **fields}.  `t_ns` is
+Events are flat dicts: {"seq", "t_ns", "kind", **fields}.  A SPAN is one
+such event, written when the span closes: `t_ns` is its end, `dur_ns` its
+length, `id` what the spans of one request share (a block's height),
+`parent` the kind of the span it was opened in (a contextvars.ContextVar
+holds the open span, so it follows a task through its awaits).  A point
+event recorded inside an open span carries that span's `parent`/`id` too.
+A span's self time is `dur_ns` minus its children's.  Stages of a span are
+FIELDS of its event (`Span.lap`), not events of their own: the ring is
+always on.  While a jax.profiler trace runs, each span is mirrored as a
+TraceAnnotation of the same name on the host plane of that trace (only
+where jax is already imported).  `t_ns` is
 time.monotonic_ns() — deltas are meaningful, wall-clock is not — but the
 recorder also carries a monotonic→wall ANCHOR (sampled at construction
 and re-sampled on every snapshot) so recorders dumped from DIFFERENT
@@ -105,8 +144,9 @@ small nets want.
 
 Performance contract: `record` on a disabled recorder (or the module NOP)
 is one attribute check; enabled it is one uncontended lock, one
-monotonic_ns call, one tuple and one list store — well under a
-microsecond (tests/test_tracing.py tripwires the budget).  Writers may be
+monotonic_ns call, one context lookup, one tuple and one list store — well
+under a microsecond; a span costs about three of those and each lap one
+more (tests/test_tracing.py tripwires both budgets).  Writers may be
 the event loop, the flush executor or warmup threads concurrently; the
 lock makes seq order equal timestamp order, which the span-chain
 consumers rely on.
@@ -114,12 +154,150 @@ consumers rely on.
 
 from __future__ import annotations
 
+import contextvars
 import json
 import os
 import re
+import sys
 import threading
 import time
+import weakref
 from typing import Callable, List, Optional, Sequence
+
+
+#: The innermost open span of the running task (or thread).
+_CURRENT: "contextvars.ContextVar[Optional[Span]]" = contextvars.ContextVar(
+    "tendermint_tpu_span", default=None
+)
+
+# every enabled recorder alive in the process, for a reader that was handed
+# none (benchmarks/reducers/ring_*.py); weak, so a stopped node's goes with it
+_LIVE: "weakref.WeakSet[FlightRecorder]" = weakref.WeakSet()
+
+
+def live_recorders() -> List["FlightRecorder"]:
+    return [r for r in _LIVE if r.enabled]
+
+
+_TRACE_ANNOTATION = None  # jax.profiler.TraceAnnotation, once jax has been imported
+
+
+def _trace_annotation():
+    """jax.profiler.TraceAnnotation where jax is already imported (a node
+    with the engine off imports nothing for a span's sake), else None."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        jax = sys.modules.get("jax")
+        _TRACE_ANNOTATION = getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+    return _TRACE_ANNOTATION
+
+
+class Laps:
+    """Stage stopwatch: each `lap(name)` is one clock read and adds the
+    milliseconds since the previous one (or the start) to `fields[name]`,
+    so a stage that runs in several pieces sums.  Returns the lap, for a
+    second sink (a Prometheus histogram) to take the same reading."""
+
+    __slots__ = ("fields", "_t")
+
+    def __init__(self):
+        self.fields: dict = {}
+        self._t = time.monotonic_ns()
+
+    def lap(self, name: str, at_ns: Optional[int] = None) -> float:
+        """`at_ns`: a `time.monotonic_ns()` reading a callee took where the
+        stage really ended (one not before the previous lap)."""
+        now = time.monotonic_ns() if at_ns is None or at_ns < self._t else at_ns
+        ms = (now - self._t) / 1e6
+        self._t = now
+        self.fields[name] = self.fields.get(name, 0.0) + ms
+        return ms
+
+
+def current_span() -> Optional["Span"]:
+    """The innermost span still open in this task, if any."""
+    span = _CURRENT.get()
+    while span is not None and span.recorder is None:  # closed in another task's copy
+        span = span._outer
+    return span
+
+
+class Span(Laps):
+    """One open span (FlightRecorder.span).  `end()` writes its event;
+    `drop()` closes it without one.  Detached (no recorder: the NOP, a
+    disabled recorder, `child_span` outside any span) it still times its
+    laps for their other sinks, writes nothing and is no one's parent."""
+
+    __slots__ = ("kind", "id", "parent", "t0_ns", "recorder", "_outer", "_hist", "_mirror")
+
+    def __init__(self, rec: Optional["FlightRecorder"], kind: str, id, hist, fields: dict):
+        self.fields = fields
+        self.kind = kind
+        self.t0_ns = self._t = time.monotonic_ns()
+        self.recorder = rec  # None once closed, or detached
+        self._hist = hist
+        self._mirror = None
+        self._outer = outer = current_span()
+        # the spans of one request share its id: the root's
+        self.id = id if outer is None or outer.id is None else outer.id
+        self.parent = None if outer is None else outer.kind
+        if rec is None:
+            return
+        _CURRENT.set(self)
+        annotation = _trace_annotation()
+        if annotation is not None and annotation.is_enabled():
+            self._mirror = annotation(kind) if self.id is None else annotation(kind, id=self.id)
+            self._mirror.__enter__()
+
+    def set(self, **fields) -> None:
+        self.fields.update(fields)
+
+    def _close(self) -> Optional["FlightRecorder"]:
+        rec, self.recorder = self.recorder, None
+        if rec is not None:
+            if self._mirror is not None:
+                self._mirror.__exit__(None, None, None)
+            if _CURRENT.get() is self:
+                _CURRENT.set(self._outer)
+        return rec
+
+    def end(self) -> int:
+        """Close the span; returns its length in ns (`t0_ns` + that is its
+        event's `t_ns`)."""
+        rec = self._close()
+        if rec is not None:
+            dur_ns = rec._store_span(self)
+        else:
+            dur_ns = time.monotonic_ns() - self.t0_ns
+        if self._hist is not None:
+            self._hist.observe(dur_ns / 1e9)
+        return dur_ns
+
+    def drop(self) -> None:
+        self._close()
+
+    def __enter__(self) -> "Span":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end()
+
+
+def child_span(kind: str, **fields) -> Span:
+    """A span on the recorder of the span this task has open: how code with
+    no recorder of its own (types/validator.py) joins its caller's chain.
+    Outside any span it is detached."""
+    outer = current_span()
+    return Span(None if outer is None else outer.recorder, kind, None, None, fields)
+
+
+def annotate(**fields) -> None:
+    """Set fields on the span this task has open, if any: how a callee
+    (BlockExecutor.apply_block) hands its stage times to whichever caller
+    opened one."""
+    outer = current_span()
+    if outer is not None:
+        outer.fields.update(fields)
 
 
 class NopRecorder:
@@ -134,6 +312,11 @@ class NopRecorder:
 
     def record_sampled(self, kind: str, **fields) -> None:
         pass
+
+    def span(self, kind: str, id=None, hist=None, **fields) -> Span:
+        return Span(None, kind, id, hist, fields)
+
+    begin = span
 
     def events(self, since: int = 0, kinds=None) -> List[dict]:
         return []
@@ -152,6 +335,7 @@ class FlightRecorder:
     __slots__ = (
         "size", "enabled", "sample_high_rate", "_buf", "_seq", "_lock",
         "_sample_counts", "_wall_ns_fn", "anchor_mono_ns", "anchor_wall_ns",
+        "__weakref__",
     )
 
     def __init__(
@@ -182,14 +366,46 @@ class FlightRecorder:
         self._wall_ns_fn = wall_ns_fn
         self.anchor_mono_ns = time.monotonic_ns()
         self.anchor_wall_ns = wall_ns_fn()
+        if enabled:
+            _LIVE.add(self)
 
     def record(self, kind: str, **fields) -> None:
         if not self.enabled:
             return
+        span = _CURRENT.get()
+        if span is not None and span.recorder is not None:  # open, in this task
+            fields.setdefault("parent", span.kind)
+            fields.setdefault("id", span.id)
         with self._lock:
             i = self._seq
             self._seq = i + 1
             self._buf[i % self.size] = (i, time.monotonic_ns(), kind, fields)
+
+    def span(self, kind: str, id=None, hist=None, **fields) -> Span:
+        """Open a span: a context manager, or (`begin`) an object to `end()`
+        by hand where a `with` cannot nest.  ONE event is written when it
+        closes: {"kind", "t_ns" (the end), "dur_ns", "id", "parent",
+        **fields}.  `hist` (a Prometheus histogram in seconds) observes the
+        same reading.  Opened inside another span it takes that span's `id`;
+        `id` names the root of a chain."""
+        return Span(self if self.enabled else None, kind, id, hist, fields)
+
+    begin = span
+
+    def _store_span(self, span: Span) -> int:
+        fields = span.fields
+        for name, value in fields.items():
+            if type(value) is float:
+                fields[name] = round(value, 3)
+        fields["id"] = span.id
+        fields["parent"] = span.parent
+        with self._lock:
+            i = self._seq
+            self._seq = i + 1
+            t_ns = time.monotonic_ns()
+            fields["dur_ns"] = dur_ns = t_ns - span.t0_ns
+            self._buf[i % self.size] = (i, t_ns, span.kind, fields)
+        return dur_ns
 
     def record_sampled(self, kind: str, **fields) -> None:
         """1-in-N recording for high-rate kinds (gossip.wakeup fires per
@@ -215,18 +431,21 @@ class FlightRecorder:
     def events(self, since: int = 0, kinds: Optional[Sequence[str]] = None) -> List[dict]:
         """Events still in the ring with seq >= since, oldest first.
         `kinds` filters by prefix match (["gossip.", "step"] keeps every
-        gossip event and the step transitions)."""
-        out = []
+        gossip event and the step transitions).  Costs what it returns: a
+        poller that passes its watermark copies only what is new."""
+        size = self.size
+        with self._lock:
+            end = self._seq
+            start = max(since, end - size, 0)
+            if start >= end:
+                return []
+            lo, hi = start % size, end % size
+            raw = self._buf[lo:hi] if lo < hi else self._buf[lo:] + self._buf[:hi]
         pref = tuple(kinds) if kinds else None
-        for ev in self._buf:
-            if ev is not None and ev[0] >= since:
-                if pref is not None and not ev[2].startswith(pref):
-                    continue
-                out.append(ev)
-        out.sort(key=lambda ev: ev[0])
         return [
             {"seq": seq, "t_ns": t_ns, "kind": kind, **fields}
-            for seq, t_ns, kind, fields in out
+            for seq, t_ns, kind, fields in raw
+            if pref is None or kind.startswith(pref)
         ]
 
     def snapshot(self, since: int = 0, kinds: Optional[Sequence[str]] = None) -> dict:
@@ -255,6 +474,14 @@ class FlightRecorder:
         span-loss number `tendermint_recorder_dropped_total` exports and
         `trace --check` warns about."""
         return max(0, self._seq - self.size)
+
+
+def _pctl(xs: List[float], q: float) -> float:
+    """Nearest-rank percentile (sorted copy; 0 on empty)."""
+    xs = sorted(xs)
+    if not xs:
+        return 0.0
+    return xs[min(len(xs) - 1, int(round(q * (len(xs) - 1))))]
 
 
 def step_chains(events: List[dict]) -> dict:
@@ -367,14 +594,6 @@ BUDGET_STAGES = (
 )
 
 
-def _pctl(xs: List[float], q: float) -> float:
-    """Nearest-rank percentile (sorted copy; 0 on empty)."""
-    xs = sorted(xs)
-    if not xs:
-        return 0.0
-    return xs[min(len(xs) - 1, int(round(q * (len(xs) - 1))))]
-
-
 def stage_budget(events: List[dict]) -> Optional[dict]:
     """Decompose committed heights into a per-stage latency budget from
     flight-recorder spans: propose→prevote→precommit→commit(persist)→
@@ -445,6 +664,77 @@ def format_budget(budget: Optional[dict]) -> str:
         lines.append(
             f"  {name:<15}{st['n']:>5}{st['p50_ms']:>10.3f}"
             f"{st['p90_ms']:>10.3f}{st['max_ms']:>10.3f}"
+        )
+    return "\n".join(lines)
+
+
+#: The rows of the replay budget, outermost first: what tiles a block's
+#: interval (the wait since the block before, then the in-span stages),
+#: apply_block's stages inside apply_ms, the two commit verifications inside
+#: verify_ms and validate_ms, the engine's calls inside those, and what the
+#: receive path measured before the block was queued.
+REPLAY_ROWS = (
+    ("fastsync.block", ("wait_ms", "parts_ms", "verify_ms", "store_ms", "apply_ms")),
+    ("fastsync.block", ("validate_ms", "abci_req_ms", "deliver_ms", "mempool_ms",
+                        "save_state_ms", "events_ms")),
+    ("verify.commit", ("sign_bytes_ms", "engine_ms", "tally_ms")),
+    ("verify.dispatch", ("rows_ms", "host_prep_ms", "pack_ms", "launch_ms", "fetch_ms")),
+    ("fastsync.block", ("decode_ms", "download_ms", "queued_ms")),
+)
+
+
+def replay_budget(events: List[dict]) -> Optional[dict]:
+    """Where a joining node's blocks spend their milliseconds, from the
+    `fastsync.block` span chain: per block applied, each stage's mean, p50
+    and p90, a child span's fields summed over that block's children (two
+    commits, two dispatches).  `interval_ms` is the mean of a block's length
+    plus its wait: the block interval the replay loop saw.  None without a
+    `fastsync.block` event."""
+    blocks = [ev for ev in events if ev.get("kind") == "fastsync.block"]
+    if not blocks:
+        return None
+    heights = {ev["id"] for ev in blocks}
+    children: dict = {}  # (kind, height) -> its events
+    for ev in events:
+        if ev.get("kind") in ("verify.commit", "verify.dispatch") and ev.get("id") in heights:
+            children.setdefault((ev["kind"], ev["id"]), []).append(ev)
+    out: dict = {
+        "source": "flight_recorder", "blocks": len(blocks),
+        "heights": [blocks[0]["id"], blocks[-1]["id"]],
+        "interval_ms": round(
+            sum(ev["dur_ns"] / 1e6 + ev.get("wait_ms", 0.0) for ev in blocks) / len(blocks), 3),
+        "stages": {},
+    }
+    rows = [("block_ms", [ev["dur_ns"] / 1e6 for ev in blocks])]
+    for kind, names in REPLAY_ROWS:
+        own = kind == "fastsync.block"
+        prefix = "" if own else kind.split(".")[1] + "."
+        for name in names:
+            rows.append((prefix + name, [
+                sum(e.get(name, 0.0) for e in ((ev,) if own else children.get((kind, ev["id"]), ())))
+                for ev in blocks
+            ]))
+    for name, xs in rows:
+        if any(xs):
+            out["stages"][name] = {
+                "mean_ms": round(sum(xs) / len(xs), 3),
+                "p50_ms": round(_pctl(xs, 0.5), 3), "p90_ms": round(_pctl(xs, 0.9), 3),
+            }
+    return out
+
+
+def format_replay_budget(budget: Optional[dict]) -> str:
+    """Aligned rendering of a replay_budget dict (`trace --replay`)."""
+    if budget is None:
+        return "no fastsync.block span — nothing to budget (the node is not fast-syncing)"
+    lines = [
+        f"replay budget over {budget['blocks']} blocks (heights {budget['heights'][0]}.."
+        f"{budget['heights'][1]}), block interval {budget['interval_ms']} ms",
+        f"  {'stage, per block':<26}{'mean ms':>10}{'p50 ms':>10}{'p90 ms':>10}",
+    ]
+    for name, st in budget["stages"].items():
+        lines.append(
+            f"  {name:<26}{st['mean_ms']:>10.3f}{st['p50_ms']:>10.3f}{st['p90_ms']:>10.3f}"
         )
     return "\n".join(lines)
 

@@ -596,6 +596,7 @@ class Node(Service):
                 self.proxy_app, syncer=syncer, on_done=self._statesync_done
             )
             self.blockchain_reactor.statesync_metrics = self.metrics_provider.statesync
+            self.blockchain_reactor.recorder = self.flight_recorder
             if do_fast_sync and not do_state_sync:
                 self.metrics_provider.statesync.sync_phase.set(
                     self.metrics_provider.statesync.PHASE_FASTSYNC

@@ -7,11 +7,13 @@ updateState:384, fireEvents:449, ExecCommitBlock:488).
 
 from __future__ import annotations
 
+import time
 from dataclasses import replace
 from typing import List, Optional, Tuple
 
 from ..abci import types as abci
 from ..crypto.keys import Ed25519PubKey
+from ..libs import tracing
 from ..libs.fail import fail_point
 from ..libs.log import get_logger
 from ..types import Block, BlockID, Commit, Validator
@@ -104,6 +106,7 @@ class BlockExecutor:
         self.event_bus = event_bus
         self.metrics = metrics
         self.log = get_logger("state")
+        self._abci_committed_ns = 0
 
     # -- proposal creation -------------------------------------------------
     def create_proposal_block(
@@ -131,14 +134,16 @@ class BlockExecutor:
         """state/execution.go:126 ApplyBlock: validate → exec over ABCI →
         save responses → validator updates → commit+mempool update →
         save state → fire events.  Returns (new_state, retain_height)."""
+        # One reading per stage, for every caller: fast sync's `fastsync.block`
+        # span takes them as fields (tracing.annotate: whichever span is open),
+        # the Prometheus histogram takes the same BeginBlock..EndBlock reading.
+        laps = tracing.Laps()
         self.validate_block(state, block)
+        laps.lap("validate_ms")
 
-        import time as _time
-
-        _t0 = _time.perf_counter()
-        abci_responses = await self._exec_block_on_proxy_app(state, block)
+        abci_responses = await self._exec_block_on_proxy_app(state, block, laps)
         if self.metrics is not None:
-            self.metrics.block_processing_time.observe((_time.perf_counter() - _t0) * 1000)
+            self.metrics.block_processing_time.observe(laps.fields["deliver_ms"])
         fail_point("applyblock-saved-responses")
         self.state_store.save_abci_responses(block.height, _responses_to_dict(abci_responses))
         fail_point("applyblock-validated-updates")
@@ -152,6 +157,11 @@ class BlockExecutor:
         state = update_state(state, block_id, block, abci_responses, validator_updates)
 
         app_hash, retain_height = await self.commit(state, block, abci_responses["deliver_txs"])
+        # BeginBlock's call to ABCI Commit's return (execution, the responses'
+        # save and the state's update between them included), then the
+        # mempool's update under its lock
+        laps.lap("deliver_ms", at_ns=self._abci_committed_ns)
+        laps.lap("mempool_ms")
 
         if self.evidence_pool is not None:
             self.evidence_pool.update(block, state)
@@ -160,8 +170,11 @@ class BlockExecutor:
         state = replace(state, app_hash=app_hash)
         self.state_store.save(state)
         fail_point("applyblock-saved-state")
+        laps.lap("save_state_ms")
 
         await self._fire_events(block, abci_responses, validator_updates)
+        laps.lap("events_ms")
+        tracing.annotate(**laps.fields)
         return state, retain_height
 
     async def commit(
@@ -172,6 +185,7 @@ class BlockExecutor:
         async with self.mempool.lock():
             await self.mempool.flush_app_conn()
             res = await self.proxy_app.commit()
+            self._abci_committed_ns = time.monotonic_ns()  # where apply_block's deliver_ms ends
             self.log.info(
                 "committed state",
                 height=block.height,
@@ -187,24 +201,29 @@ class BlockExecutor:
             )
         return res.data, res.retain_height
 
-    async def _exec_block_on_proxy_app(self, state: State, block: Block) -> dict:
-        """BeginBlock → DeliverTx×N → EndBlock (state/execution.go:248)."""
-        commit_info = self._begin_block_validator_info(state, block)
-        begin = await self.proxy_app.begin_block(
-            abci.RequestBeginBlock(
-                hash=block.hash(),
-                header=block.header.to_dict(),
-                last_commit_info=commit_info,
-                byzantine_validators=[
-                    {
-                        "height": ev.height(),
-                        "time_ns": ev.time_ns(),
-                        "address": ev.address(),
-                    }
-                    for ev in block.evidence
-                ],
-            )
+    async def _exec_block_on_proxy_app(
+        self, state: State, block: Block, laps: Optional[tracing.Laps] = None
+    ) -> dict:
+        """BeginBlock → DeliverTx×N → EndBlock (state/execution.go:248).
+        `laps` gets `abci_req_ms` (building BeginBlock's request: one entry
+        per validator) and `deliver_ms` (BeginBlock's call to EndBlock's
+        return)."""
+        laps = laps or tracing.Laps()  # handshake replay passes none
+        request = abci.RequestBeginBlock(
+            hash=block.hash(),
+            header=block.header.to_dict(),
+            last_commit_info=self._begin_block_validator_info(state, block),
+            byzantine_validators=[
+                {
+                    "height": ev.height(),
+                    "time_ns": ev.time_ns(),
+                    "address": ev.address(),
+                }
+                for ev in block.evidence
+            ],
         )
+        laps.lap("abci_req_ms")
+        begin = await self.proxy_app.begin_block(request)
         deliver_txs = []
         valid = invalid = 0
         for tx in block.txs:
@@ -215,6 +234,7 @@ class BlockExecutor:
                 invalid += 1
             deliver_txs.append(r)
         end = await self.proxy_app.end_block(abci.RequestEndBlock(height=block.height))
+        laps.lap("deliver_ms")
         self.log.info("executed block", height=block.height, valid_txs=valid, invalid_txs=invalid)
         return {"begin_block": begin, "deliver_txs": deliver_txs, "end_block": end}
 

@@ -25,6 +25,7 @@ from ..crypto import merkle
 from ..crypto.keys import PubKey, pubkey_from_dict
 from ..encoding import codec
 from ..encoding.proto import field_bytes, field_varint
+from ..libs import tracing
 from .block import BlockID, Commit
 
 INT64_MAX = (1 << 63) - 1
@@ -544,38 +545,43 @@ class ValidatorSet:
             )
         _verify_commit_basic(commit, height, block_id)
 
-        idxs, pubkeys, msgs, sigs = [], [], [], []
-        for idx, cs in enumerate(commit.signatures):
-            if cs.is_absent():
-                continue
-            idxs.append(idx)
-            pk = self.validators[idx].pub_key
-            pubkeys.append(pk)
-            msgs.append(commit.vote_sign_bytes(chain_id, idx, pub_key=pk))
-            sigs.append(cs.signature)
+        with tracing.child_span("verify.commit", height=height) as span:
+            idxs, pubkeys, msgs, sigs = [], [], [], []
+            for idx, cs in enumerate(commit.signatures):
+                if cs.is_absent():
+                    continue
+                idxs.append(idx)
+                pk = self.validators[idx].pub_key
+                pubkeys.append(pk)
+                msgs.append(commit.vote_sign_bytes(chain_id, idx, pub_key=pk))
+                sigs.append(cs.signature)
+            span.set(n=len(sigs))
+            span.lap("sign_bytes_ms")
 
-        indexed = None
-        if crypto_batch.get_indexed_verifier() is not None:
-            # signatures align with set rows: validator index IS the row.
-            # Rows are passed lazily — a table-cache hit (the steady state)
-            # never materializes the V-sized list.
-            indexed = (
-                self.pubkeys_digest(),
-                lambda: [v.pub_key.bytes() for v in self.validators],
-                idxs,
-            )
-        ok = mixed_batch_verify(pubkeys, msgs, sigs, batch_verify, indexed=indexed)
+            indexed = None
+            if crypto_batch.get_indexed_verifier() is not None:
+                # signatures align with set rows: validator index IS the row.
+                # Rows are passed lazily — a table-cache hit (the steady state)
+                # never materializes the V-sized list.
+                indexed = (
+                    self.pubkeys_digest(),
+                    lambda: [v.pub_key.bytes() for v in self.validators],
+                    idxs,
+                )
+            ok = mixed_batch_verify(pubkeys, msgs, sigs, batch_verify, indexed=indexed)
+            span.lap("engine_ms")
 
-        tallied = 0
-        needed = self.total_voting_power() * 2 // 3
-        for pos, idx in enumerate(idxs):
-            if not ok[pos]:
-                raise ValueError(f"wrong signature (#{idx}): {sigs[pos].hex()}")
-            cs = commit.signatures[idx]
-            # Stray signatures (votes for nil) are valid but don't count
-            # toward the block's power (validator_set.go:656-662).
-            if block_id == cs.block_id(commit.block_id):
-                tallied += self.validators[idx].voting_power
+            tallied = 0
+            needed = self.total_voting_power() * 2 // 3
+            for pos, idx in enumerate(idxs):
+                if not ok[pos]:
+                    raise ValueError(f"wrong signature (#{idx}): {sigs[pos].hex()}")
+                cs = commit.signatures[idx]
+                # Stray signatures (votes for nil) are valid but don't count
+                # toward the block's power (validator_set.go:656-662).
+                if block_id == cs.block_id(commit.block_id):
+                    tallied += self.validators[idx].voting_power
+            span.lap("tally_ms")
         if tallied <= needed:
             raise NotEnoughVotingPowerError(got=tallied, needed=needed)
 
@@ -668,41 +674,46 @@ class ValidatorSet:
             return
         _verify_commit_basic(commit, height, block_id)
 
-        seen_vals = {}
-        idxs, row_idxs, powers, pubkeys, msgs, sigs = [], [], [], [], [], []
-        for idx, cs in enumerate(commit.signatures):
-            if cs.is_absent():
-                continue
-            val_idx, val = self.get_by_address(cs.validator_address)
-            if val is None:
-                continue
-            if val_idx in seen_vals:
-                raise ValueError(f"double vote from {val} ({seen_vals[val_idx]} and {idx})")
-            seen_vals[val_idx] = idx
-            idxs.append(idx)
-            row_idxs.append(val_idx)
-            powers.append(val.voting_power)
-            pubkeys.append(val.pub_key)
-            msgs.append(commit.vote_sign_bytes(chain_id, idx, pub_key=val.pub_key))
-            sigs.append(cs.signature)
+        with tracing.child_span("verify.commit", height=height) as span:
+            seen_vals = {}
+            idxs, row_idxs, powers, pubkeys, msgs, sigs = [], [], [], [], [], []
+            for idx, cs in enumerate(commit.signatures):
+                if cs.is_absent():
+                    continue
+                val_idx, val = self.get_by_address(cs.validator_address)
+                if val is None:
+                    continue
+                if val_idx in seen_vals:
+                    raise ValueError(f"double vote from {val} ({seen_vals[val_idx]} and {idx})")
+                seen_vals[val_idx] = idx
+                idxs.append(idx)
+                row_idxs.append(val_idx)
+                powers.append(val.voting_power)
+                pubkeys.append(val.pub_key)
+                msgs.append(commit.vote_sign_bytes(chain_id, idx, pub_key=val.pub_key))
+                sigs.append(cs.signature)
+            span.set(n=len(sigs))
+            span.lap("sign_bytes_ms")
 
-        indexed = None
-        if crypto_batch.get_indexed_verifier() is not None:
-            indexed = (
-                self.pubkeys_digest(),
-                lambda: [v.pub_key.bytes() for v in self.validators],
-                row_idxs,
-            )
-        ok = mixed_batch_verify(pubkeys, msgs, sigs, batch_verify, indexed=indexed)
+            indexed = None
+            if crypto_batch.get_indexed_verifier() is not None:
+                indexed = (
+                    self.pubkeys_digest(),
+                    lambda: [v.pub_key.bytes() for v in self.validators],
+                    row_idxs,
+                )
+            ok = mixed_batch_verify(pubkeys, msgs, sigs, batch_verify, indexed=indexed)
+            span.lap("engine_ms")
 
-        tallied = 0
-        needed = self.total_voting_power() * trust_numerator // trust_denominator
-        for pos, idx in enumerate(idxs):
-            if not ok[pos]:
-                raise ValueError(f"wrong signature (#{idx}): {sigs[pos].hex()}")
-            cs = commit.signatures[idx]
-            if block_id == cs.block_id(commit.block_id):
-                tallied += powers[pos]
+            tallied = 0
+            needed = self.total_voting_power() * trust_numerator // trust_denominator
+            for pos, idx in enumerate(idxs):
+                if not ok[pos]:
+                    raise ValueError(f"wrong signature (#{idx}): {sigs[pos].hex()}")
+                cs = commit.signatures[idx]
+                if block_id == cs.block_id(commit.block_id):
+                    tallied += powers[pos]
+            span.lap("tally_ms")
         if tallied <= needed:
             raise NotEnoughVotingPowerError(got=tallied, needed=needed)
 

@@ -9,7 +9,9 @@ import pytest
 from tendermint_tpu.config import test_config as make_test_cfg
 from tendermint_tpu.fastsync.processor import Processor, verify_commit_run
 from tendermint_tpu.fastsync.scheduler import Scheduler
+from tendermint_tpu.libs import tracing
 from tendermint_tpu.node import Node
+from tendermint_tpu.rpc.core import RPCCore
 from tendermint_tpu.types import GenesisDoc, GenesisValidator, MockPV
 
 from tests.test_consensus_net import CHAIN_ID, make_net, stop_net, wait_all_height
@@ -181,6 +183,93 @@ class TestFastSyncNet:
             await syncer.stop()
         finally:
             await stop_net(nodes)
+
+
+class TestReplaySpans:
+    async def test_every_applied_block_leaves_its_span_chain(self, tmp_path):
+        """A joining node's flight recorder, read as `dump_flight_recorder`
+        serves it: one `fastsync.block` per block applied, its in-span
+        stages inside its length, what the receive path and `apply_block`
+        measured carried as fields, and the two commit verifications of
+        that block (its own, by the next block's LastCommit; its LastCommit,
+        in validation) under its height."""
+        nodes, pvs = await make_net(tmp_path, 3, name="fsspan")
+        try:
+            await wait_all_height(nodes, 8)
+            cfg = make_test_cfg(str(tmp_path / "syncer"))
+            cfg.rpc.laddr = ""
+            cfg.base.db_backend = "memdb"
+            cfg.base.fast_sync = True
+            cfg.p2p.laddr = "127.0.0.1:0"
+            gen = GenesisDoc(
+                chain_id=CHAIN_ID,
+                genesis_time_ns=1_700_000_000_000_000_000,
+                validators=[
+                    GenesisValidator(pv.address(), pv.get_pub_key(), 10) for pv in pvs
+                ],
+                consensus_params=_FAST_IOTA_PARAMS,
+            )
+            syncer = Node(cfg, gen, priv_validator=None, db_backend="memdb")
+            await syncer.start()
+            try:
+                addr = f"{nodes[0].node_key.id}@{nodes[0].switch.transport.listen_addr}"
+                await syncer.switch.dial_peer(addr)
+
+                async def replayed():
+                    while syncer.blockchain_reactor.blocks_synced < 6:
+                        await asyncio.sleep(0.02)
+
+                await asyncio.wait_for(replayed(), 60.0)
+                applied = syncer.blockchain_reactor.blocks_synced
+                events = (await RPCCore(syncer).call("dump_flight_recorder"))["events"]
+            finally:
+                await syncer.stop()
+        finally:
+            await stop_net(nodes)
+
+        blocks = [e for e in events if e["kind"] == "fastsync.block"]
+        assert len(blocks) >= applied - 1  # one more may have been applied since the read
+        heights = [e["id"] for e in blocks]
+        assert heights == list(range(heights[0], heights[0] + len(blocks)))
+        commits = [e for e in events if e["kind"] == "verify.commit"]
+        for ev in blocks:
+            stages = ev["parts_ms"] + ev["verify_ms"] + ev["store_ms"] + ev["apply_ms"]
+            assert 0 < stages <= ev["dur_ns"] / 1e6 + 0.005
+            inside = ev["validate_ms"] + ev["abci_req_ms"] + ev["deliver_ms"] \
+                + ev["mempool_ms"] + ev["save_state_ms"] + ev["events_ms"]
+            assert 0 < inside <= ev["apply_ms"] + 0.005
+            assert ev["parent"] is None and ev["pending"] >= 2
+            assert ev["bytes"] > 0 and ev["decode_ms"] > 0 and len(ev["peer"]) == 8
+            assert ev["download_ms"] >= 0 and ev["queued_ms"] >= 0
+            mine = [c for c in commits if c["id"] == ev["id"]]
+            # the block's own commit (height H), then its LastCommit (H - 1;
+            # the first block of a chain has none to verify)
+            assert [c["height"] for c in mine] == (
+                [ev["id"]] if ev["id"] == 1 else [ev["id"], ev["id"] - 1])
+            for c in mine:
+                assert c["parent"] == "fastsync.block" and "ok" not in c
+                assert c["n"] == 3 and c["t_ns"] <= ev["t_ns"]
+                assert c["sign_bytes_ms"] + c["engine_ms"] + c["tally_ms"] <= c["dur_ns"] / 1e6 + 0.005
+        # consecutive spans tile the replay loop's time: a block's wait is
+        # the gap since the one before it
+        for prev, ev in zip(blocks, blocks[1:]):
+            gap_ms = (ev["t_ns"] - ev["dur_ns"] - prev["t_ns"]) / 1e6
+            assert ev["wait_ms"] == pytest.approx(gap_ms, abs=0.002)
+        assert "wait_ms" not in blocks[0]
+        # every dispatch and table lookup inside says which block it served
+        for e in events:
+            if e["kind"] in ("verify.dispatch", "verify.table") and "parent" in e:
+                assert e["parent"] == "verify.commit" and e["id"] in heights
+        # `trace --replay` on that dump: the chain per height, stage by stage
+        budget = tracing.replay_budget(events)
+        assert budget["blocks"] == len(blocks) and budget["heights"] == [heights[0], heights[-1]]
+        assert {"block_ms", "parts_ms", "verify_ms", "store_ms", "apply_ms", "deliver_ms",
+                "commit.sign_bytes_ms", "commit.engine_ms"} <= set(budget["stages"])
+        # no more than 8 events a block, the ring's budget at the hub's rate
+        per_block = [e for e in events if e.get("id") in heights]
+        assert len(per_block) <= 8 * len(blocks)
+        # consensus's deliver.* events stay consensus's: fast sync adds none
+        assert not [e for e in events if e["kind"].startswith("deliver.") and e["height"] in heights]
 
 
 class TestBehaviourReporting:

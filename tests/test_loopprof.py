@@ -28,6 +28,14 @@ class TestCategorize:
         assert loopprof.categorize("RPCServer") == "rpc"
         assert loopprof.categorize("SomethingElse") == "other"
 
+    def test_the_replay_loop_has_a_category_of_its_own(self):
+        # `Service.spawn` names the reactor's tasks "blockchain-reactor/<task>"
+        for task in ("pool", "status-bcast", "refill"):
+            assert loopprof.categorize("blockchain-reactor", task) == "fastsync"
+        assert "fastsync" in loopprof.CATEGORIES
+        # a peer's receive task, where a block response is decoded, stays p2p's
+        assert loopprof.categorize("mconn", "recv") == "p2p-conn"
+
     def test_every_rule_lands_in_a_known_category(self):
         for _, cat in loopprof._RULES:
             assert cat in loopprof.CATEGORIES
@@ -215,6 +223,38 @@ class TestProbe:
         assert prof.gc_total_ms >= 0
         assert snap["lag_samples"] > 0
         assert snap["owns_hooks"] is True
+
+    async def test_busy_interval_is_the_time_elapsed_not_the_nominal_one(self):
+        """A task that holds the loop for three probe intervals: the
+        `loop.busy` event that accounts it says how long it has really been
+        since the last one, so the category's share cannot pass 100%."""
+        rec = FlightRecorder(size=512)
+        interval = 0.02
+        prof = LoopProfiler(interval=interval, recorder=rec)
+        await prof.start()
+        try:
+            async def hold():
+                await asyncio.sleep(interval / 2)  # let the probe arm its timer first
+                t0 = time.perf_counter()
+                while time.perf_counter() - t0 < 3 * interval:
+                    pass  # no await: the probe cannot tick meanwhile
+
+            t_start = time.monotonic_ns()
+            await prof.wrap(hold(), "fastsync")
+            await asyncio.sleep(3 * interval)
+            t_end = time.monotonic_ns()
+        finally:
+            await prof.stop()
+        busy = [e for e in rec.events() if e["kind"] == "loop.busy"]
+        held = max(busy, key=lambda e: e.get("fastsync_ms", 0.0))
+        assert held["fastsync_ms"] >= 3 * interval * 1e3
+        assert held["interval_ms"] >= held["fastsync_ms"]  # was the nominal 20.0
+        assert all(
+            sum(loopprof.busy_categories(e).values()) <= e["interval_ms"] + 0.5 for e in busy
+        )
+        # the events tile the loop's time: their intervals sum to what elapsed
+        assert sum(e["interval_ms"] for e in busy) <= (t_end - t_start) / 1e6 + 0.5
+        assert sum(e["interval_ms"] for e in busy) >= 3 * interval * 1e3
 
     def test_lag_histogram_p90(self):
         prof = LoopProfiler()

@@ -2,8 +2,11 @@
 span-chain analysis, the dump_flight_recorder RPC route and the
 verify-engine event stream."""
 
+import asyncio
 import os
 import time
+
+import pytest
 
 from tendermint_tpu.libs import tracing
 from tendermint_tpu.libs.tracing import FlightRecorder, NopRecorder
@@ -37,6 +40,29 @@ class TestRing:
         fresh = r.events(since=snap["next_seq"])
         assert [e["height"] for e in fresh] == [10]
 
+    def test_events_since_costs_and_returns_what_a_full_walk_would(self):
+        """events(since) indexes the ring from max(since, seq - size): the
+        same answer as walking and sorting all of it, before the ring is
+        full, across the wrap and far beyond it."""
+
+        def full_walk(r, since, kinds):
+            pref = tuple(kinds) if kinds else None
+            kept = sorted(
+                (ev for ev in r._buf if ev is not None and ev[0] >= since
+                 and (pref is None or ev[2].startswith(pref))),
+                key=lambda ev: ev[0],
+            )
+            return [{"seq": q, "t_ns": t, "kind": k, **f} for q, t, k, f in kept]
+
+        r = FlightRecorder(size=8)
+        for i in range(21):
+            r.record("step" if i % 3 else "verify.flush", height=i)
+            for since in (0, i - 9, i - 3, i, i + 1, i + 5):
+                for kinds in (None, ["verify."]):
+                    assert r.events(max(since, 0), kinds) == full_walk(r, max(since, 0), kinds)
+        assert [e["seq"] for e in r.events(since=18)] == [18, 19, 20]
+        assert r.events(since=21) == []
+
     def test_disabled_and_nop_record_nothing(self):
         for r in (FlightRecorder(size=8, enabled=False), NopRecorder()):
             r.record("step", height=1)
@@ -53,6 +79,189 @@ class TestRing:
             r.record("verify.flush", batch=4, wait_ms=0.2, quantum_ms=0.2)
         per_event = (time.perf_counter() - t0) / n
         assert per_event < 5e-6, f"record() took {per_event * 1e6:.2f} us/event"
+
+
+class _Hist:
+    def __init__(self):
+        self.seen = []
+
+    def observe(self, v):
+        self.seen.append(v)
+
+
+class TestSpans:
+    def test_one_event_when_it_closes_with_the_span_shape(self):
+        r = FlightRecorder(size=64)
+        t0 = time.monotonic_ns()
+        with r.span("fastsync.block", id=7, pending=3) as span:
+            assert r.events() == []  # nothing until it closes
+            time.sleep(0.002)
+            assert span.lap("parts_ms") >= 2.0
+            span.lap("verify_ms")
+            span.set(peer="abcd")
+        t1 = time.monotonic_ns()
+        (ev,) = r.events()
+        assert ev["kind"] == "fastsync.block" and ev["id"] == 7 and ev["parent"] is None
+        assert ev["pending"] == 3 and ev["peer"] == "abcd"
+        assert t0 <= ev["t_ns"] - ev["dur_ns"] and ev["t_ns"] <= t1  # t_ns is the end
+        # laps tile the span from its start: they sum to no more than it
+        assert 2.0 <= ev["parts_ms"] + ev["verify_ms"] <= ev["dur_ns"] / 1e6 + 0.001
+        assert ev["parts_ms"] == round(ev["parts_ms"], 3)
+
+    def test_a_lap_taken_twice_sums(self):
+        laps = tracing.Laps()
+        first = laps.lap("fetch_ms")
+        time.sleep(0.001)
+        second = laps.lap("fetch_ms")
+        assert second >= 1.0
+        assert laps.fields == {"fetch_ms": pytest.approx(first + second)}
+
+    def test_a_lap_can_end_at_a_reading_its_callee_took(self):
+        laps = tracing.Laps()
+        time.sleep(0.001)
+        mark = time.monotonic_ns()  # taken inside the callee, where the stage ended
+        time.sleep(0.002)
+        deliver, rest = laps.lap("deliver_ms", at_ns=mark), laps.lap("mempool_ms")
+        assert 1.0 <= deliver < rest and rest >= 2.0
+        # a stale reading (the callee was replaced and never took one) is not used
+        assert laps.lap("save_state_ms", at_ns=mark) >= 0.0
+
+    def test_nested_spans_and_point_events_carry_parent_and_id(self):
+        r = FlightRecorder(size=64)
+        with r.span("fastsync.block", id=12):
+            with tracing.child_span("verify.commit", height=11) as commit:
+                assert commit.recorder is r
+                r.record("verify.table", hit=True, n=4)
+                with r.span("verify.dispatch", id=999, n=4):  # the root names the id
+                    pass
+            tracing.annotate(deliver_ms=1.5)
+        r.record("verify.table", hit=True, n=4)  # outside: no parent
+        table, dispatch, commit, block, outside = r.events()
+        assert (table["parent"], table["id"]) == ("verify.commit", 12)
+        assert (dispatch["parent"], dispatch["id"]) == ("verify.commit", 12)
+        assert (commit["parent"], commit["id"], commit["height"]) == ("fastsync.block", 12, 11)
+        assert block["parent"] is None and block["deliver_ms"] == 1.5
+        assert "parent" not in outside and "id" not in outside
+        assert tracing.current_span() is None
+        # self time: a span's length minus its children's
+        assert block["dur_ns"] >= commit["dur_ns"] >= dispatch["dur_ns"]
+
+    async def test_parent_and_id_follow_a_task_through_an_await(self):
+        r = FlightRecorder(size=64)
+
+        async def replay(height):
+            with r.span("fastsync.block", id=height):
+                await asyncio.sleep(0.001)  # the other task runs meanwhile
+                with tracing.child_span("verify.commit", height=height):
+                    await asyncio.sleep(0.001)
+                    r.record("verify.table", hit=True, n=1)
+
+        await asyncio.gather(replay(5), replay(6))
+        by_kind = {}
+        for ev in r.events():
+            by_kind.setdefault(ev["kind"], []).append(ev)
+        assert sorted(e["id"] for e in by_kind["verify.table"]) == [5, 6]
+        assert all(e["parent"] == "verify.commit" for e in by_kind["verify.table"])
+        assert all(e["id"] == e["height"] for e in by_kind["verify.commit"])
+
+    async def test_a_task_started_inside_a_span_outlives_it_without_a_parent(self):
+        r = FlightRecorder(size=64)
+        go = asyncio.Event()
+
+        async def later():
+            await go.wait()
+            r.record("commit", height=1)
+            with r.span("fastsync.block", id=2):
+                pass
+
+        with r.span("fastsync.block", id=1):
+            task = asyncio.ensure_future(later())  # copies the context, span and all
+        go.set()
+        await task
+        _, point, own = r.events()
+        assert "parent" not in point
+        assert own["id"] == 2 and own["parent"] is None
+
+    def test_begin_end_and_drop(self):
+        r = FlightRecorder(size=64)
+        span = r.begin("fastsync.block", id=3)
+        assert tracing.current_span() is span
+        assert span.end() == r.events()[0]["dur_ns"]
+        dropped = r.begin("fastsync.block", id=4)
+        dropped.drop()
+        assert tracing.current_span() is None
+        assert [e["id"] for e in r.events()] == [3]
+        span.end()  # closing twice writes nothing more
+        assert len(r.events()) == 1
+
+    def test_the_histogram_takes_the_same_reading(self):
+        r, hist = FlightRecorder(size=64), _Hist()
+        with r.span("verify.commit", hist=hist):
+            time.sleep(0.001)
+        assert hist.seen == [r.events()[0]["dur_ns"] / 1e9]
+
+    def test_detached_spans_time_their_laps_and_record_nothing(self):
+        hist = _Hist()
+        for r in (FlightRecorder(size=8, enabled=False), NopRecorder()):
+            with r.span("verify.dispatch", hist=hist, n=1) as span:
+                time.sleep(0.001)
+                assert span.lap("device_ms") >= 1.0  # the other sink still reads it
+                assert tracing.current_span() is None  # and it is no one's parent
+            assert r.events() == []
+        assert len(hist.seen) == 2 and min(hist.seen) >= 0.001
+        with tracing.child_span("verify.commit") as span:  # no caller's span to join
+            assert span.recorder is None
+
+    def test_live_recorders_holds_the_enabled_ones_weakly(self):
+        import gc
+
+        on, off = FlightRecorder(size=8), FlightRecorder(size=8, enabled=False)
+        assert on in tracing.live_recorders() and off not in tracing.live_recorders()
+        ident = id(on)
+        del on
+        gc.collect()
+        assert ident not in {id(r) for r in tracing.live_recorders()}
+
+    def test_span_overhead_budget(self):
+        # contract: a span with two laps costs about three record() calls;
+        # tripwire at 15 us (record()'s is 5) so CI-host noise can't flake
+        r = FlightRecorder(size=4096)
+        n = 20_000
+        t0 = time.perf_counter()
+        for i in range(n):
+            with r.span("fastsync.block", id=i, pending=2) as span:
+                span.lap("parts_ms")
+                span.lap("verify_ms")
+        per_span = (time.perf_counter() - t0) / n
+        assert per_span < 15e-6, f"span() took {per_span * 1e6:.2f} us"
+
+    def test_mirrored_on_the_host_plane_of_a_running_profile(self, tmp_path):
+        """While a jax.profiler trace runs, a span is a TraceAnnotation of
+        the same name: host stages and device ops on one clock."""
+        import jax
+
+        from benchmarks import trace as tracelib
+
+        r = FlightRecorder(size=64)
+        with r.span("fastsync.block", id=1):
+            pass  # no trace running: nothing to mirror
+        anchor_ns = tracelib.start(str(tmp_path))
+        try:
+            with r.span("fastsync.block", id=2):
+                with tracing.child_span("verify.commit", height=2):
+                    time.sleep(0.002)
+        finally:
+            jax.profiler.stop_trace()
+        events = tracelib.load_xplane(str(tmp_path))
+        shift = anchor_ns - next(ev for ev in events if ev[2] == tracelib.ANCHOR)[3]
+        named = {ev[2]: ev for ev in events if ev[2] in ("fastsync.block", "verify.commit")}
+        assert set(named) == {"fastsync.block", "verify.commit"}
+        assert all(ev[0].startswith("/host:") for ev in named.values())
+        assert sum(ev[2] == "fastsync.block" for ev in events) == 1
+        # the recorder's monotonic clock and the profile's, anchored, agree
+        block = r.events()[-1]
+        start_ns = named["fastsync.block"][3] + shift
+        assert abs(start_ns - (block["t_ns"] - block["dur_ns"])) < 1e6
 
 
 class TestSampling:
@@ -213,6 +422,55 @@ class TestSpanChains:
         assert tracing.block_breakdown([]) is None
 
 
+class TestReplayBudget:
+    def _events(self):
+        """Two blocks by hand: 10 ms and 20 ms long, the second 5 ms after
+        the first; two commits and two dispatches under each."""
+        evs, t = [], 1_000_000_000
+        for height, scale, wait in ((7, 1.0, None), (8, 2.0, 5.0)):
+            t += int((wait or 0) * 1e6) + int(10e6 * scale)
+            for h in (height, height - 1):
+                evs.append({"kind": "verify.dispatch", "t_ns": t, "id": height,
+                            "parent": "verify.commit", "n": 3, "path": "indexed",
+                            "host_prep_ms": 0.25 * scale, "device_ms": 1.0 * scale,
+                            "pack_ms": 0.1 * scale, "launch_ms": 0.4 * scale,
+                            "fetch_ms": 0.5 * scale, "dur_ns": int(1.25e6 * scale)})
+                evs.append({"kind": "verify.commit", "t_ns": t, "id": height, "height": h,
+                            "parent": "fastsync.block", "n": 3, "sign_bytes_ms": 0.5 * scale,
+                            "engine_ms": 1.5 * scale, "tally_ms": 0.0,
+                            "dur_ns": int(2e6 * scale)})
+            block = {"kind": "fastsync.block", "t_ns": t, "id": height, "parent": None,
+                     "dur_ns": int(10e6 * scale), "parts_ms": 1.0 * scale,
+                     "verify_ms": 2.0 * scale, "store_ms": 3.0 * scale, "apply_ms": 4.0 * scale,
+                     "deliver_ms": 1.5 * scale, "decode_ms": 0.75, "pending": 4}
+            if wait is not None:
+                block["wait_ms"] = wait
+            evs.append(block)
+        evs.append({"kind": "verify.dispatch", "t_ns": t + 1, "n": 3, "path": "indexed",
+                    "host_prep_ms": 9.0, "device_ms": 9.0})  # the harness's own call: no id
+        return evs
+
+    def test_stages_per_block_and_children_summed_under_their_block(self):
+        budget = tracing.replay_budget(self._events())
+        assert budget["blocks"] == 2 and budget["heights"] == [7, 8]
+        assert budget["interval_ms"] == 17.5  # (10 + 0) and (20 + 5)
+        st = budget["stages"]
+        assert st["block_ms"]["mean_ms"] == 15.0
+        assert st["wait_ms"]["mean_ms"] == 2.5
+        assert st["store_ms"]["mean_ms"] == 4.5 and st["deliver_ms"]["mean_ms"] == 2.25
+        assert st["commit.sign_bytes_ms"]["mean_ms"] == 1.5  # two a block: 1.0 and 2.0
+        assert st["dispatch.fetch_ms"]["mean_ms"] == 1.5
+        assert st["dispatch.host_prep_ms"]["mean_ms"] == 0.75  # the id-less call is no block's
+        assert "commit.tally_ms" not in st and "queued_ms" not in st  # all zero or absent
+        assert list(st)[:6] == ["block_ms", "wait_ms", "parts_ms", "verify_ms", "store_ms", "apply_ms"]
+        table = tracing.format_replay_budget(budget)
+        assert "block interval 17.5 ms" in table and "dispatch.launch_ms" in table
+
+    def test_nothing_to_budget_without_a_block_span(self):
+        assert tracing.replay_budget([{"kind": "verify.commit", "id": 3}]) is None
+        assert "nothing to budget" in tracing.format_replay_budget(None)
+
+
 class TestRPCRoute:
     async def test_dump_flight_recorder_route(self):
         from tendermint_tpu.rpc.core import RPCCore
@@ -293,6 +551,114 @@ class TestVerifyEngineEvents:
         assert flush["batch"] >= 1 and flush["wait_ms"] >= 0
         dispatch = next(e for e in rec.events() if e["kind"] == "verify.dispatch")
         assert dispatch["path"] == "host" and dispatch["n"] >= 1
+
+
+def _signed(n):
+    from tendermint_tpu.crypto.keys import Ed25519PrivKey
+
+    keys = [Ed25519PrivKey.from_secret(f"span-{i}".encode()) for i in range(n)]
+    msgs = [b"\x08\x02\x11" + bytes([i]) * 40 for i in range(n)]
+    return [k.pub_key().bytes() for k in keys], msgs, [k.sign(m) for k, m in zip(keys, msgs)]
+
+
+DEVICE_PATHS = ("device", "indexed", "chunked", "tabulated")
+
+
+class TestDispatchSpans:
+    """verify.dispatch on every path: `host_prep_ms + device_ms` is the
+    call's wall time, and on the device paths host prep is measured (the
+    chunked path used to report the constant 0.0) and `pack_ms + launch_ms
+    + fetch_ms` split the rest."""
+
+    @pytest.mark.parametrize("path", ("host", "host-cold") + DEVICE_PATHS)
+    def test_prep_and_device_tile_the_calls_wall_time(self, path, monkeypatch):
+        import numpy as np
+
+        from tendermint_tpu.crypto import batch_verifier as bv
+
+        rec, prep_hist, dev_hist = FlightRecorder(size=256), _Hist(), _Hist()
+        n = 70 if path == "chunked" else 6
+        pubkeys, msgs, sigs = _signed(6)
+        idxs = [i % 6 for i in range(n)]
+        msgs, sigs = [msgs[i] for i in idxs], [sigs[i] for i in idxs]
+        engine = bv.BatchVerifier(
+            recorder=rec, min_device_batch=1 << 30 if path == "host" else 1
+        )
+        engine._pallas = False  # the XLA kernel: any shape, no interpreter
+        engine.metrics.host_prep_seconds = prep_hist
+        engine.metrics.device_seconds = dev_hist
+
+        def call():
+            if path in ("host", "host-cold", "device"):
+                return engine.verify([pubkeys[i] for i in idxs], msgs, sigs)
+            return table.verify_indexed(idxs, msgs, sigs)
+
+        if path == "host-cold":
+            engine._warmup_mode = True
+            engine._compiling_buckets.add(engine._bucket(n))  # as if its compile were running
+        elif path in ("indexed", "chunked", "tabulated"):
+            if path == "chunked":
+                monkeypatch.setattr(bv, "_CHUNK", 32)
+            table = bv.PubkeyTable(pubkeys, engine, tabulated=path == "tabulated")
+            table.chunked_single_shot = path == "chunked"
+            if path == "tabulated":
+                # the span's accounting is under test, not the kernel (whose
+                # interpreter takes minutes: tests/test_batch_verifier.py, slow)
+                from tendermint_tpu.ops import ed25519_table
+
+                monkeypatch.setattr(table, "build_tables", lambda: None)
+                monkeypatch.setattr(
+                    ed25519_table, "verify_tabulated",
+                    lambda tables, idx, *rows, **kw: np.ones(len(idx), dtype=bool),
+                )
+        call()  # a compile, a library's first load: outside the timed call
+        seq = rec.snapshot()["next_seq"]
+        t0 = time.monotonic_ns()
+        assert call() == [True] * n
+        wall_ms = (time.monotonic_ns() - t0) / 1e6
+        (ev,) = rec.events(since=seq, kinds=["verify.dispatch"])
+        assert (ev["path"], ev["n"], ev["shards"]) == (path, n, 1)
+        assert "ok" not in ev  # the harness reads ok=False on a verify.* event as a failure
+        # ... to within the lines that close the span, which run on cold caches
+        # (and, on the table paths, the row list built from the indices)
+        tiled = ev["host_prep_ms"] + ev["device_ms"] + ev.get("rows_ms", 0.0)
+        assert tiled == pytest.approx(ev["dur_ns"] / 1e6, abs=0.25)
+        assert ("rows_ms" in ev) == (path in ("indexed", "chunked", "tabulated"))
+        # from outside, the call is that span and a few lines around it
+        assert wall_ms - 1.0 <= ev["dur_ns"] / 1e6 <= wall_ms
+        if path in DEVICE_PATHS:
+            assert ev["host_prep_ms"] > 0
+            assert ev["pack_ms"] > 0 and ev["launch_ms"] > 0 and ev["fetch_ms"] > 0
+            assert ev["pack_ms"] + ev["launch_ms"] + ev["fetch_ms"] == pytest.approx(
+                ev["device_ms"], abs=0.01)
+            assert ev["bucket"] == {"chunked": 32, "tabulated": 256}.get(path, engine._bucket(n))
+            # one reading, two sinks: the histograms saw the event's numbers
+            assert prep_hist.seen[-1] * 1e3 == pytest.approx(ev["host_prep_ms"], abs=0.001)
+            assert dev_hist.seen[-1] * 1e3 == pytest.approx(ev["device_ms"], abs=0.001)
+        else:
+            assert ev["host_prep_ms"] == 0.0 and ev["device_ms"] > 0 and ev["bucket"] in (
+                0, engine._bucket(n))
+            assert "launch_ms" not in ev
+
+    def test_a_dispatch_inside_a_commit_says_which_block_it_served(self):
+        from tendermint_tpu.crypto.batch_verifier import BatchVerifier
+
+        rec = FlightRecorder(size=64)
+        engine = BatchVerifier(recorder=rec, min_device_batch=1 << 30)
+        pubkeys, msgs, sigs = _signed(3)
+        with rec.span("fastsync.block", id=41):
+            with tracing.child_span("verify.commit", height=40):
+                engine.verify(pubkeys, msgs, sigs)
+        dispatch = rec.events()[0]
+        assert (dispatch["kind"], dispatch["parent"], dispatch["id"]) == (
+            "verify.dispatch", "verify.commit", 41)
+
+    def test_no_direct_batch_event(self):
+        import inspect
+
+        from tendermint_tpu.crypto import batch_verifier as bv
+
+        assert "verify.direct_batch" not in inspect.getsource(bv)
 
 
 class TestFlightSpool:
