@@ -11,8 +11,6 @@ Proto3 semantics: zero values are omitted by the canonical encoders.
 
 from __future__ import annotations
 
-import struct
-
 from .varint import encode_uvarint
 
 
@@ -29,10 +27,15 @@ def field_varint(field_num: int, value: int, *, emit_zero: bool = False) -> byte
     return _tag(field_num, 0) + encode_uvarint(value)
 
 
+def fixed64_bytes(value: int) -> bytes:
+    """A fixed64 field's 8 value bytes: two's complement, little-endian."""
+    return (value & 0xFFFFFFFFFFFFFFFF).to_bytes(8, "little")
+
+
 def field_fixed64(field_num: int, value: int, *, emit_zero: bool = False) -> bytes:
     if value == 0 and not emit_zero:
         return b""
-    return _tag(field_num, 1) + struct.pack("<Q", value & ((1 << 64) - 1))
+    return _tag(field_num, 1) + fixed64_bytes(value)
 
 
 def field_bytes(field_num: int, value: bytes | str, *, emit_zero: bool = False) -> bytes:

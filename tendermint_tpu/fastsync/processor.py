@@ -78,8 +78,9 @@ def verify_commit_run(
     """
     from ..types.agg_commit import AggregateCommit
 
-    idxs: List[Tuple[int, int]] = []  # (pair_idx, sig_idx)
+    idxs: List[Tuple[int, int, bool]] = []  # (pair_idx, sig_idx, vote is for the block)
     pubkeys, msgs, sigs = [], [], []
+    set_keys = [v.pub_key for v in val_set.validators]
     structural_ok = []
     agg_items: List[Tuple[int, tuple]] = []  # (pair_idx, claim) — one batch
     agg_power: dict = {}
@@ -106,14 +107,11 @@ def verify_commit_run(
             agg_items.append((pi, (pks, commit.sign_message(chain_id), commit.agg_sig)))
             agg_power[pi] = sum(val_set.validators[i].voting_power for i in signer_idxs)
             continue
-        for i, cs in enumerate(commit.signatures):
-            if cs.is_absent():
-                continue
-            idxs.append((pi, i))
-            pk = val_set.validators[i].pub_key
-            pubkeys.append(pk)
-            msgs.append(commit.vote_sign_bytes(chain_id, i, pub_key=pk))
-            sigs.append(cs.signature)
+        batch = commit.vote_batch(chain_id, set_keys)
+        idxs.extend((pi, i, counts) for i, counts in zip(batch.idxs, batch.for_block))
+        pubkeys.extend(batch.pub_keys)
+        msgs.extend(batch.msgs)
+        sigs.extend(batch.sigs)
 
     # type-routed: ed25519 rides the batch engine, other key types verify
     # via their own PubKey.verify (same dispatch as ValidatorSet.verify_commit)
@@ -124,12 +122,10 @@ def verify_commit_run(
     tallied = [0] * len(pairs)
     sig_ok = [True] * len(pairs)
     needed = val_set.total_voting_power() * 2 // 3
-    for (pi, i), good in zip(idxs, ok):
+    for (pi, i, counts), good in zip(idxs, ok):
         if not good:
             sig_ok[pi] = False
-            continue
-        cs = pairs[pi][2].signatures[i]
-        if pairs[pi][0] == cs.block_id(pairs[pi][2].block_id):
+        elif counts:
             tallied[pi] += val_set.validators[i].voting_power
     if agg_items:
         from ..crypto.bls import scheme as _bls_scheme

@@ -705,6 +705,14 @@ def replay_budget(events: List[dict]) -> Optional[dict]:
             sum(ev["dur_ns"] / 1e6 + ev.get("wait_ms", 0.0) for ev in blocks) / len(blocks), 3),
         "stages": {},
     }
+    commits = [ev for (kind, _), evs in children.items() if kind == "verify.commit" for ev in evs]
+    if commits:
+        # of the messages the commits verified, how many were built from a
+        # per-commit template (Commit.vote_batch): all of them
+        out["commit_messages"] = {
+            "n": sum(ev.get("n", 0) for ev in commits),
+            "templated": sum(ev.get("templated", 0) for ev in commits),
+        }
     rows = [("block_ms", [ev["dur_ns"] / 1e6 for ev in blocks])]
     for kind, names in REPLAY_ROWS:
         own = kind == "fastsync.block"
@@ -732,6 +740,9 @@ def format_replay_budget(budget: Optional[dict]) -> str:
         f"{budget['heights'][1]}), block interval {budget['interval_ms']} ms",
         f"  {'stage, per block':<26}{'mean ms':>10}{'p50 ms':>10}{'p90 ms':>10}",
     ]
+    if "commit_messages" in budget:
+        msgs = budget["commit_messages"]
+        lines.insert(1, f"  commit messages: {msgs['templated']} of {msgs['n']} from a template")
     for name, st in budget["stages"].items():
         lines.append(
             f"  {name:<26}{st['mean_ms']:>10.3f}{st['p50_ms']:>10.3f}{st['p90_ms']:>10.3f}"

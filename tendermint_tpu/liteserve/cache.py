@@ -34,7 +34,6 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 from ..crypto import batch as crypto_batch
-from ..crypto.keys import Ed25519PubKey
 from ..crypto.tmhash import sum_sha256
 from ..libs.log import get_logger
 from ..types import SignedHeader
@@ -158,16 +157,7 @@ class VerifyCache:
             return await self._verify_agg(sh, vals, digest)
         if vals.size() != len(sh.commit.signatures):
             return None
-        items: List[Tuple[bytes, bytes, bytes]] = []
-        for idx, cs in enumerate(sh.commit.signatures):
-            if cs.is_absent():
-                continue
-            pk = vals.validators[idx].pub_key
-            if not isinstance(pk, Ed25519PubKey):
-                continue  # other key types verify via their own PubKey path
-            items.append(
-                (pk.bytes(), sh.commit.vote_sign_bytes(sh.header.chain_id, idx), cs.signature)
-            )
+        items = vals.ed25519_vote_triples(sh.header.chain_id, sh.commit)
         if self.async_verifier is not None and items:
             futs = self.async_verifier.verify_many(items)
             results = await asyncio.gather(*futs)

@@ -31,7 +31,6 @@ from typing import Callable, Dict, List, Optional, Set, Tuple
 
 from ..abci import types as abci
 from ..crypto import batch as crypto_batch
-from ..crypto.keys import Ed25519PubKey
 from ..libs.log import get_logger
 from ..libs.metrics import StateSyncMetrics
 from ..libs.tracing import NOP as NOP_RECORDER
@@ -92,16 +91,11 @@ class EngineCommitPreverify:
             return None  # sync path routes through the aggregate branch + memo
         if vals.size() != len(sh.commit.signatures):
             return None  # malformed; let verify_commit raise its own error
-        items = []
-        for idx, cs in enumerate(sh.commit.signatures):
-            if cs.is_absent():
-                continue
-            pk = vals.validators[idx].pub_key
-            if not isinstance(pk, Ed25519PubKey):
-                continue  # non-ed25519 rides mixed_batch_verify's own path
-            key = (pk.bytes(), sh.commit.vote_sign_bytes(sh.header.chain_id, idx), cs.signature)
-            if key not in self._cache:
-                items.append(key)
+        items = [
+            key
+            for key in vals.ed25519_vote_triples(sh.header.chain_id, sh.commit)
+            if key not in self._cache
+        ]
         if items:
             futs = self.async_verifier.verify_many(items)
             results = await asyncio.gather(*futs)

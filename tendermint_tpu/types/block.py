@@ -9,7 +9,7 @@ Times are integer unix nanoseconds throughout (deterministic, no tz).
 from __future__ import annotations
 
 from dataclasses import dataclass, field, replace
-from typing import List, Optional
+from typing import List, NamedTuple, Optional, Sequence
 
 from ..crypto import merkle, tmhash
 from ..encoding import codec
@@ -284,6 +284,17 @@ class CommitSig:
         return cls(d["block_id_flag"], d["validator_address"], d["timestamp_ns"], d["signature"])
 
 
+class VoteBatch(NamedTuple):
+    """A commit's verification batch (Commit.vote_batch), position-aligned."""
+
+    idxs: List[int]  # slots of commit.signatures, ascending
+    pub_keys: list  # the slot's PubKey, as the caller gave it
+    msgs: List[bytes]  # == commit.vote_sign_bytes(chain_id, idx, pub_key=pk)
+    sigs: List[bytes]
+    for_block: List[bool]  # the vote counts toward the commit's block (not nil)
+    templated: int  # how many of msgs came from a per-commit template
+
+
 class Commit:
     """Proof a block was committed: ordered CommitSigs (types/block.go:556).
 
@@ -352,6 +363,57 @@ class Commit:
             bid.parts_header.hash,
             cs.timestamp_ns,
         )
+
+    def _vote_template(self, chain_id: str, for_block: bool, bls: bool):
+        """(prefix, suffix) of a slot's sign-bytes around its timestamp;
+        suffix is None where the message has none (the BLS domain) and the
+        prefix is the whole message."""
+        bid = self.block_id if for_block else BlockID()
+        encode = (
+            canonical.canonical_vote_sign_bytes_no_ts if bls else canonical.canonical_vote_template
+        )
+        tpl = encode(
+            chain_id,
+            canonical.PRECOMMIT_TYPE,
+            self.height,
+            self.round,
+            bid.hash,
+            bid.parts_header.total,
+            bid.parts_header.hash,
+        )
+        return (tpl, None) if bls else tpl
+
+    def vote_batch(self, chain_id: str, pub_keys: Sequence) -> VoteBatch:
+        """The batch that verifies this commit, in one pass: every present
+        slot whose entry in `pub_keys` (aligned with the signatures) is not
+        None, with the sign-bytes vote_sign_bytes(chain_id, idx, pub_key=pk)
+        would give.  The canonical vote is encoded once per (block-id flag,
+        key scheme) -- at most four times -- and each message is that
+        template around the slot's own timestamp."""
+        from .vote import is_bls_key
+
+        templates: dict = {}  # (flag, key class) -> (prefix, suffix)
+        by_scheme: dict = {}  # (for_block, bls) -> (prefix, suffix)
+        fixed64 = canonical.fixed64_bytes
+        idxs, keys, msgs, sigs, for_block = [], [], [], [], []
+        for idx, (cs, pk) in enumerate(zip(self.signatures, pub_keys)):
+            flag = cs.block_id_flag
+            if flag == BLOCK_ID_FLAG_ABSENT or pk is None:
+                continue
+            tpl = templates.get((flag, pk.__class__))
+            if tpl is None:
+                scheme = (flag == BLOCK_ID_FLAG_COMMIT, is_bls_key(pk))
+                tpl = by_scheme.get(scheme)
+                if tpl is None:
+                    tpl = by_scheme[scheme] = self._vote_template(chain_id, *scheme)
+                templates[(flag, pk.__class__)] = tpl
+            prefix, suffix = tpl
+            idxs.append(idx)
+            keys.append(pk)
+            msgs.append(prefix if suffix is None else prefix + fixed64(cs.timestamp_ns) + suffix)
+            sigs.append(cs.signature)
+            for_block.append(flag == BLOCK_ID_FLAG_COMMIT)
+        return VoteBatch(idxs, keys, msgs, sigs, for_block, len(msgs))
 
     def bit_array(self) -> BitArray:
         if self._bit_array is None:

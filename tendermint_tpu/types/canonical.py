@@ -12,9 +12,12 @@ exactly what the vmapped SHA-512 kernel wants (no padding-induced recompiles).
 
 from __future__ import annotations
 
+from typing import Tuple
+
 from ..encoding.proto import (
     field_bytes,
     field_fixed64,
+    fixed64_bytes,
     field_varint,
     length_prefixed,
 )
@@ -98,6 +101,30 @@ def canonical_vote_sign_bytes_no_ts(
         payload += field_bytes(4, bid)
     payload += field_bytes(6, chain_id)
     return length_prefixed(payload)
+
+
+def canonical_vote_template(
+    chain_id: str,
+    vote_type: int,
+    height: int,
+    round_: int,
+    block_id_hash: bytes,
+    block_id_psh_total: int,
+    block_id_psh_hash: bytes,
+) -> Tuple[bytes, bytes]:
+    """(prefix, suffix) around the timestamp's 8 bytes: for every timestamp
+    canonical_vote_sign_bytes(..., ts) == prefix + fixed64_bytes(ts) + suffix.
+
+    The cut is known by construction: field 5 is always emitted as a tag
+    and 8 little-endian bytes, only field 6 (the chain id) follows it, and
+    the length prefix in front does not depend on the timestamp's value.
+    """
+    full = canonical_vote_sign_bytes(
+        chain_id, vote_type, height, round_,
+        block_id_hash, block_id_psh_total, block_id_psh_hash, 0,
+    )
+    cut = len(full) - len(field_bytes(6, chain_id))
+    return full[: cut - 8], full[cut:]
 
 
 def canonical_proposal_sign_bytes(

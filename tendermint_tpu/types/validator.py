@@ -26,7 +26,7 @@ from ..crypto.keys import PubKey, pubkey_from_dict
 from ..encoding import codec
 from ..encoding.proto import field_bytes, field_varint
 from ..libs import tracing
-from .block import BlockID, Commit
+from .block import BLOCK_ID_FLAG_ABSENT, BlockID, Commit, VoteBatch
 
 INT64_MAX = (1 << 63) - 1
 INT64_MIN = -(1 << 63)
@@ -546,44 +546,73 @@ class ValidatorSet:
         _verify_commit_basic(commit, height, block_id)
 
         with tracing.child_span("verify.commit", height=height) as span:
-            idxs, pubkeys, msgs, sigs = [], [], [], []
-            for idx, cs in enumerate(commit.signatures):
-                if cs.is_absent():
-                    continue
-                idxs.append(idx)
-                pk = self.validators[idx].pub_key
-                pubkeys.append(pk)
-                msgs.append(commit.vote_sign_bytes(chain_id, idx, pub_key=pk))
-                sigs.append(cs.signature)
-            span.set(n=len(sigs))
+            # signatures align with set rows: validator index IS the row
+            batch = commit.vote_batch(chain_id, [v.pub_key for v in self.validators])
+            span.set(n=len(batch.sigs), templated=batch.templated)
             span.lap("sign_bytes_ms")
 
-            indexed = None
-            if crypto_batch.get_indexed_verifier() is not None:
-                # signatures align with set rows: validator index IS the row.
-                # Rows are passed lazily — a table-cache hit (the steady state)
-                # never materializes the V-sized list.
-                indexed = (
-                    self.pubkeys_digest(),
-                    lambda: [v.pub_key.bytes() for v in self.validators],
-                    idxs,
-                )
-            ok = mixed_batch_verify(pubkeys, msgs, sigs, batch_verify, indexed=indexed)
+            ok = self._verify_batch(batch, batch.idxs, batch_verify)
             span.lap("engine_ms")
 
-            tallied = 0
             needed = self.total_voting_power() * 2 // 3
-            for pos, idx in enumerate(idxs):
-                if not ok[pos]:
-                    raise ValueError(f"wrong signature (#{idx}): {sigs[pos].hex()}")
-                cs = commit.signatures[idx]
-                # Stray signatures (votes for nil) are valid but don't count
-                # toward the block's power (validator_set.go:656-662).
-                if block_id == cs.block_id(commit.block_id):
-                    tallied += self.validators[idx].voting_power
+            tallied = _tally(batch, ok, (self.validators[i].voting_power for i in batch.idxs))
             span.lap("tally_ms")
         if tallied <= needed:
             raise NotEnoughVotingPowerError(got=tallied, needed=needed)
+
+    def _verify_batch(self, batch, row_idxs, batch_verify) -> List[bool]:
+        """mixed_batch_verify of a commit's batch whose position p signs
+        with this set's row row_idxs[p]."""
+        indexed = None
+        if crypto_batch.get_indexed_verifier() is not None:
+            # Rows are passed lazily — a table-cache hit (the steady state)
+            # never materializes the V-sized list.
+            indexed = (
+                self.pubkeys_digest(),
+                lambda: [v.pub_key.bytes() for v in self.validators],
+                row_idxs,
+            )
+        return mixed_batch_verify(
+            batch.pub_keys, batch.msgs, batch.sigs, batch_verify, indexed=indexed
+        )
+
+    def _batch_by_address(self, chain_id: str, commit: Commit, double_vote_raises: bool):
+        """The commit's batch over the slots whose address is in THIS set
+        (the commit may belong to another), with this set's row and power
+        for each position.  A second slot of one validator raises or, for
+        the future-commit check, is skipped."""
+        seen_vals: dict = {}
+        pub_keys: list = [None] * len(commit.signatures)
+        rows: dict = {}
+        for idx, cs in enumerate(commit.signatures):
+            if cs.block_id_flag == BLOCK_ID_FLAG_ABSENT:
+                continue
+            val_idx, val = self.get_by_address(cs.validator_address)
+            if val is None:
+                continue
+            if val_idx in seen_vals:
+                if double_vote_raises:
+                    raise ValueError(f"double vote from {val} ({seen_vals[val_idx]} and {idx})")
+                continue
+            seen_vals[val_idx] = idx
+            pub_keys[idx] = val.pub_key
+            rows[idx] = val_idx
+        batch = commit.vote_batch(chain_id, pub_keys)
+        row_idxs = [rows[i] for i in batch.idxs]
+        return batch, row_idxs, [self.validators[r].voting_power for r in row_idxs]
+
+    def ed25519_vote_triples(self, chain_id: str, commit: Commit) -> List[Tuple[bytes, bytes, bytes]]:
+        """(pubkey, sign-bytes, signature) of every present ed25519 slot of
+        an index-aligned commit: what the async pre-verify lanes (statesync,
+        liteserve) hand the engine.  Other key types verify via their own
+        PubKey path in mixed_batch_verify."""
+        from ..crypto.keys import Ed25519PubKey
+
+        batch = commit.vote_batch(
+            chain_id,
+            [v.pub_key if isinstance(v.pub_key, Ed25519PubKey) else None for v in self.validators],
+        )
+        return list(zip((pk.bytes() for pk in batch.pub_keys), batch.msgs, batch.sigs))
 
     def verify_future_commit(
         self,
@@ -610,29 +639,9 @@ class ValidatorSet:
             )
             return
 
-        old_voting_power = 0
-        seen = set()
-        idxs, powers, pubkeys, msgs, sigs = [], [], [], [], []
-        for idx, cs in enumerate(commit.signatures):
-            if cs.is_absent():
-                continue
-            old_idx, val = self.get_by_address(cs.validator_address)
-            if val is None or old_idx in seen:
-                continue
-            seen.add(old_idx)
-            idxs.append(idx)
-            powers.append(val.voting_power)
-            pubkeys.append(val.pub_key)
-            msgs.append(commit.vote_sign_bytes(chain_id, idx, pub_key=val.pub_key))
-            sigs.append(cs.signature)
-
-        ok = mixed_batch_verify(pubkeys, msgs, sigs, batch_verify)
-        for pos, idx in enumerate(idxs):
-            if not ok[pos]:
-                raise ValueError(f"wrong signature (#{idx}): {sigs[pos].hex()}")
-            cs = commit.signatures[idx]
-            if block_id == cs.block_id(commit.block_id):
-                old_voting_power += powers[pos]
+        batch, _, powers = self._batch_by_address(chain_id, commit, double_vote_raises=False)
+        ok = mixed_batch_verify(batch.pub_keys, batch.msgs, batch.sigs, batch_verify)
+        old_voting_power = _tally(batch, ok, powers)
 
         needed = self.total_voting_power() * 2 // 3
         if old_voting_power <= needed:
@@ -675,44 +684,17 @@ class ValidatorSet:
         _verify_commit_basic(commit, height, block_id)
 
         with tracing.child_span("verify.commit", height=height) as span:
-            seen_vals = {}
-            idxs, row_idxs, powers, pubkeys, msgs, sigs = [], [], [], [], [], []
-            for idx, cs in enumerate(commit.signatures):
-                if cs.is_absent():
-                    continue
-                val_idx, val = self.get_by_address(cs.validator_address)
-                if val is None:
-                    continue
-                if val_idx in seen_vals:
-                    raise ValueError(f"double vote from {val} ({seen_vals[val_idx]} and {idx})")
-                seen_vals[val_idx] = idx
-                idxs.append(idx)
-                row_idxs.append(val_idx)
-                powers.append(val.voting_power)
-                pubkeys.append(val.pub_key)
-                msgs.append(commit.vote_sign_bytes(chain_id, idx, pub_key=val.pub_key))
-                sigs.append(cs.signature)
-            span.set(n=len(sigs))
+            batch, row_idxs, powers = self._batch_by_address(
+                chain_id, commit, double_vote_raises=True
+            )
+            span.set(n=len(batch.sigs), templated=batch.templated)
             span.lap("sign_bytes_ms")
 
-            indexed = None
-            if crypto_batch.get_indexed_verifier() is not None:
-                indexed = (
-                    self.pubkeys_digest(),
-                    lambda: [v.pub_key.bytes() for v in self.validators],
-                    row_idxs,
-                )
-            ok = mixed_batch_verify(pubkeys, msgs, sigs, batch_verify, indexed=indexed)
+            ok = self._verify_batch(batch, row_idxs, batch_verify)
             span.lap("engine_ms")
 
-            tallied = 0
             needed = self.total_voting_power() * trust_numerator // trust_denominator
-            for pos, idx in enumerate(idxs):
-                if not ok[pos]:
-                    raise ValueError(f"wrong signature (#{idx}): {sigs[pos].hex()}")
-                cs = commit.signatures[idx]
-                if block_id == cs.block_id(commit.block_id):
-                    tallied += powers[pos]
+            tallied = _tally(batch, ok, powers)
             span.lap("tally_ms")
         if tallied <= needed:
             raise NotEnoughVotingPowerError(got=tallied, needed=needed)
@@ -749,6 +731,20 @@ class ValidatorSet:
 
 
 codec.register("tm/ValidatorSet")(ValidatorSet)
+
+
+def _tally(batch: VoteBatch, ok: Sequence[bool], powers) -> int:
+    """Voting power of the batch's votes for the block, `powers` aligned
+    with the batch; the first bad signature raises.  Stray signatures
+    (votes for nil) are valid but don't count toward the block's power
+    (validator_set.go:656-662)."""
+    tallied = 0
+    for pos, (good, power, counts) in enumerate(zip(ok, powers, batch.for_block)):
+        if not good:
+            raise ValueError(f"wrong signature (#{batch.idxs[pos]}): {batch.sigs[pos].hex()}")
+        if counts:
+            tallied += power
+    return tallied
 
 
 def _verify_commit_basic(commit: Commit, height: int, block_id: BlockID) -> None:

@@ -466,6 +466,16 @@ class TestReplayBudget:
         table = tracing.format_replay_budget(budget)
         assert "block interval 17.5 ms" in table and "dispatch.launch_ms" in table
 
+    def test_the_dump_says_how_many_commit_messages_came_from_a_template(self):
+        events = self._events()
+        assert tracing.replay_budget(events)["commit_messages"] == {"n": 12, "templated": 0}
+        for ev in events:  # a node of this tree: every commit span carries the counter
+            if ev["kind"] == "verify.commit":
+                ev["templated"] = ev["n"]
+        budget = tracing.replay_budget(events)
+        assert budget["commit_messages"] == {"n": 12, "templated": 12}
+        assert "commit messages: 12 of 12 from a template" in tracing.format_replay_budget(budget)
+
     def test_nothing_to_budget_without_a_block_span(self):
         assert tracing.replay_budget([{"kind": "verify.commit", "id": 3}]) is None
         assert "nothing to budget" in tracing.format_replay_budget(None)
@@ -659,6 +669,79 @@ class TestDispatchSpans:
         from tendermint_tpu.crypto import batch_verifier as bv
 
         assert "verify.direct_batch" not in inspect.getsource(bv)
+
+
+class TestCommitSpans:
+    """verify.commit as fast sync leaves it: two a block, each one event
+    with its three laps, `n`, and `templated` == `n` (every message came
+    from the commit's templates: none through the per-vote encoder)."""
+
+    CHAIN = "span-chain"
+
+    def _chain(self, heights):
+        from tendermint_tpu.types import (
+            PRECOMMIT_TYPE, BlockID, Commit, CommitSig, MockPV, PartSetHeader, Validator,
+            ValidatorSet, Vote,
+        )
+
+        pvs = sorted((MockPV() for _ in range(7)), key=lambda pv: pv.address())
+        vset = ValidatorSet([Validator.new(pv.get_pub_key(), 10) for pv in pvs])
+        commits = {}
+        for h in heights:
+            bid = BlockID(bytes([h]) * 32, PartSetHeader(1, bytes([h]) * 32))
+            sigs = []
+            for i, pv in enumerate(pvs):
+                if i == 1 + h % 6:
+                    sigs.append(CommitSig.absent())
+                    continue
+                # slot 0 votes nil: verified, not counted
+                vote = Vote(PRECOMMIT_TYPE, h, 0, BlockID() if i == 0 else bid,
+                            1_700_000_000_000_000_000 + 1000 * h + i, pv.address(), i)
+                pv.sign_vote(self.CHAIN, vote)
+                sigs.append(vote.commit_sig())
+            commits[h] = (bid, Commit(h, 0, bid, sigs))
+        return vset, commits
+
+    def test_seven_events_a_block_and_every_message_templated(self, monkeypatch):
+        from tendermint_tpu.crypto import batch as crypto_batch
+        from tendermint_tpu.crypto import batch_verifier as bv
+
+        rec = FlightRecorder(size=256)
+        engine = bv.BatchVerifier(recorder=rec, min_device_batch=1)
+        engine._pallas = False  # the XLA kernel: any shape, no interpreter
+        monkeypatch.setattr(crypto_batch, "_indexed_verifier", bv.TableCache(engine).verify_indexed)
+        vset, commits = self._chain(range(1, 5))
+        vset.verify_commit(self.CHAIN, commits[1][0], 1, commits[1][1])  # table build, compile
+        seq = rec.snapshot()["next_seq"]
+        for h in (2, 3, 4):
+            # as _try_sync does: the block's own commit, then its LastCommit in validation
+            with rec.span("fastsync.block", id=h):
+                vset.verify_commit(self.CHAIN, commits[h][0], h, commits[h][1])
+                vset.verify_commit(self.CHAIN, commits[h - 1][0], h - 1, commits[h - 1][1])
+        events = rec.events(since=seq)
+        for h in (2, 3, 4):
+            mine = [e for e in events if e.get("id") == h]
+            assert sorted(e["kind"] for e in mine) == [
+                "fastsync.block", "verify.commit", "verify.commit", "verify.dispatch",
+                "verify.dispatch", "verify.table", "verify.table"]  # 7, of a budget of 8
+            commit_events = [e for e in mine if e["kind"] == "verify.commit"]
+            assert [c["height"] for c in commit_events] == [h, h - 1]
+            for c in commit_events:
+                assert c["parent"] == "fastsync.block" and "ok" not in c
+                assert c["n"] == c["templated"] == 6  # seven slots, one absent
+                assert min(c["sign_bytes_ms"], c["engine_ms"], c["tally_ms"]) >= 0
+                assert c["sign_bytes_ms"] + c["engine_ms"] + c["tally_ms"] <= c["dur_ns"] / 1e6 + 0.005
+            for d in (e for e in mine if e["kind"] == "verify.dispatch"):
+                assert (d["parent"], d["path"], d["n"]) == ("verify.commit", "indexed", 6)
+
+    def test_the_trusting_check_closes_the_same_span(self):
+        rec = FlightRecorder(size=16)
+        vset, commits = self._chain([9])
+        with rec.span("lite.verify", id=9):
+            vset.verify_commit_trusting(self.CHAIN, commits[9][0], 9, commits[9][1], 1, 3)
+        (c,) = [e for e in rec.events() if e["kind"] == "verify.commit"]
+        assert c["n"] == c["templated"] == 6 and (c["parent"], c["id"], c["height"]) == ("lite.verify", 9, 9)
+        assert {"sign_bytes_ms", "engine_ms", "tally_ms", "dur_ns"} <= set(c)
 
 
 class TestFlightSpool:

@@ -6,6 +6,8 @@ block/commit hashing (types/block_test.go), part sets
 (types/part_set_test.go), evidence (types/evidence_test.go).
 """
 
+import functools
+import random
 import time
 
 import pytest
@@ -32,9 +34,10 @@ from tendermint_tpu.types import (
     Vote,
     VoteSet,
 )
+from tendermint_tpu.types.block import BLOCK_ID_FLAG_COMMIT, BLOCK_ID_FLAG_NIL
 from tendermint_tpu.types.part_set import PartSet
 from tendermint_tpu.types.tx import tx_proof, txs_hash
-from tendermint_tpu.types.vote import VoteError
+from tendermint_tpu.types.vote import VoteError, is_bls_key
 
 CHAIN_ID = "test-chain"
 
@@ -322,6 +325,199 @@ class TestVerifyCommit:
         bid = make_block_id()
         commit = make_commit(vset, pvs, 3, 0, bid)
         vset.verify_future_commit(vset, CHAIN_ID, bid, 3, commit)
+
+
+# ---------------------------------------------------------------------------
+# a commit's verification batch, built from per-commit templates
+# ---------------------------------------------------------------------------
+
+REAL_TS = 1_700_000_000_123_456_789
+BATCH_TIMESTAMPS = {
+    "zero": 0, "one": 1, "real": REAL_TS, "int64-max": 2**63 - 1,
+    "past-uint64": 2**64 + 12345, "negative": -7,
+}
+BATCH_FLAGS = ("for-block", "absent", "nil", "nil+absent")
+
+
+@functools.lru_cache(maxsize=None)
+def _batch_keys(kind, n):
+    """Public keys of the right classes over random bytes: sign-bytes depend
+    on a key's scheme, not on its value."""
+    from tendermint_tpu.crypto.bls.keys import BlsPubKey
+    from tendermint_tpu.crypto.keys import Ed25519PubKey
+    from tendermint_tpu.crypto.sr25519 import Sr25519PubKey
+
+    rng = random.Random(f"{kind}-{n}")
+    if kind == "sr25519":
+        return [Sr25519PubKey(rng.randbytes(32)) for _ in range(n)]
+    return [
+        BlsPubKey(rng.randbytes(BlsPubKey.SIZE)) if kind == "mixed" and i % 3 == 1
+        else Ed25519PubKey(rng.randbytes(32))
+        for i in range(n)
+    ]
+
+
+def _batch_commit(n, flags, ts, bid):
+    """Unsigned-but-shaped commit: every 20th slot absent and/or every 7th a
+    nil vote as `flags` says; even slots carry `ts` itself, odd ones ts + i."""
+    rng = random.Random(f"{n}-{flags}-{ts}")
+    sigs = []
+    for i in range(n):
+        if "absent" in flags and i % 20 == 3 % n:
+            sigs.append(CommitSig.absent())
+            continue
+        flag = BLOCK_ID_FLAG_NIL if "nil" in flags and i % 7 == 2 % n else BLOCK_ID_FLAG_COMMIT
+        sigs.append(CommitSig(flag, rng.randbytes(20), ts + (i if i % 2 else 0), rng.randbytes(64)))
+    return Commit(12, 1, bid, sigs)
+
+
+def _signed_commit(vset, pvs, bid, height=5, nil=(), ts=REAL_TS):
+    """A commit every validator really signed; slots in `nil` vote nil."""
+    sigs = []
+    for i, pv in enumerate(pvs):
+        vote = signed_vote(pv, vset, PRECOMMIT_TYPE, height, 0, BlockID() if i in nil else bid, ts + i)
+        sigs.append(vote.commit_sig())
+    return Commit(height, 0, bid, sigs)
+
+
+@functools.lru_cache(maxsize=None)
+def _verdict_set(kind):
+    """8 validators, power 10 + slot: all ed25519, or ed25519 with BLS and
+    sr25519 slots between (PR 20's rotation leaves such sets)."""
+    from tendermint_tpu.crypto.bls.keys import BlsPrivKey
+    from tendermint_tpu.crypto.sr25519 import Sr25519PrivKey
+
+    pvs = [MockPV() for _ in range(8)]
+    if kind == "mixed":
+        pvs[1], pvs[6] = MockPV(BlsPrivKey.generate()), MockPV(BlsPrivKey.generate())
+        pvs[4] = MockPV(Sr25519PrivKey(bytes(range(32))))
+    pvs.sort(key=lambda pv: pv.address())
+    vset = ValidatorSet([Validator.new(pv.get_pub_key(), 10 + i) for i, pv in enumerate(pvs)])
+    assert [v.address for v in vset.validators] == [pv.address() for pv in pvs]
+    return vset, pvs
+
+
+def _check(method, vset, bid, commit):
+    if method == "verify_commit":
+        return vset.verify_commit(CHAIN_ID, bid, commit.height, commit)
+    if method == "verify_future_commit":
+        return vset.verify_future_commit(vset, CHAIN_ID, bid, commit.height, commit)
+    return vset.verify_commit_trusting(CHAIN_ID, bid, commit.height, commit, 2, 3)
+
+
+VERDICT_METHODS = ("verify_commit", "verify_commit_trusting", "verify_future_commit")
+
+
+class TestCommitVoteBatch:
+    @pytest.mark.parametrize("ts", list(BATCH_TIMESTAMPS))
+    @pytest.mark.parametrize("flags", BATCH_FLAGS)
+    @pytest.mark.parametrize("n", [1, 4, 175, 2000])
+    @pytest.mark.parametrize("kind", ["ed25519", "mixed", "sr25519"])
+    def test_messages_equal_the_single_vote_encoder(self, kind, n, flags, ts):
+        keys = _batch_keys(kind, n)
+        bid = make_block_id(b"\x05")
+        commit = _batch_commit(n, flags, BATCH_TIMESTAMPS[ts], bid)
+        batch = commit.vote_batch(CHAIN_ID, keys)
+        present = [i for i, cs in enumerate(commit.signatures) if not cs.is_absent()]
+        assert batch.idxs == present
+        assert batch.msgs == [commit.vote_sign_bytes(CHAIN_ID, i, pub_key=keys[i]) for i in present]
+        assert batch.pub_keys == [keys[i] for i in present]
+        assert batch.sigs == [commit.signatures[i].signature for i in present]
+        assert batch.for_block == [
+            bid == commit.signatures[i].block_id(commit.block_id) for i in present
+        ]
+        assert batch.templated == len(batch.msgs) == len(present)
+        if n >= 175:  # the cases hold what they are named for
+            assert ("absent" in flags) == (len(present) < n)
+            assert ("nil" in flags) == (False in batch.for_block)
+
+    def test_a_slot_without_a_key_is_left_out(self):
+        keys = list(_batch_keys("mixed", 175))
+        keys[0] = keys[9] = keys[174] = None
+        commit = _batch_commit(175, "nil+absent", REAL_TS, make_block_id())
+        batch = commit.vote_batch(CHAIN_ID, keys)
+        want = [
+            i for i, cs in enumerate(commit.signatures)
+            if not cs.is_absent() and keys[i] is not None
+        ]
+        assert batch.idxs == want and None not in batch.pub_keys
+        assert batch.msgs == [commit.vote_sign_bytes(CHAIN_ID, i, pub_key=keys[i]) for i in want]
+
+    @pytest.mark.parametrize("kind,flags,templates", [
+        ("ed25519", "for-block", 1), ("sr25519", "nil+absent", 2), ("mixed", "nil+absent", 4),
+    ])
+    def test_the_canonical_encoder_runs_once_per_template(self, kind, flags, templates, monkeypatch):
+        """2,000 votes, at most four encodings: one per (block-id flag, key
+        scheme) the commit holds, never one per vote."""
+        from tendermint_tpu.types import canonical
+
+        calls = []
+        for name in ("canonical_vote_sign_bytes", "canonical_vote_sign_bytes_no_ts"):
+            def counted(*a, _real=getattr(canonical, name), _name=name, **kw):
+                calls.append(_name)
+                return _real(*a, **kw)
+
+            monkeypatch.setattr(canonical, name, counted)
+        commit = _batch_commit(2000, flags, REAL_TS, make_block_id())
+        batch = commit.vote_batch(CHAIN_ID, _batch_keys(kind, 2000))
+        assert len(batch.msgs) >= 1900 and batch.templated == len(batch.msgs)
+        assert len(calls) == templates <= 4
+        assert calls.count("canonical_vote_sign_bytes_no_ts") == (templates // 2 if kind == "mixed" else 0)
+
+    @pytest.mark.parametrize("ts", list(BATCH_TIMESTAMPS))
+    def test_the_template_cuts_at_the_timestamp(self, ts):
+        from tendermint_tpu.types import canonical
+
+        args = ("a-chain-id-of-some-length", PRECOMMIT_TYPE, 2**40, 3, b"\x07" * 32, 9, b"\x08" * 32)
+        prefix, suffix = canonical.canonical_vote_template(*args)
+        value = BATCH_TIMESTAMPS[ts]
+        assert prefix + canonical.fixed64_bytes(value) + suffix == \
+            canonical.canonical_vote_sign_bytes(*args, value)
+        assert prefix[-1] == (5 << 3) | 1  # field 5, fixed64: the timestamp's tag
+
+    @pytest.mark.parametrize("quarter", range(4))
+    @pytest.mark.parametrize("method", VERDICT_METHODS)
+    @pytest.mark.parametrize("kind", ["ed25519", "mixed"])
+    def test_a_tampered_slot_is_named_by_its_index(self, kind, method, quarter):
+        vset, pvs = _verdict_set(kind)
+        bid = make_block_id(b"\x03")
+        commit = _signed_commit(vset, pvs, bid, nil={2})
+        _check(method, vset, bid, commit)  # sound, with its nil vote
+        bad = 2 * quarter + 1
+        cs = commit.signatures[bad]
+        if is_bls_key(pvs[bad].get_pub_key()):  # its message has no timestamp
+            tampered = (cs.timestamp_ns, cs.signature[:-1] + bytes([cs.signature[-1] ^ 1]))
+        else:  # a valid signature, over another timestamp
+            tampered = (cs.timestamp_ns + 1, cs.signature)
+        commit.signatures[bad] = CommitSig(cs.block_id_flag, cs.validator_address, *tampered)
+        with pytest.raises(ValueError, match=rf"wrong signature \(#{bad}\)"):
+            _check(method, vset, bid, commit)
+
+    @pytest.mark.parametrize("method", VERDICT_METHODS)
+    @pytest.mark.parametrize("kind", ["ed25519", "mixed"])
+    def test_a_nil_vote_verifies_and_does_not_count(self, kind, method):
+        vset, pvs = _verdict_set(kind)
+        bid = make_block_id(b"\x04")
+        total = sum(10 + i for i in range(8))  # 108: more than 72 needed
+        # slots 5..7 hold 48: without them 60 is not enough
+        commit = _signed_commit(vset, pvs, bid, nil={5, 6, 7})
+        with pytest.raises(NotEnoughVotingPowerError) as err:
+            _check(method, vset, bid, commit)
+        assert (err.value.got, err.value.needed) == (total - 48, total * 2 // 3)
+        # absent instead of nil: the same tally
+        for i in (5, 6, 7):
+            commit.signatures[i] = CommitSig.absent()
+        with pytest.raises(NotEnoughVotingPowerError) as err:
+            _check(method, vset, bid, commit)
+        assert (err.value.got, err.value.needed) == (60, 72)
+        # a nil vote is still verified: a bad one fails the commit
+        commit = _signed_commit(vset, pvs, bid, nil={0})
+        _check(method, vset, bid, commit)
+        cs = commit.signatures[0]
+        commit.signatures[0] = CommitSig(cs.block_id_flag, cs.validator_address, cs.timestamp_ns,
+                                         bytes(len(cs.signature)))
+        with pytest.raises(ValueError, match=r"wrong signature \(#0\)"):
+            _check(method, vset, bid, commit)
 
 
 # ---------------------------------------------------------------------------
