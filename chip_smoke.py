@@ -413,16 +413,24 @@ async def phase_commit(
     return report
 
 
-def mesh_placement(node, vset, dispatch: dict) -> dict:
+def mesh_placement(node, vset, dispatch: dict, platform: Optional[str] = None) -> dict:
     """More than one device: the commit's dispatch events must say
     shards == device count, the pubkey table must sit replicated on every
-    device, and the verdict array's shards on that many distinct devices."""
+    device, and the verdict array's shards on that many distinct devices.
+    The report names the inner verify the dispatch event names (`kernel`);
+    on TPUs (`platform`: the backend's, but for the tests) that must be the
+    ladder: XLA Straus on a mesh of chips is the slow path PR 26 took out."""
     import jax
     import numpy as np
 
     n_dev = len(jax.devices())
     if dispatch["shards"] != n_dev:
         raise SmokeFailure(f"dispatch reports shards={dispatch['shards']} on {n_dev} devices")
+    if (platform or jax.default_backend()) == "tpu" and dispatch.get("kernel") != "ladder":
+        raise SmokeFailure(
+            f"{n_dev} TPU chips dispatched the commit on kernel={dispatch.get('kernel')!r}, "
+            "not the Pallas ladder"
+        )
     with node.table_cache._lock:
         table = node.table_cache._tables[vset.pubkeys_digest()]
     rows = table.neg_a_rows
@@ -441,7 +449,8 @@ def mesh_placement(node, vset, dispatch: dict) -> dict:
         raise SmokeFailure(
             f"verdict shards on {len(verdict_devices)} devices, expected {n_dev}"
         )
-    return {"table_devices": len(row_devices), "verdict_devices": len(verdict_devices)}
+    return {"table_devices": len(row_devices), "verdict_devices": len(verdict_devices),
+            "kernel": dispatch.get("kernel")}
 
 
 async def phase_votes(
@@ -536,10 +545,8 @@ class CompileClock:
         }
 
 
-def kernel_name(node, path: str) -> str:
-    if path == "tabulated":
-        return "pallas-tabulated"
-    return "pallas-ladder" if node.batch_verifier._use_pallas() else "xla-straus"
+# the report's names for a dispatch event's `kernel`
+KERNEL_NAMES = {"ladder": "pallas-ladder", "straus": "xla-straus", "tabulated": "pallas-tabulated"}
 
 
 async def run(seed: int, n_validators: int, n_votes: int, n_txs: int,
@@ -561,7 +568,7 @@ async def run(seed: int, n_validators: int, n_votes: int, n_txs: int,
         votes = await phase_votes(node, watch, seed, n_votes, deadline_s)
         await settle(node, watch, deadline_s)
         for phase in (commit, votes):
-            phase["kernel"] = kernel_name(node, phase["path"])
+            phase["kernel"] = KERNEL_NAMES[phase["kernel"]]
         profiles = [e for e in watch.events if e["kind"] == "verify.tabulated_profile"]
         engine = [e for e in watch.events if e["kind"] == "verify.engine"]
         return {

@@ -14,9 +14,11 @@ Split of labor:
   device — [s]B + [h](−A) for the whole batch (ops/ed25519.py).
 
 Batches are padded to power-of-two buckets so XLA compiles a handful of
-shapes once; with a `jax.sharding.Mesh` the batch axis is sharded across
-chips (data-parallel over signatures — the system's scale axis per
-SURVEY.md §5 long-context note).
+shapes once; with a `jax.sharding.Mesh` the batch axis is split across
+chips under `shard_map` (data-parallel over signatures — the system's scale
+axis per SURVEY.md §5 long-context note): every chip runs the same inner
+verify on its rows, no collective.  A batch too small to give every chip a
+whole kernel tile runs on one device of the mesh.
 """
 
 from __future__ import annotations
@@ -251,147 +253,111 @@ def _timed(fn) -> float:
 # instances.  jax.jit memoizes traces per WRAPPER object: a per-instance
 # wrapper re-traces (and re-lowers) every bucket shape for every new
 # verifier — seconds per shape on a small host even when the persistent
-# compile cache hits, and tests/nodes create many verifiers.  Keyed by
-# (mesh, batch_axis): None for the single-device path.
+# compile cache hits, and tests/nodes create many verifiers.
 _shared_jit_lock = _threading.Lock()
 _shared_jit: Dict = {}
 
 
-def _shared_verify_jit(mesh, batch_axis: str):
-    key = (mesh, batch_axis) if mesh is not None else None
+def _shared(key, build):
     with _shared_jit_lock:
         fn = _shared_jit.get(key)
         if fn is None:
-            import jax
-
-            from ..ops import ed25519_kernel
-
-            if mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec as P
-
-                data = NamedSharding(mesh, P(batch_axis))
-                fn = jax.jit(
-                    ed25519_kernel.verify_prepared,
-                    in_shardings=(data, data, data, data, data),
-                    out_shardings=data,
-                )
-            else:
-                fn = jax.jit(ed25519_kernel.verify_prepared)
-            _shared_jit[key] = fn
+            fn = _shared_jit[key] = build()
     return fn
 
 
-def _shared_pallas_fn(tile: int):
+def _shared_pallas_fn(tile: int, interpret: bool = False):
     """Process-wide Pallas verify entry point.  Must be shared for the
     same reason as the jit wrappers — and because _shared_fused_jit keys
     by id(inner): a per-instance functools.partial would mint a fresh
     never-evicted fused-jit cache entry (and a full re-trace) for every
-    PubkeyTable on a TPU backend."""
-    key = ("pallas", tile)
-    with _shared_jit_lock:
-        fn = _shared_jit.get(key)
-        if fn is None:
-            import functools
+    PubkeyTable on a TPU backend.  `interpret` is for the CPU tests."""
 
-            from ..ops.ed25519_pallas import verify_prepared_pallas
+    def build():
+        import functools
 
-            fn = functools.partial(verify_prepared_pallas, tile=tile)
-            _shared_jit[key] = fn
-    return fn
+        from ..ops.ed25519_pallas import verify_prepared_pallas
+
+        return functools.partial(verify_prepared_pallas, tile=tile, interpret=interpret)
+
+    return _shared(("pallas", tile, interpret), build)
 
 
-def _shared_fused_jit(inner, mesh=None, batch_axis: str = "batch"):
-    """Fused gather+verify wrapper, one per inner verify wrapper (which is
-    itself process-wide) — same per-instance re-trace trap as above.
+def _mesh_jit(fn, mesh, batch_axis: str, replicated: int, donate=()):
+    """`fn` once per chip of the mesh under `shard_map`, jitted under fn's
+    own name: its first `replicated` arguments whole on every chip, the five
+    per-signature arrays after them and the verdicts split over the batch
+    axis.  Each chip works on its own rows and no collective runs — which is
+    also how a Pallas custom call, which GSPMD cannot partition, runs on a
+    mesh.  The jit's shardings say the same, so host arrays go straight to
+    their chips."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    specs = (P(),) * replicated + (P(batch_axis),) * 5
+    return jax.jit(
+        jax.shard_map(
+            fn, mesh=mesh, in_specs=specs, out_specs=P(batch_axis), check_vma=False
+        ),
+        in_shardings=tuple(NamedSharding(mesh, spec) for spec in specs),
+        out_shardings=NamedSharding(mesh, P(batch_axis)),
+        donate_argnums=donate,
+    )
+
+
+def _shared_verify_jit(inner, mesh=None, batch_axis: str = "batch"):
+    """The flat dispatch: `inner` itself (a jitted verify) on one device,
+    `inner` per shard on a mesh."""
+    if mesh is None:
+        return inner
+    return _shared(
+        ("flat", id(inner), mesh, batch_axis), lambda: _mesh_jit(inner, mesh, batch_axis, 0)
+    )
+
+
+def _shared_fused_jit(inner, mesh=None, batch_axis: str = "batch", donate: bool = False):
+    """Fused gather+verify wrapper, one per inner verify (which is itself
+    process-wide) — same per-instance re-trace trap as above.
 
     Wire format: h/s arrive as PACKED 32-byte little-endian scalars and are
     expanded to window digits on-device (ops/ed25519.expand_digits) — half
     the per-signature scalar transfer, exactly round-trippable.
 
-    With a mesh the wrapper is itself the sharded dispatch: pubkey rows
-    replicated (the HBM-resident table lives on every chip), per-signature
-    arrays partitioned over the batch axis, output partitioned the same way.
-    The gather then runs shard-local — GSPMD needs no collectives because
-    every device holds the full table.  This is the jit the warmup path
-    compiles, so the first real sharded dispatch never eats the compile."""
-    key = ("fused", id(inner))
-    with _shared_jit_lock:
-        fn = _shared_jit.get(key)
-        if fn is None:
-            import jax
-            import jax.numpy as jnp
+    With a mesh the wrapper is itself the sharded dispatch (_mesh_jit):
+    pubkey rows replicated (the HBM-resident table lives on every chip),
+    per-signature arrays and verdicts split over the batch axis, the gather
+    shard-local.  This is the jit the warmup path compiles, so the first
+    real sharded dispatch never eats the compile.
 
-            from ..ops import ed25519_kernel
+    `donate` is the double-buffered single-shot path's per-chunk variant:
+    the per-signature arrays DONATED — every chunk ships fresh host-prepped
+    buffers, so the device reuses their allocation instead of growing the
+    arena one chunk at a time.  Donation is NOT safe on the plain wrapper
+    (bench and steady-state callers legitimately re-dispatch the same
+    device arrays).  CPU backends ignore donation (and warn per call), so it
+    is requested only off-CPU."""
 
-            def run(rows, idx, h_le, s_le, ry, rs):
-                return inner(
-                    jnp.take(rows, idx, axis=0),
-                    ed25519_kernel.expand_digits(h_le),
-                    ed25519_kernel.expand_digits(s_le),
-                    ry,
-                    rs,
-                )
+    def build():
+        import jax
+        import jax.numpy as jnp
 
-            if mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec as P
+        from ..ops import ed25519_kernel
 
-                repl = NamedSharding(mesh, P())
-                data = NamedSharding(mesh, P(batch_axis))
-                fn = jax.jit(
-                    run,
-                    in_shardings=(repl, data, data, data, data, data),
-                    out_shardings=data,
-                )
-            else:
-                fn = jax.jit(run)
-            _shared_jit[key] = fn
-    return fn
+        def run(rows, idx, h_le, s_le, ry, rs):
+            return inner(
+                jnp.take(rows, idx, axis=0),
+                ed25519_kernel.expand_digits(h_le),
+                ed25519_kernel.expand_digits(s_le),
+                ry,
+                rs,
+            )
 
+        donated = (1, 2, 3, 4, 5) if donate and jax.default_backend() != "cpu" else ()
+        if mesh is not None:
+            return _mesh_jit(run, mesh, batch_axis, 1, donated)
+        return jax.jit(run, donate_argnums=donated)
 
-def _shared_chunked_jit(inner, mesh=None, batch_axis: str = "batch"):
-    """The double-buffered single-shot path's per-chunk dispatch: same
-    fused gather+verify as _shared_fused_jit but with the per-signature
-    arrays DONATED — every chunk ships fresh host-prepped buffers, so the
-    device reuses their allocation instead of growing the arena one chunk
-    at a time.  Donation is NOT safe on the shared fused jit above (bench
-    and steady-state callers legitimately re-dispatch the same device
-    arrays); it lives only here, where the call contract is fresh arrays
-    per chunk.  CPU backends ignore donation (and warn per call), so it is
-    requested only off-CPU."""
-    key = ("chunk", id(inner))
-    with _shared_jit_lock:
-        fn = _shared_jit.get(key)
-        if fn is None:
-            import jax
-            import jax.numpy as jnp
-
-            from ..ops import ed25519_kernel
-
-            def run(rows, idx, h_le, s_le, ry, rs):
-                return inner(
-                    jnp.take(rows, idx, axis=0),
-                    ed25519_kernel.expand_digits(h_le),
-                    ed25519_kernel.expand_digits(s_le),
-                    ry,
-                    rs,
-                )
-
-            donate = () if jax.default_backend() == "cpu" else (1, 2, 3, 4, 5)
-            if mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec as P
-
-                repl = NamedSharding(mesh, P())
-                data = NamedSharding(mesh, P(batch_axis))
-                fn = jax.jit(
-                    run,
-                    in_shardings=(repl, data, data, data, data, data),
-                    out_shardings=data,
-                    donate_argnums=donate,
-                )
-            else:
-                fn = jax.jit(run, donate_argnums=donate)
-            _shared_jit[key] = fn
-    return fn
+    return _shared(("fused", id(inner), mesh, batch_axis, donate), build)
 
 
 class BatchVerifier:
@@ -399,9 +365,11 @@ class BatchVerifier:
 
     On a TPU backend the Pallas kernel (ops/ed25519_pallas.py) runs the
     whole ladder VMEM-resident — ~4x the fused-XLA kernel, ~20x the serial
-    host path.  On CPU (tests) or with `mesh` (multi-chip: inputs/outputs
-    sharded over the batch axis, data-parallel signatures over ICI) the
-    portable XLA kernel (ops/ed25519.py) is used instead.
+    host path; elsewhere (the CPU tests, `mesh = on` on another platform)
+    the portable XLA kernel (ops/ed25519.py) does.  With `mesh` (multi-chip)
+    the same inner verify runs per shard under `shard_map`, inputs and
+    verdicts split over the batch axis; a batch whose bucket would not give
+    every chip a whole kernel tile runs on one device of the mesh instead.
     """
 
     def __init__(
@@ -440,8 +408,8 @@ class BatchVerifier:
         # always device (bench/tests); nodes set it from config
         # (tpu.min_device_batch).
         self.min_device_batch = min_device_batch
-        self._fn = None
         self._pallas = None  # resolved lazily: backend known only at first use
+        self._interpret = False  # the ladder under the Pallas interpreter (CPU tests only)
         # Cold-start handling.  When warmup mode is on, verify() serves any
         # bucket shape whose XLA compile hasn't landed yet from the serial
         # host path while a background thread compiles it — a cold or
@@ -463,8 +431,9 @@ class BatchVerifier:
         pays (see PubkeyTable.chunked_single_shot).
 
         - dispatch_rtt_ms: min round-trip of a minimal jitted dispatch +
-          result fetch (what every extra chunk dispatch costs).
-        - prep_ms_per_chunk: host prep time for one _CHUNK of signatures
+          result fetch (what every extra chunk dispatch costs), over every
+          chip of the mesh when there is one.
+        - prep_ms_per_chunk: host prep time for one chunk of signatures
           (what overlap can hide per extra dispatch).
 
         Chunking is selected iff dispatch_rtt_ms < prep_ms_per_chunk.
@@ -479,7 +448,11 @@ class BatchVerifier:
         from .. import ops  # noqa: F401 — places the compile cache before any compile
 
         tiny = jax.jit(lambda x: x + 1)
-        x = jnp.zeros(8, jnp.int32)
+        x = jnp.zeros(8 * self.shards, jnp.int32)
+        if self.mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            x = jax.device_put(x, NamedSharding(self.mesh, P(self.batch_axis)))
         tiny(x).block_until_ready()  # compile outside the timed loop
         rtts = []
         for _ in range(samples):
@@ -514,14 +487,14 @@ class BatchVerifier:
         return self.rtt_probe
 
     def effective_chunk(self) -> int:
-        """Chunk size for the double-buffered path: the configured size (or
-        the module default), rounded up so each chunk shards evenly over
-        the mesh."""
-        cs = self.chunk_size or _CHUNK
-        m = self._pad_multiple()
-        if cs % m:
-            cs = ((cs + m - 1) // m) * m
-        return cs
+        """Rows of one chunk of the double-buffered path.  The configured
+        size (or the module default) is what ONE chip takes per chunk, in
+        whole kernel tiles: a mesh's chunk is that many rows for every chip,
+        so an extra dispatch buys each chip as much work as it buys a chip
+        alone (2,048 rows over four chips would be one 512-row tile each)."""
+        tile = self._tile()
+        per_chip = -(-(self.chunk_size or _CHUNK) // tile) * tile
+        return per_chip * self.shards
 
     def chunked_auto(self) -> bool:
         """True when the RTT probe says chunked single-shot overlap pays.
@@ -545,7 +518,7 @@ class BatchVerifier:
         s = np.zeros((b, 64), dtype=np.uint8)
         r_y = np.zeros((b, _N_LIMBS), dtype=np.int16)
         r_s = np.zeros(b, dtype=np.uint8)
-        np.asarray(self._jitted()(neg_a, h, s, r_y, r_s))
+        np.asarray(self._jitted(self._shards_for(b))(neg_a, h, s, r_y, r_s))
 
     def _bucket_ready(self, b: int) -> bool:
         """True when bucket b may run on-device without an inline compile.
@@ -633,50 +606,63 @@ class BatchVerifier:
         if self._pallas is None:
             import jax
 
-            self._pallas = self.mesh is None and jax.default_backend() == "tpu"
+            self._pallas = jax.default_backend() == "tpu"
         return self._pallas
 
-    def _jitted(self):
-        # called from warmup threads, the flush executor AND event-loop hook
-        # callers: without the lock two threads could build two jit objects
-        # and the warmup compile would land in a discarded instance
-        with self._warm_lock:
-            return self._jitted_locked()
+    def _tile(self) -> int:
+        """Rows the inner verify takes at a time: the ladder's batch tile;
+        the XLA kernel takes any number."""
+        return _PALLAS_TILE if self._use_pallas() else 1
 
-    def _jitted_locked(self):
-        if self._fn is None:
-            if self._use_pallas():
-                self._fn = _shared_pallas_fn(_PALLAS_TILE)
-            else:
-                self._fn = _shared_verify_jit(self.mesh, self.batch_axis)
-        return self._fn
+    def _inner(self):
+        """The verify that runs on each device (process-wide objects): the
+        Pallas ladder, or XLA Straus where there is no TPU."""
+        if self._use_pallas():
+            return _shared_pallas_fn(_PALLAS_TILE, self._interpret)
+        from ..ops import ed25519_kernel
 
-    def _pad_multiple(self) -> int:
-        if self.mesh is None:
-            return 1
-        return int(np.prod(list(self.mesh.shape.values())))
+        return ed25519_kernel.verify_prepared_jit
+
+    def _mesh_for(self, shards: Optional[int] = None):
+        """The mesh a dispatch split over `shards` devices runs on (the
+        default: every device there is); None for one device."""
+        return self.mesh if (shards or self.shards) > 1 else None
+
+    def _jitted(self, shards: Optional[int] = None):
+        """The flat dispatch over the whole mesh (the default) or, with
+        `shards` 1, on one device."""
+        return _shared_verify_jit(self._inner(), self._mesh_for(shards), self.batch_axis)
 
     def _bucket(self, n: int) -> int:
-        if self._use_pallas():
-            # tile-aligned buckets: powers of two up to 2048, then
-            # multiples of 1024 — bounds padding waste at large batches
-            # (10k pads to 10240, not 16384); shapes are compile-cached
-            if n <= _PALLAS_TILE:
-                return _PALLAS_TILE
-            if n <= 2048:
-                return _bucket_size(n)
-            return ((n + 1023) // 1024) * 1024
-        m = self._pad_multiple()
-        if n <= 2048:
-            return _bucket_size(n, m)
-        # Same padding-waste bound for the XLA path: pure powers of two pad
-        # a 10k commit to 16384 (+60% device time and transfer); multiples
-        # of lcm(1024, mesh) pad it to 10240 while keeping the shape count
-        # compile-cache friendly and every shard evenly loaded.
-        import math as _math
+        """Rows dispatched for a batch of n: powers of two up to 2048, then
+        multiples of 1024 — bounds padding waste at large batches (10k pads
+        to 10240, not 16384; +60% device time and transfer otherwise) while
+        the shapes stay few and compile-cached — never less than one kernel
+        tile.  On a mesh a bucket that gives every shard a tile is rounded up
+        so that every shard's rows are whole tiles; a smaller one is left as
+        one device takes it (_shards_for)."""
+        b = max(_bucket_size(n), self._tile()) if n <= 2048 else -(-n // 1024) * 1024
+        step = self.shards * self._tile()
+        if b >= step and b % step:
+            b = -(-b // step) * step
+        return b
 
-        step = 1024 * m // _math.gcd(1024, m)
-        return ((n + step - 1) // step) * step
+    def _shards_for(self, bucket: int) -> int:
+        """How many devices a bucket's rows are split over: the whole mesh,
+        or one device of it for a bucket under a kernel tile per chip (a
+        166-signature commit in a 512-row bucket must not pad to 2,048 rows
+        to fill four chips)."""
+        return self.shards if bucket >= self.shards * self._tile() else 1
+
+    @staticmethod
+    def shard_fill(n: int, rows: int, shards: int) -> List[int]:
+        """Useful rows in each shard when the first n of `rows` rows are
+        signatures and the rows are split evenly over `shards`: the padding
+        lands on the last shards.  With n over `rows` (the chunked path: a
+        dispatch of several chunks of `rows`), summed over the chunks."""
+        per = rows // shards
+        full, rest = divmod(n, rows)
+        return [full * per + max(0, min(per, rest - i * per)) for i in range(shards)]
 
     def _dispatch_span(self, n: int, bucket: int, path: str) -> "tracing.Span":
         """The `verify.dispatch` span of one engine call.  Its laps tile the
@@ -687,6 +673,44 @@ class BatchVerifier:
             "verify.dispatch", n=n, bucket=bucket, path=path, shards=self.shards,
             host_prep_ms=0.0, device_ms=0.0,
         )
+
+    def _device_span(
+        self, n: int, bucket: int, path: str, shards: int, kernel: Optional[str] = None
+    ) -> "tracing.Span":
+        """A device path's span: besides _dispatch_span's fields, how many
+        devices THIS dispatch is split over (`shards`: the mesh, or 1 with
+        `device`, the one it was routed to), which inner verify the jit wraps
+        (`kernel`: ladder or straus) and the useful rows in each shard
+        (`shard_n`; on the chunked path, where `bucket` is one chunk, summed
+        over the chunks)."""
+        span = self._dispatch_span(n, bucket, path)
+        span.set(
+            shards=shards,
+            kernel=kernel or ("ladder" if self._use_pallas() else "straus"),
+            shard_n=self.shard_fill(n, bucket, shards),
+        )
+        if shards == 1 and self.mesh is not None:
+            span.set(device=int(self.mesh.devices.flat[0].id))
+        return span
+
+    def _put(self, span: "tracing.Span", shards: int, arrays):
+        """Start the per-signature arrays' transfer to the mesh, split over
+        the batch axis (SNIPPETS pjit guidance: correctly pre-partitioned
+        inputs skip the resharding step), or to the one device.  Async; the
+        time the host spends here is `put_ms`, a part of `launch_ms`."""
+        import time as _time
+
+        import jax
+
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        t0 = _time.perf_counter()
+        where = None if shards == 1 else NamedSharding(self.mesh, P(self.batch_axis))
+        dev = jax.device_put(list(arrays), where)
+        span.fields["put_ms"] = (
+            span.fields.get("put_ms", 0.0) + (_time.perf_counter() - t0) * 1e3
+        )
+        return dev
 
     def _end_device_span(self, span: "tracing.Span") -> None:
         """Close a device path's span: `device_ms` is what followed host
@@ -710,7 +734,8 @@ class BatchVerifier:
                 out = batch_hook.host_batch_verify(pubkeys, msgs, sigs)
                 span.lap("device_ms")
             return out
-        span = self._dispatch_span(n, b, "device")
+        shards = self._shards_for(b)
+        span = self._device_span(n, b, "device", shards)
         neg_a, h_digits, s_digits, r_y, r_sign, valid = prepare_batch(pubkeys, msgs, sigs)
         self.metrics.host_prep_seconds.observe(span.lap("host_prep_ms") / 1e3)
         if not valid.any():
@@ -720,7 +745,10 @@ class BatchVerifier:
             neg_a = np.concatenate([neg_a, np.tile(neg_a[-1:], (b - n, 1, 1))])
         h_digits, s_digits, r_y, r_sign = _pad_scalar_rows(b, h_digits, s_digits, r_y, r_sign)
         span.lap("pack_ms")
-        dev_ok = self._jitted()(neg_a, h_digits, s_digits, r_y, r_sign)
+        args = (neg_a, h_digits, s_digits, r_y, r_sign)
+        if shards > 1:
+            args = self._put(span, shards, args)
+        dev_ok = self._jitted(shards)(*args)
         span.lap("launch_ms")
         out = list(np.logical_and(np.asarray(dev_ok)[:n], valid))
         span.lap("fetch_ms")
@@ -794,11 +822,10 @@ class PubkeyTable:
             self.neg_a_rows = jax.device_put(
                 jnp.asarray(rows), NamedSharding(self.verifier.mesh, P())
             )
+            # the first device's copy, for a batch routed to one device
+            self._rows_one = self.neg_a_rows.addressable_shards[0].data
         else:
-            self.neg_a_rows = jnp.asarray(rows)  # device-resident
-        self._fused_fn = None
-        self._chunk_fn_cached = None
-        self._chunk_sharding = None
+            self.neg_a_rows = self._rows_one = jnp.asarray(rows)  # device-resident
         if n > self.TABULATED_MAX_VALIDATORS:
             tabulated = False
         # None = auto: resolved at the first real dispatch by a one-time
@@ -836,13 +863,14 @@ class PubkeyTable:
 
     def _auto_tabulated(self, n: int) -> bool:
         """Auto-engage rule: only where the Pallas tabulated kernel can run
-        at all (TPU backend, single device — under a mesh the sharded
-        ladder owns the path), and only when a one-shot timed comparison at
-        this commit's bucket shape says the zero-doubling gather beats the
+        at all (TPU backend, single device — the tabulated kernel is not
+        sharded, so under a mesh the sharded ladder owns the path and no
+        profile runs), and only when a one-shot timed comparison at this
+        commit's bucket shape says the zero-doubling gather beats the
         VMEM-resident ladder.  The table build is amortized against the
         warm validator set; the verdict against the whole process (cached
         per backend — it is a property of the chip, not the table)."""
-        if not self.verifier._use_pallas():
+        if self.verifier.mesh is not None or not self.verifier._use_pallas():
             return False
         import jax
 
@@ -933,47 +961,19 @@ class PubkeyTable:
     def __len__(self) -> int:
         return len(self.pubkeys)
 
-    def _fused(self):
+    def _fused(self, shards: Optional[int] = None, donate: bool = False):
         """One jitted dispatch: on-device gather of the pubkey rows fused
         with the verify kernel — a second dispatch would pay the host↔device
-        round trip twice.  Takes PACKED h/s (32 B/scalar, _pack_digits); expansion happens
-        in-kernel.  With a mesh this is the sharded jit (rows replicated,
-        per-signature arrays partitioned over the batch axis)."""
-        if self._fused_fn is None:
-            self._fused_fn = _shared_fused_jit(
-                self.verifier._jitted(),
-                self.verifier.mesh,
-                self.verifier.batch_axis,
-            )
-        return self._fused_fn
+        round trip twice.  Takes PACKED h/s (32 B/scalar, _pack_digits);
+        expansion happens in-kernel.  Over the whole mesh (the default: rows
+        replicated, per-signature arrays split over the batch axis) or, with
+        `shards` 1, on one device."""
+        v = self.verifier
+        return _shared_fused_jit(v._inner(), v._mesh_for(shards), v.batch_axis, donate)
 
     def _chunked(self):
-        """Per-chunk donated-buffer variant of _fused (see _shared_chunked_jit)."""
-        if self._chunk_fn_cached is None:
-            self._chunk_fn_cached = _shared_chunked_jit(
-                self.verifier._jitted(),
-                self.verifier.mesh,
-                self.verifier.batch_axis,
-            )
-        return self._chunk_fn_cached
-
-    def _put_chunk(self, *arrays):
-        """Async device_put of one chunk's per-signature arrays, pre-
-        partitioned over the mesh when present (SNIPPETS pjit guidance:
-        correctly pre-partitioned inputs skip the resharding step).  The
-        transfer of chunk k+1 overlaps device verify of chunk k, and the
-        resulting jax Arrays are what the donated chunk jit consumes."""
-        import jax
-
-        if self.verifier.mesh is None:
-            return [jax.device_put(a) for a in arrays]
-        if self._chunk_sharding is None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            self._chunk_sharding = NamedSharding(
-                self.verifier.mesh, P(self.verifier.batch_axis)
-            )
-        return [jax.device_put(a, self._chunk_sharding) for a in arrays]
+        """Per-chunk donated-buffer variant of _fused (see _shared_fused_jit)."""
+        return self._fused(donate=True)
 
     def verify_indexed(
         self, idxs: Sequence[int], msgs: Sequence[bytes], sigs: Sequence[bytes]
@@ -1009,7 +1009,9 @@ class PubkeyTable:
             path, b = "tabulated", ((n + tile - 1) // tile) * tile
         else:
             path, b = "indexed", self.verifier._bucket(n)
-        span = self.verifier._dispatch_span(n, b, path)
+        # a chunk gives every chip whole tiles; the tabulated kernel is unsharded
+        shards = 1 if tab else self.verifier._shards_for(b)
+        span = self.verifier._device_span(n, b, path, shards, "tabulated" if tab else None)
 
         idx_arr = np.asarray(idxs, dtype=np.int32)
         # Host prep for everything except pubkey limbs (gathered on device);
@@ -1059,7 +1061,9 @@ class PubkeyTable:
                 while len(pending) >= depth:
                     _collect()
                 span.lap("fetch_ms")
-                dev = self._put_chunk(idx_c, h, s, ry, rs)
+                # the transfer of chunk k+1 overlaps device verify of chunk
+                # k; the jax Arrays are what the donated chunk jit consumes
+                dev = self.verifier._put(span, shards, (idx_c, h, s, ry, rs))
                 pending.append((fn(self.neg_a_rows, *dev), valid_c, cnt))
                 span.lap("launch_ms")
             while pending:
@@ -1089,10 +1093,14 @@ class PubkeyTable:
                 tile=tile, interpret=self._interpret,
             )
         else:
-            fused = self._fused()
+            fused = self._fused(shards)
             h_digits, s_digits = _pack_digits(h_digits), _pack_digits(s_digits)
             span.lap("pack_ms")
-            dev_ok = fused(self.neg_a_rows, idx_arr, h_digits, s_digits, r_y, r_sign)
+            args = (idx_arr, h_digits, s_digits, r_y, r_sign)
+            if shards > 1:
+                dev_ok = fused(self.neg_a_rows, *self.verifier._put(span, shards, args))
+            else:
+                dev_ok = fused(self._rows_one, *args)
         span.lap("launch_ms")
         out = list(np.logical_and(np.asarray(dev_ok)[:n], valid))
         span.lap("fetch_ms")

@@ -36,7 +36,18 @@ Event kinds currently emitted:
                                                indices); pack_ms + launch_ms
                                                (until the jitted call returns) +
                                                fetch_ms (blocked in np.asarray)
-                                               split device_ms on device paths
+                                               split device_ms on device paths.
+                                               Device paths also carry kernel
+                                               (ladder | straus | tabulated: the
+                                               inner verify), shard_n (useful
+                                               rows per shard; shards is how
+                                               many devices THIS dispatch was
+                                               split over), device (the one a
+                                               mesh's small batch ran on) and
+                                               put_ms (the host's time in the
+                                               explicit argument transfer, a
+                                               part of launch_ms: every mesh
+                                               dispatch and every chunk)
     verify.bucket_compile  bucket, ms, ok      background XLA compile done
     verify.chunked    selected, rtt_ms, prep_ms    RTT-probe decision
     verify.table      hit, n                   TableCache lookup
@@ -678,7 +689,8 @@ REPLAY_ROWS = (
     ("fastsync.block", ("validate_ms", "abci_req_ms", "deliver_ms", "mempool_ms",
                         "save_state_ms", "events_ms")),
     ("verify.commit", ("sign_bytes_ms", "engine_ms", "tally_ms")),
-    ("verify.dispatch", ("rows_ms", "host_prep_ms", "pack_ms", "launch_ms", "fetch_ms")),
+    ("verify.dispatch", ("rows_ms", "host_prep_ms", "pack_ms", "launch_ms", "put_ms",
+                         "fetch_ms")),
     ("fastsync.block", ("decode_ms", "download_ms", "queued_ms")),
 )
 
@@ -713,6 +725,26 @@ def replay_budget(events: List[dict]) -> Optional[dict]:
             "n": sum(ev.get("n", 0) for ev in commits),
             "templated": sum(ev.get("templated", 0) for ev in commits),
         }
+    dispatches = [
+        ev for (kind, _), evs in children.items() if kind == "verify.dispatch" for ev in evs
+        if "shard_n" in ev
+    ]
+    if dispatches:
+        # where the blocks' device dispatches ran: by inner verify and by how
+        # many devices each was split over, and the useful share of the rows
+        # of the shard that held fewest (the mesh's padding lands on the
+        # last; a chunked dispatch's `bucket` is one chunk)
+        placed: dict = {}
+        for ev in dispatches:
+            where = f"{ev.get('kernel')} x{ev['shards']}"
+            if "device" in ev:
+                where += f" (device {ev['device']})"
+            placed[where] = placed.get(where, 0) + 1
+        out["dispatch_placement"] = placed
+        out["min_shard_fill"] = round(min(
+            min(ev["shard_n"]) * ev["shards"] / (
+                ev["bucket"] * (-(-ev["n"] // ev["bucket"]) if ev["path"] == "chunked" else 1))
+            for ev in dispatches), 4)
     rows = [("block_ms", [ev["dur_ns"] / 1e6 for ev in blocks])]
     for kind, names in REPLAY_ROWS:
         own = kind == "fastsync.block"
@@ -743,6 +775,10 @@ def format_replay_budget(budget: Optional[dict]) -> str:
     if "commit_messages" in budget:
         msgs = budget["commit_messages"]
         lines.insert(1, f"  commit messages: {msgs['templated']} of {msgs['n']} from a template")
+    if "dispatch_placement" in budget:
+        placed = ", ".join(f"{n} on {where}" for where, n in budget["dispatch_placement"].items())
+        lines.insert(1, f"  device dispatches: {placed}; useful rows in the least-filled "
+                        f"shard {100 * budget['min_shard_fill']:.1f}%")
     for name, st in budget["stages"].items():
         lines.append(
             f"  {name:<26}{st['mean_ms']:>10.3f}{st['p50_ms']:>10.3f}{st['p90_ms']:>10.3f}"

@@ -75,8 +75,24 @@ class TestSmoke:
         assert set(commit["tampered"]) == {
             "signature", "message", "noncanonical_s", "invalid_pubkey"
         }
-        assert commit["placement"] == {"table_devices": n_dev, "verdict_devices": n_dev}
+        assert commit["placement"] == {
+            "table_devices": n_dev, "verdict_devices": n_dev, "kernel": "straus"}
         assert commit["kernel"] == votes["kernel"] == "xla-straus"
+
+    def test_straus_on_a_mesh_of_tpus_fails_the_smoke(self):
+        """The placement check reads the dispatch event's `kernel`: four TPU
+        chips that dispatched XLA Straus are refused before anything else is
+        looked at, a CPU mesh (the rehearsal above) is not."""
+        import jax
+
+        n_dev = len(jax.devices())
+        straus = {"shards": n_dev, "kernel": "straus", "path": "indexed", "bucket": 64}
+        with pytest.raises(chip_smoke.SmokeFailure, match="kernel='straus', not the Pallas ladder"):
+            chip_smoke.mesh_placement(None, None, straus, platform="tpu")
+        with pytest.raises(chip_smoke.SmokeFailure, match="kernel=None"):  # an older engine's event
+            chip_smoke.mesh_placement(None, None, {"shards": n_dev}, platform="tpu")
+        with pytest.raises(AttributeError):  # the ladder passes on to the table checks
+            chip_smoke.mesh_placement(None, None, {**straus, "kernel": "ladder"}, platform="tpu")
 
     def test_main_refuses_a_cpu(self, capsys):
         assert chip_smoke.main([]) != 0

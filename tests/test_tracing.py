@@ -476,6 +476,23 @@ class TestReplayBudget:
         assert budget["commit_messages"] == {"n": 12, "templated": 12}
         assert "commit messages: 12 of 12 from a template" in tracing.format_replay_budget(budget)
 
+    def test_the_dump_says_where_the_dispatches_ran_and_how_full_the_shards_were(self):
+        events = self._events()
+        assert "dispatch_placement" not in tracing.replay_budget(events)  # an older node's events
+        for i, ev in enumerate(e for e in events if e["kind"] == "verify.dispatch" and "id" in e):
+            if i == 0:  # a small batch, routed to one device of the mesh
+                ev.update(n=3, bucket=16, shards=1, kernel="ladder", shard_n=[3], device=0)
+            else:
+                ev.update(n=45, bucket=64, shards=4, kernel="ladder", shard_n=[16, 16, 13, 0],
+                          put_ms=0.2)
+        budget = tracing.replay_budget(events)
+        assert budget["dispatch_placement"] == {"ladder x1 (device 0)": 1, "ladder x4": 3}
+        assert budget["min_shard_fill"] == 0.0
+        assert budget["stages"]["dispatch.put_ms"]["mean_ms"] == 0.3  # 1 and 2 a block
+        table = tracing.format_replay_budget(budget)
+        assert "device dispatches: 1 on ladder x1 (device 0), 3 on ladder x4" in table
+        assert "dispatch.put_ms" in table
+
     def test_nothing_to_budget_without_a_block_span(self):
         assert tracing.replay_budget([{"kind": "verify.commit", "id": 3}]) is None
         assert "nothing to budget" in tracing.format_replay_budget(None)
@@ -642,6 +659,10 @@ class TestDispatchSpans:
             assert ev["pack_ms"] + ev["launch_ms"] + ev["fetch_ms"] == pytest.approx(
                 ev["device_ms"], abs=0.01)
             assert ev["bucket"] == {"chunked": 32, "tabulated": 256}.get(path, engine._bucket(n))
+            # one device, no mesh: all the useful rows in its one shard, no device named
+            assert ev["shard_n"] == [n] and "device" not in ev
+            assert ev["kernel"] == ("tabulated" if path == "tabulated" else "straus")
+            assert ("put_ms" in ev) == (path == "chunked")  # only the chunks are put by hand
             # one reading, two sinks: the histograms saw the event's numbers
             assert prep_hist.seen[-1] * 1e3 == pytest.approx(ev["host_prep_ms"], abs=0.001)
             assert dev_hist.seen[-1] * 1e3 == pytest.approx(ev["device_ms"], abs=0.001)
