@@ -55,14 +55,20 @@ Event kinds currently emitted:
     verify.commit     height, n, sign_bytes_ms, engine_ms, tally_ms   SPAN
                                                around verify_commit /
                                                verify_commit_trusting
-  fast sync (fastsync/reactor.py, state/execution.py):
+  fast sync (fastsync/reactor.py, state/execution.py, state/validation.py):
     fastsync.block    SPAN, one per block applied, id = height: peek_two to
                       block_processed.  In-span stages parts_ms, verify_ms,
                       store_ms, apply_ms (sum <= dur_ns); from apply_block
                       validate_ms, abci_req_ms (BeginBlock's request built),
                       deliver_ms (BeginBlock's call to Commit's return),
-                      mempool_ms, save_state_ms, events_ms; carried with the
-                      block bytes, decode_ms, download_ms (request to receipt),
+                      mempool_ms, save_state_ms, events_ms; inside
+                      validate_ms, from validate_block, set_hash_ms (the
+                      header's two validator-set hashes held against the
+                      state's sets) and set_hashes (how many of the two
+                      Merkle roots were built for it, a count: 0 on a set
+                      that has not changed since its root was taken);
+                      carried with the block bytes, decode_ms, download_ms
+                      (request to receipt),
                       queued_ms (receipt to peek_two), peer; wait_ms (since the
                       previous block's end), pending (blocks queued)
   gossip (consensus/reactor.py, event-driven path):
@@ -681,13 +687,16 @@ def format_budget(budget: Optional[dict]) -> str:
 
 #: The rows of the replay budget, outermost first: what tiles a block's
 #: interval (the wait since the block before, then the in-span stages),
-#: apply_block's stages inside apply_ms, the two commit verifications inside
-#: verify_ms and validate_ms, the engine's calls inside those, and what the
-#: receive path measured before the block was queued.
+#: apply_block's stages inside apply_ms, the validator sets' roots inside
+#: validate_ms (set_hashes is a count of roots built, not milliseconds), the
+#: two commit verifications inside verify_ms and validate_ms, the engine's
+#: calls inside those, and what the receive path measured before the block
+#: was queued.
 REPLAY_ROWS = (
     ("fastsync.block", ("wait_ms", "parts_ms", "verify_ms", "store_ms", "apply_ms")),
     ("fastsync.block", ("validate_ms", "abci_req_ms", "deliver_ms", "mempool_ms",
                         "save_state_ms", "events_ms")),
+    ("fastsync.block", ("set_hash_ms", "set_hashes")),
     ("verify.commit", ("sign_bytes_ms", "engine_ms", "tally_ms")),
     ("verify.dispatch", ("rows_ms", "host_prep_ms", "pack_ms", "launch_ms", "put_ms",
                          "fetch_ms")),

@@ -7,8 +7,10 @@ batch target #2 (SURVEY.md §3.2).
 
 from __future__ import annotations
 
+import time
 from typing import Optional
 
+from ..libs import tracing
 from ..types import Block
 from ..types.block import ADDRESS_SIZE
 from ..types.params import max_evidence_per_block
@@ -50,10 +52,16 @@ def validate_block(state: State, block: Block, state_store=None, evidence_pool=N
         raise InvalidBlockError("wrong Block.Header.ConsensusHash")
     if h.last_results_hash != state.last_results_hash:
         raise InvalidBlockError("wrong Block.Header.LastResultsHash")
+    # The two set roots, a stage of their own inside the caller's validate_ms,
+    # on whichever span is open: a set keeps its root until it changes
+    # (ValidatorSet.hash), so `set_hashes` says how many were built here.
+    t0 = time.monotonic_ns()
+    built = (state.validators._root is None) + (state.next_validators._root is None)
     if h.validators_hash != state.validators.hash():
         raise InvalidBlockError("wrong Block.Header.ValidatorsHash")
     if h.next_validators_hash != state.next_validators.hash():
         raise InvalidBlockError("wrong Block.Header.NextValidatorsHash")
+    tracing.annotate(set_hash_ms=(time.monotonic_ns() - t0) / 1e6, set_hashes=built)
 
     # LastCommit — batched signature verification (TPU target #2)
     if block.height == 1:

@@ -172,6 +172,7 @@ class ValidatorSet:
         self.proposer: Optional[Validator] = None
         self._total_voting_power = 0
         self._pk_digest: Optional[bytes] = None
+        self._root: Optional[bytes] = None
         if validators:
             self._update_with_change_set(validators, allow_deletes=False)
             self.increment_proposer_priority(1)
@@ -252,13 +253,19 @@ class ValidatorSet:
         new.proposer = self.proposer
         new._total_voting_power = self._total_voting_power
         new._pk_digest = self._pk_digest
+        new._root = self._root
         return new
 
     def hash(self) -> bytes:
-        """Merkle root over validator bytes (types/validator_set.go:315)."""
+        """Merkle root over validator bytes (types/validator_set.go:315),
+        built once and kept until a pubkey or a power changes: priorities
+        and addresses are not in `Validator.bytes()`, so a block's rotation
+        of the proposer leaves it standing."""
         if not self.validators:
             return b""
-        return merkle.hash_from_byte_slices([v.bytes() for v in self.validators])
+        if self._root is None:
+            self._root = merkle.hash_from_byte_slices([v.bytes() for v in self.validators])
+        return self._root
 
     # -- proposer rotation -------------------------------------------------
     def get_proposer(self) -> Optional[Validator]:
@@ -365,6 +372,7 @@ class ValidatorSet:
         self._apply_updates(updates)
         self._apply_removals(deletes)
         self._pk_digest = None  # membership changed: table cache key rotates
+        self._root = None  # and the one place a power changes: hash() builds anew
         self._update_total_voting_power()
         self.rescale_priorities(PRIORITY_WINDOW_SIZE_FACTOR * self.total_voting_power())
         self._shift_by_avg_proposer_priority()

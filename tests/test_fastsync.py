@@ -15,6 +15,7 @@ from tendermint_tpu.rpc.core import RPCCore
 from tendermint_tpu.types import GenesisDoc, GenesisValidator, MockPV
 
 from tests.test_consensus_net import CHAIN_ID, make_net, stop_net, wait_all_height
+from tests.test_types import CHAIN_ID as TYPES_CHAIN_ID
 from tests.test_types import make_block_id, make_commit, rand_validator_set
 
 from tendermint_tpu.types.params import BlockParams as _BP, ConsensusParams as _CP
@@ -270,6 +271,126 @@ class TestReplaySpans:
         assert len(per_block) <= 8 * len(blocks)
         # consensus's deliver.* events stay consensus's: fast sync adds none
         assert not [e for e in events if e["kind"].startswith("deliver.") and e["height"] in heights]
+
+
+class TestSetHashStage:
+    """`validate_block` holds the header's two validator-set hashes against
+    the state's sets on every block; the sets keep their Merkle root across
+    `update_state`'s copies, so on a set that did not change the stage
+    builds none.  Read off the open span, as `validate_ms` is."""
+
+    CHAIN = TYPES_CHAIN_ID  # the chain `make_commit` signs for
+    HEIGHTS = 6
+
+    @staticmethod
+    async def _executor():
+        from tendermint_tpu.abci.client import LocalClient
+        from tendermint_tpu.abci.examples import KVStoreApplication
+        from tendermint_tpu.libs.kvstore import MemDB
+        from tendermint_tpu.mempool import NopMempool
+        from tendermint_tpu.state import StateStore
+        from tendermint_tpu.state.execution import BlockExecutor
+
+        app = LocalClient(KVStoreApplication())
+        await app.start()
+        return BlockExecutor(StateStore(MemDB()), app, NopMempool())
+
+    def _genesis(self, pvs):
+        return GenesisDoc(
+            chain_id=self.CHAIN,
+            genesis_time_ns=1_700_000_000_000_000_000,
+            validators=[GenesisValidator(pv.address(), pv.get_pub_key(), 10) for pv in pvs],
+        )
+
+    async def _chain(self, pvs, update_at=None):
+        """Blocks 1..HEIGHTS with their ids, made and applied by a proposer
+        with a state of its own: `make_block` warms that state's sets, the
+        replayer's start cold.  The block at `update_at` carries a kvstore
+        validator tx, so its EndBlock returns a power change."""
+        import base64
+
+        from tendermint_tpu.state import make_genesis_state
+
+        state = make_genesis_state(self._genesis(pvs))
+        ex = await self._executor()
+        commit, chain = None, []
+        try:
+            for h in range(1, self.HEIGHTS + 1):
+                txs = [b"k%d=v%d" % (h, h)]
+                if h == update_at:
+                    pk = base64.b64encode(pvs[0].get_pub_key().bytes())
+                    txs.append(b"val:" + pk + b"!15")
+                block = state.make_block(
+                    h, txs, commit, [], state.validators.get_proposer().address)
+                block_id = block.block_id(65536)
+                commit = make_commit(state.validators, pvs, h, 0, block_id)
+                chain.append((block_id, block))
+                state, _ = await ex.apply_block(state, block_id, block)
+        finally:
+            await ex.proxy_app.stop()
+        return chain
+
+    async def _replay(self, pvs, chain):
+        """A joining node's side: a fresh state from genesis, each block
+        through `apply_block` under an open `fastsync.block` span.  Returns
+        the blocks' events, the state reached and the executor."""
+        from tendermint_tpu.state import make_genesis_state
+
+        rec = tracing.FlightRecorder(size=64)
+        state = make_genesis_state(self._genesis(pvs))
+        ex = await self._executor()
+        try:
+            for block_id, block in chain:
+                with rec.span("fastsync.block", id=block.height):
+                    state, _ = await ex.apply_block(state, block_id, block)
+        finally:
+            await ex.proxy_app.stop()
+        blocks = [e for e in rec.events() if e["kind"] == "fastsync.block"]
+        return blocks, state, ex
+
+    async def test_a_static_set_builds_its_roots_once(self):
+        pvs = sorted((MockPV() for _ in range(4)), key=lambda pv: pv.address())
+        events, state, _ = await self._replay(pvs, await self._chain(pvs))
+        assert [e["id"] for e in events] == [1, 2, 3, 4, 5, 6]
+        built = [e["set_hashes"] for e in events]
+        assert built[0] <= 2 and built[1:] == [0, 0, 0, 0, 0]
+        for ev in events:
+            assert 0 <= ev["set_hash_ms"] <= ev["validate_ms"]
+        assert state.validators._root is not None and state.next_validators._root is not None
+
+    async def test_a_set_that_changes_pays_one_root_per_change(self):
+        pvs = sorted((MockPV() for _ in range(4)), key=lambda pv: pv.address())
+        chain = await self._chain(pvs, update_at=3)
+        events, state, _ = await self._replay(pvs, chain)
+        # EndBlock of height 3 changes a power: block 4 is the first held
+        # against the changed next_validators and builds its root, once;
+        # at 5 that set is promoted to validators by copy(), root and all
+        built = [e["set_hashes"] for e in events]
+        assert built[0] <= 2 and built[1:] == [0, 0, 1, 0, 0]
+        assert chain[3][1].header.next_validators_hash != chain[2][1].header.next_validators_hash
+        assert chain[4][1].header.validators_hash == chain[3][1].header.next_validators_hash
+        _, changed = state.validators.get_by_address(pvs[0].address())
+        assert changed.voting_power == 15
+
+    @pytest.mark.parametrize("field", ["validators_hash", "next_validators_hash"])
+    async def test_a_header_that_lies_about_a_set_is_rejected_with_the_memo_warm(self, field):
+        from dataclasses import replace
+
+        from tendermint_tpu.state.validation import InvalidBlockError
+        from tendermint_tpu.types import Block
+
+        pvs = sorted((MockPV() for _ in range(4)), key=lambda pv: pv.address())
+        chain = await self._chain(pvs)
+        _, state, ex = await self._replay(pvs, chain[:3])
+        assert state.validators._root is not None and state.next_validators._root is not None
+        block_id, block = chain[3]
+        name = "".join(part.capitalize() for part in field.split("_"))
+        for wrong in (b"\x00" * 32, b""):
+            forged = Block(replace(block.header, **{field: wrong}), block.txs,
+                           block.evidence, block.last_commit)
+            with pytest.raises(InvalidBlockError, match=rf"Header\.{name}$"):
+                await ex.apply_block(state, block_id, forged)
+        ex.validate_block(state, block)  # and the honest block still passes
 
 
 class TestBehaviourReporting:

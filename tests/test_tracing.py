@@ -493,6 +493,32 @@ class TestReplayBudget:
         assert "device dispatches: 1 on ladder x1 (device 0), 3 on ladder x4" in table
         assert "dispatch.put_ms" in table
 
+    def test_the_set_hash_stage_is_listed_and_printed(self):
+        """`set_hash_ms` and `set_hashes` (state/validation.py) are fields of
+        `fastsync.block`: in the rows `trace --replay` prints, in the module's
+        list of the span's fields, and in the table for a recorded chain."""
+        own = [name for kind, names in tracing.REPLAY_ROWS if kind == "fastsync.block"
+               for name in names]
+        assert {"set_hash_ms", "set_hashes"} <= set(own) and len(own) == len(set(own))
+        listed = tracing.__doc__.split("fastsync.block    SPAN", 1)[1].split("gossip (", 1)[0]
+        assert "set_hash_ms" in listed and "set_hashes" in listed and "validate_ms" in listed
+        events = self._events()
+        assert "set_hash_ms" not in tracing.replay_budget(events)["stages"]  # an older node
+        blocks = [ev for ev in events if ev["kind"] == "fastsync.block"]
+        blocks[0].update(validate_ms=3.0, set_hash_ms=1.5, set_hashes=2)  # the first block applied
+        blocks[1].update(validate_ms=1.0, set_hash_ms=0.002, set_hashes=0)  # an unchanged set
+        st = tracing.replay_budget(events)["stages"]
+        assert st["set_hashes"]["mean_ms"] == 1.0 and st["set_hashes"]["p90_ms"] == 2
+        assert st["set_hash_ms"]["mean_ms"] == 0.751
+        assert list(st).index("validate_ms") < list(st).index("set_hash_ms") \
+            < list(st).index("commit.sign_bytes_ms")
+        table = tracing.format_replay_budget(tracing.replay_budget(events))
+        assert "  set_hash_ms " in table and "  set_hashes " in table
+        for ev in blocks:  # a warm static set: the count reads 0 and its row goes
+            ev.update(set_hash_ms=0.002, set_hashes=0)
+        st = tracing.replay_budget(events)["stages"]
+        assert "set_hashes" not in st and st["set_hash_ms"]["mean_ms"] == 0.002
+
     def test_nothing_to_budget_without_a_block_span(self):
         assert tracing.replay_budget([{"kind": "verify.commit", "id": 3}]) is None
         assert "nothing to budget" in tracing.format_replay_budget(None)
@@ -754,6 +780,35 @@ class TestCommitSpans:
                 assert c["sign_bytes_ms"] + c["engine_ms"] + c["tally_ms"] <= c["dur_ns"] / 1e6 + 0.005
             for d in (e for e in mine if e["kind"] == "verify.dispatch"):
                 assert (d["parent"], d["path"], d["n"]) == ("verify.commit", "indexed", 6)
+
+    def test_the_set_hash_stage_is_two_fields_and_no_event(self):
+        """`validate_block` under an open span: the two validator-set roots
+        are a stage on that span's one event, so a block's 7 events stay 7.
+        Height 1 has no LastCommit, so the span is all there is."""
+        from tendermint_tpu.state import make_genesis_state
+        from tendermint_tpu.state.validation import validate_block
+        from tendermint_tpu.types import GenesisDoc, GenesisValidator, MockPV
+
+        pvs = [MockPV() for _ in range(7)]
+
+        def genesis_state():
+            return make_genesis_state(GenesisDoc(
+                chain_id=self.CHAIN, genesis_time_ns=1_700_000_000_000_000_000,
+                validators=[GenesisValidator(pv.address(), pv.get_pub_key(), 10) for pv in pvs]))
+
+        proposer = genesis_state()
+        block = proposer.make_block(1, [b"a=b"], None, [], proposer.validators.get_proposer().address)
+        state = genesis_state()  # the validating node's own: no root taken yet
+        rec = FlightRecorder(size=16)
+        for _ in range(2):
+            with rec.span("fastsync.block", id=1):
+                validate_block(state, block)
+        first, second = rec.events()
+        assert first["kind"] == second["kind"] == "fastsync.block"
+        assert (first["set_hashes"], second["set_hashes"]) == (2, 0)
+        assert 0 < second["set_hash_ms"] < first["set_hash_ms"] <= first["dur_ns"] / 1e6
+        validate_block(state, block)  # outside any span: nothing to annotate, nothing raised
+        assert len(rec.events()) == 2
 
     def test_the_trusting_check_closes_the_same_span(self):
         rec = FlightRecorder(size=16)
