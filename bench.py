@@ -40,11 +40,9 @@ _CPU_CHILD_ENV = dict(os.environ, JAX_PLATFORMS="cpu")
 def bench_primary(n_vals: int = 10_000):
     """10k-validator commit batch: latency + steady-state + breakdown.
 
-    Measures the engine's ACTIVE steady-state path: on a TPU backend that is
-    the tabulated zero-doubling kernel (ops/ed25519_table.py — per-validator
-    window tables in HBM, 128 gathered adds per signature, no ladder); on
-    CPU/mesh it is the fused gather + Straus kernel.  Table build time is
-    reported separately (one-time per validator-set change).
+    Measures the engine's steady-state path, the fused gather + verify
+    kernel (the Pallas ladder on a TPU backend, XLA Straus elsewhere).  Table
+    build time is reported separately (one-time per validator-set change).
 
     Also reports the host<->device dispatch RTT probe and BOTH single-shot
     flavors — monolithic (one dispatch) and double-buffered chunked (prep
@@ -65,17 +63,10 @@ def bench_primary(n_vals: int = 10_000):
     ]
     sigs = [k.sign(m) for k, m in zip(keys, msgs)]
 
-    table = PubkeyTable(pubkeys, BatchVerifier())  # tabulated auto-profiled on TPU
-    idxs = list(range(n_vals))
-    # Resolve the tabulated auto-profile up front (on a TPU backend this
-    # times both kernels once, building the window tables along the way) so
-    # the warm runs below measure the path the engine actually selected;
-    # the one-time resolve+build cost is what table_build_ms reports.
-    table_build_ms = 0.0
     t0 = time.perf_counter()
-    if table._tabulated_active(n_vals):
-        table.build_tables()
-        table_build_ms = (time.perf_counter() - t0) * 1000
+    table = PubkeyTable(pubkeys, BatchVerifier())
+    table_build_ms = (time.perf_counter() - t0) * 1000
+    idxs = list(range(n_vals))
     ok = table.verify_indexed(idxs, msgs, sigs)  # warmup/compile
     assert all(ok), "bench batch failed to verify"
 
@@ -111,42 +102,24 @@ def bench_primary(n_vals: int = 10_000):
 
     # steady state: K pipelined device batches, one fetch at the end
     K = 10
-    if table.tabulated:
-        from tendermint_tpu.ops import ed25519_table
-
-        tile = 256
-        b = ((n_vals + tile - 1) // tile) * tile
-        h2, s2, ry2, rs2 = bv._pad_scalar_rows(b, h, s, ry, rs)
-        idx_arr = np.clip(
-            np.concatenate([np.asarray(idxs, np.int32), np.zeros(b - n_vals, np.int32)]),
-            0, n_vals - 1,
-        )
-        tables = table.build_tables()
-        dev = [jax.device_put(a) for a in (idx_arr, h2, s2, ry2, rs2)]
-        np.asarray(ed25519_table.verify_tabulated(tables, *dev, tile=tile))
-        t0 = time.perf_counter()
-        outs = [ed25519_table.verify_tabulated(tables, *dev, tile=tile) for _ in range(K)]
-        np.asarray(outs[-1])
-        steady_device_ms = (time.perf_counter() - t0) / K * 1000
-    else:
-        b = table.verifier._bucket(n_vals)
-        h2, s2, ry2, rs2 = bv._pad_scalar_rows(b, h, s, ry, rs)
-        idx_arr = np.clip(
-            np.concatenate([np.asarray(idxs, np.int32), np.zeros(b - n_vals, np.int32)]),
-            0, n_vals - 1,
-        )
-        # the fused dispatch ships packed 32 B/scalar h and s (expanded
-        # in-kernel) — device arrays here must match that wire format
-        dev = [
-            jax.device_put(a)
-            for a in (idx_arr, bv._pack_digits(h2), bv._pack_digits(s2), ry2, rs2)
-        ]
-        fn = table._fused()
-        np.asarray(fn(table.neg_a_rows, *dev))
-        t0 = time.perf_counter()
-        outs = [fn(table.neg_a_rows, *dev) for _ in range(K)]
-        np.asarray(outs[-1])
-        steady_device_ms = (time.perf_counter() - t0) / K * 1000
+    b = table.verifier._bucket(n_vals)
+    h2, s2, ry2, rs2 = bv._pad_scalar_rows(b, h, s, ry, rs)
+    idx_arr = np.clip(
+        np.concatenate([np.asarray(idxs, np.int32), np.zeros(b - n_vals, np.int32)]),
+        0, n_vals - 1,
+    )
+    # the fused dispatch ships packed 32 B/scalar h and s (expanded
+    # in-kernel) — device arrays here must match that wire format
+    dev = [
+        jax.device_put(a)
+        for a in (idx_arr, bv._pack_digits(h2), bv._pack_digits(s2), ry2, rs2)
+    ]
+    fn = table._fused()
+    np.asarray(fn(table.neg_a_rows, *dev))
+    t0 = time.perf_counter()
+    outs = [fn(table.neg_a_rows, *dev) for _ in range(K)]
+    np.asarray(outs[-1])
+    steady_device_ms = (time.perf_counter() - t0) / K * 1000
 
     steady_ms = max(steady_device_ms, host_prep_ms)
     sigs_per_sec = n_vals / (steady_ms / 1000)
@@ -174,7 +147,6 @@ def bench_primary(n_vals: int = 10_000):
         "host_prep_ms": host_prep_ms,
         "host_prep_fused_c": bool(hostprep.have_fast_prep()),
         "host_serial_sigs_per_sec": host_sigs_per_sec,
-        "tabulated_kernel": bool(table.tabulated),
         "table_build_ms": table_build_ms,
     }
 
@@ -1118,7 +1090,6 @@ def main() -> None:
         "host_prep_ms": round(primary["host_prep_ms"], 2),
         "host_prep_fused_c": primary["host_prep_fused_c"],
         "host_serial_sigs_per_sec": round(primary["host_serial_sigs_per_sec"], 1),
-        "tabulated_kernel": primary["tabulated_kernel"],
         "table_build_ms": round(primary["table_build_ms"], 1),
         "verify_shards": mesh.get("verify_shards"),
         "sharded_sigs_per_sec": mesh.get("sharded_sigs_per_sec", -1.0),

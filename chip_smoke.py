@@ -6,7 +6,7 @@ on the chip; it measures nothing a PR may claim as a speed.
 One process, three phases, through the entry points a deployment uses:
 
 1. node    — `cli init` a home, `default_new_node(cfg)` with the default
-             config (engine on, mesh/tabulated auto, kvstore, RPC), then
+             config (engine on, mesh auto, kvstore, RPC), then
              `broadcast_tx_commit` + `abci_query` read-back over real HTTP.
              Starting the node is what installs the engine's process-wide
              hooks, exactly as `cli node` does.
@@ -53,7 +53,7 @@ from typing import Awaitable, Callable, Dict, List, Optional, Sequence
 
 # verify.dispatch `path` values: through a device-resident PubkeyTable (the
 # indexed hook), the flat device path, and the host tier
-TABLE_PATHS = ("indexed", "chunked", "tabulated")
+TABLE_PATHS = ("indexed", "chunked")
 DEVICE_PATHS = TABLE_PATHS + ("device",)
 HOST_PATHS = ("host", "host-cold")
 
@@ -93,7 +93,7 @@ def hbm() -> str:
 def engine_failures(events: Sequence[dict], min_device_batch: int, warm: bool) -> List[str]:
     """What a slice of flight-recorder events holds that the smoke refuses:
     any engine event reporting ok=False (a failed bucket compile, table
-    build/rebuild, tabulated profile, RTT probe or mesh probe), and — once
+    build/rebuild, RTT probe or mesh probe), and — once
     `warm` — any host-tier dispatch of a batch the device should take."""
     problems = []
     for ev in events:
@@ -546,7 +546,7 @@ class CompileClock:
 
 
 # the report's names for a dispatch event's `kernel`
-KERNEL_NAMES = {"ladder": "pallas-ladder", "straus": "xla-straus", "tabulated": "pallas-tabulated"}
+KERNEL_NAMES = {"ladder": "pallas-ladder", "straus": "xla-straus"}
 
 
 async def run(seed: int, n_validators: int, n_votes: int, n_txs: int,
@@ -569,7 +569,6 @@ async def run(seed: int, n_validators: int, n_votes: int, n_txs: int,
         await settle(node, watch, deadline_s)
         for phase in (commit, votes):
             phase["kernel"] = KERNEL_NAMES[phase["kernel"]]
-        profiles = [e for e in watch.events if e["kind"] == "verify.tabulated_profile"]
         engine = [e for e in watch.events if e["kind"] == "verify.engine"]
         return {
             "host_tier": backend.active_tier(),
@@ -580,7 +579,6 @@ async def run(seed: int, n_validators: int, n_votes: int, n_txs: int,
             "single_shot": (
                 "chunked" if (verifier.rtt_probe or {}).get("chunked_selected") else "monolithic"
             ),
-            "tabulated_profile": fields(profiles[-1]) if profiles else None,
             "bucket_compiles": {
                 str(e["bucket"]): e["ms"] for e in watch.events
                 if e["kind"] == "verify.bucket_compile"

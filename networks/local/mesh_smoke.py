@@ -174,7 +174,7 @@ async def live_node_phase(args, tmp: str) -> dict:
     sharded = [
         e for e in dispatches
         if e.get("shards") == args.devices
-        and e.get("path") in ("device", "indexed", "chunked", "tabulated")
+        and e.get("path") in ("device", "indexed", "chunked")
     ]
     assert dispatches, "live node recorded no verify.dispatch events"
     assert sharded, (

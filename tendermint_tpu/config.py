@@ -321,9 +321,6 @@ class TPUConfig:
     # donated chunks may be in flight ahead of the device.
     chunk_size: int = 0
     chunk_depth: int = 2
-    # Tabulated zero-doubling kernel: "auto" profiles break-even once per
-    # process and engages only where it wins; "on"/"off" force it.
-    tabulated: str = "auto"
     # Route BLS multi-point aggregation (Σpk / Σsig of aggregate commits)
     # through the batched JAX tier (crypto/bls/jax_tier).  OFF by default:
     # on CPU-only hosts the pure-python fold wins below committee scale
@@ -648,10 +645,6 @@ class Config:
             raise ValueError("tpu.chunk_size can't be negative")
         if self.tpu.chunk_depth < 1:
             raise ValueError("tpu.chunk_depth must be >= 1")
-        if self.tpu.tabulated not in ("auto", "on", "off"):
-            raise ValueError(
-                f"unknown tpu.tabulated {self.tpu.tabulated!r} (want auto|on|off)"
-            )
         if self.storage.integrity_scan_limit < 0:
             raise ValueError("storage.integrity_scan_limit can't be negative")
         if self.storage.min_free_bytes < 0:

@@ -56,8 +56,8 @@ def set_verifier(fn: Optional[BatchVerifyFn]) -> None:
 
 # Indexed commit verification: callers that know (validator-set key, row
 # indices) — verify_commit and friends — can route through a per-valset
-# device table (HBM pubkey rows / precomputed window tables) instead of
-# shipping pubkeys every call.  fn(set_key, pubkeys, idxs, msgs, sigs)
+# device table (HBM pubkey rows) instead of shipping pubkeys every call.
+# fn(set_key, pubkeys, idxs, msgs, sigs)
 # returns list[bool], or None to decline (engine cold / set too large),
 # in which case the caller falls back to the flat batch verifier.
 IndexedVerifyFn = Callable[

@@ -220,35 +220,6 @@ def prepare_batch(
 _PALLAS_TILE = 512  # best-measured batch tile (sublane 20 x lane 512 blocks)
 _CHUNK = 2048  # double-buffer chunk for large single-shot indexed batches
 
-# One break-even profile per process (keyed by jax backend): does the
-# tabulated zero-doubling kernel beat the ladder at commit shapes?  See
-# PubkeyTable._auto_tabulated.
-_tabulated_verdict: Dict[str, bool] = {}
-_tabulated_lock = _threading.Lock()
-# Held for the whole profile, so table-build threads that arrive together
-# run ONE profile and share its verdict: two concurrent profiles at 10k
-# validators (1.5 GiB of window tables plus gather temporaries each) ran a
-# 16 GB v5e out of HBM.  Separate from _tabulated_lock, which the event
-# loop takes (invalidate_tabulated_profile) and must never wait on.
-_tabulated_profile_lock = _threading.Lock()
-
-
-def invalidate_tabulated_profile() -> None:
-    """Drop the cached tabulated-vs-ladder verdict.  The profile is timed
-    AT the live commit bucket shape, so a validator-set size change that
-    moves the bucket can flip the break-even — TableCache.rebuild calls
-    this when the set size changes and the next dispatch re-profiles."""
-    with _tabulated_lock:
-        _tabulated_verdict.clear()
-
-
-def _timed(fn) -> float:
-    import time as _time
-
-    t0 = _time.perf_counter()
-    fn()
-    return (_time.perf_counter() - t0) * 1000
-
 # Process-wide jit wrappers, shared across BatchVerifier/PubkeyTable
 # instances.  jax.jit memoizes traces per WRAPPER object: a per-instance
 # wrapper re-traces (and re-lowers) every bucket shape for every new
@@ -674,9 +645,7 @@ class BatchVerifier:
             host_prep_ms=0.0, device_ms=0.0,
         )
 
-    def _device_span(
-        self, n: int, bucket: int, path: str, shards: int, kernel: Optional[str] = None
-    ) -> "tracing.Span":
+    def _device_span(self, n: int, bucket: int, path: str, shards: int) -> "tracing.Span":
         """A device path's span: besides _dispatch_span's fields, how many
         devices THIS dispatch is split over (`shards`: the mesh, or 1 with
         `device`, the one it was routed to), which inner verify the jit wraps
@@ -686,7 +655,7 @@ class BatchVerifier:
         span = self._dispatch_span(n, bucket, path)
         span.set(
             shards=shards,
-            kernel=kernel or ("ladder" if self._use_pallas() else "straus"),
+            kernel="ladder" if self._use_pallas() else "straus",
             shard_n=self.shard_fill(n, bucket, shards),
         )
         if shards == 1 and self.mesh is not None:
@@ -770,32 +739,12 @@ class BatchVerifier:
 class PubkeyTable:
     """HBM-resident decompressed validator pubkey table, keyed by validator
     index — commits verify by gathering rows on-device (the BASELINE.json
-    north star).  Rebuilt only on validator-set changes.
-
-    `tabulated=True` additionally precomputes per-validator window tables
-    (ops/ed25519_table.py: table[v, w, d] = d·16^w·(−A_v)) so steady-state
-    commit verification needs ZERO point doublings — 128 gathered adds per
-    signature instead of the 384-op Straus ladder.
-
-    MEASURED: on v5e the gather is the bottleneck, not the VPU — 128
-    random 160 B table rows per signature make the tabulated dispatch
-    79 ms against 23 ms for the VMEM-resident ladder at a 10k committee,
-    and it loses at 256, 1024 and 4096 validators too (PERF.md, PR 21).
-    The zero-doubling math only pays off if the gather can be made
-    sequential.  `tabulated=None` (the default) is therefore AUTO: a
-    one-time per-process break-even profile (_auto_tabulated) times both
-    kernels at the live bucket shape and engages the tables only where
-    they actually win — on v5e the verdict stays off; a future chip with a
-    faster gather engages with no config change."""
-
-    # ~2.7 GB of HBM tables, twice that while the build joins its slices
-    TABULATED_MAX_VALIDATORS = 16384
+    north star).  Rebuilt only on validator-set changes."""
 
     def __init__(
         self,
         pubkeys: Sequence[bytes],
         verifier: Optional[BatchVerifier] = None,
-        tabulated: Optional[bool] = None,
     ):
         import jax.numpy as jnp
 
@@ -826,13 +775,6 @@ class PubkeyTable:
             self._rows_one = self.neg_a_rows.addressable_shards[0].data
         else:
             self.neg_a_rows = self._rows_one = jnp.asarray(rows)  # device-resident
-        if n > self.TABULATED_MAX_VALIDATORS:
-            tabulated = False
-        # None = auto: resolved at the first real dispatch by a one-time
-        # per-process break-even profile (_auto_tabulated) — engages the
-        # zero-doubling tabulated kernel only where it measures faster than
-        # the ladder.  True/False still force it either way.
-        self.tabulated = tabulated
         # Double-buffered chunking overlaps host prep with device compute
         # (saves ~prep time), but each extra dispatch pays the host<->device
         # round trip, which can exceed the saving.  None = auto: decided by
@@ -840,123 +782,6 @@ class PubkeyTable:
         # RTT < one chunk of host prep).  True/False still force it either
         # way.
         self.chunked_single_shot: Optional[bool] = None
-        self._window_tables = None
-        self._interpret = False  # CPU-interpret pallas (tests only)
-
-    def build_tables(self):
-        """One-time per validator set: device-built window tables
-        (~seconds, amortized over every commit until the set changes)."""
-        if self._window_tables is None:
-            from ..ops import ed25519_table
-
-            self._window_tables = ed25519_table.build_window_tables(self.neg_a_rows)
-            self._window_tables.block_until_ready()
-        return self._window_tables
-
-    def _tabulated_active(self, n: int) -> bool:
-        """Resolve the tabulated knob for a real dispatch of n signatures.
-        Explicit True/False pass through; None (auto) profiles once per
-        process and engages only when the break-even holds."""
-        if self.tabulated is None:
-            self.tabulated = self._auto_tabulated(n)
-        return self.tabulated
-
-    def _auto_tabulated(self, n: int) -> bool:
-        """Auto-engage rule: only where the Pallas tabulated kernel can run
-        at all (TPU backend, single device — the tabulated kernel is not
-        sharded, so under a mesh the sharded ladder owns the path and no
-        profile runs), and only when a one-shot timed comparison at this
-        commit's bucket shape says the zero-doubling gather beats the
-        VMEM-resident ladder.  The table build is amortized against the
-        warm validator set; the verdict against the whole process (cached
-        per backend — it is a property of the chip, not the table)."""
-        if self.verifier.mesh is not None or not self.verifier._use_pallas():
-            return False
-        import jax
-
-        backend = jax.default_backend()
-        with _tabulated_profile_lock:
-            with _tabulated_lock:
-                if backend in _tabulated_verdict:
-                    return _tabulated_verdict[backend]
-            verdict = self._profile_tabulated(n)
-            with _tabulated_lock:
-                return _tabulated_verdict.setdefault(backend, verdict)
-
-    def _profile_tabulated(self, n: int) -> bool:
-        """Time one tabulated dispatch vs one ladder dispatch at this
-        batch's bucket shapes (zero-filled inputs — the kernels are data-
-        oblivious).  Compiles are excluded; min-of-3 each.  The window
-        tables are kept only when they win.  A failure (a kernel the
-        compiler refuses, OOM building tables) keeps the ladder and is
-        reported: an error log line and a `verify.tabulated_profile` event
-        with ok=False and the error text."""
-        import time as _time
-
-        pk_count = max(len(self.pubkeys), 1)
-        try:
-            from ..ops import ed25519_table
-
-            tile = min(_PALLAS_TILE, 256)
-            b = max(((n + tile - 1) // tile) * tile, tile)
-            idx = np.zeros(b, dtype=np.int32)
-            h = np.zeros((b, 64), dtype=np.uint8)
-            s = np.zeros((b, 64), dtype=np.uint8)
-            ry = np.zeros((b, _N_LIMBS), dtype=np.int16)
-            rs = np.zeros(b, dtype=np.uint8)
-            t0 = _time.perf_counter()
-            tables = self.build_tables()
-            build_ms = (_time.perf_counter() - t0) * 1000
-
-            def run_tab():
-                np.asarray(
-                    ed25519_table.verify_tabulated(
-                        tables, idx, h, s, ry, rs,
-                        tile=tile, interpret=self._interpret,
-                    )
-                )
-
-            bb = self.verifier._bucket(b)
-            hb, sb, ryb, rsb = _pad_scalar_rows(bb, h, s, ry, rs)
-            hp, sp = _pack_digits(hb), _pack_digits(sb)
-            idx_b = np.zeros(bb, dtype=np.int32)
-            fn = self._fused()
-
-            def run_ladder():
-                np.asarray(fn(self.neg_a_rows, idx_b, hp, sp, ryb, rsb))
-
-            run_tab()
-            run_ladder()  # compiles land outside the timed runs
-            tab_ms = min(_timed(run_tab) for _ in range(3))
-            ladder_ms = min(_timed(run_ladder) for _ in range(3))
-            win = tab_ms < ladder_ms
-            if not win:
-                self._window_tables = None  # free the HBM the loser held
-            self.verifier.recorder.record(
-                "verify.tabulated_profile",
-                engaged=win,
-                ok=True,
-                tab_ms=round(tab_ms, 3),
-                ladder_ms=round(ladder_ms, 3),
-                table_build_ms=round(build_ms, 3),
-                bucket=b,
-                validators=pk_count,
-            )
-            return win
-        except Exception as exc:  # first-dispatch path: the ladder still serves
-            self._window_tables = None
-            logger.exception(
-                "verify engine: tabulated profile failed at %d validators; "
-                "the ladder kernel stays selected", pk_count,
-            )
-            self.verifier.recorder.record(
-                "verify.tabulated_profile",
-                engaged=False,
-                ok=False,
-                error=repr(exc),
-                validators=pk_count,
-            )
-            return False
 
     def __len__(self) -> int:
         return len(self.pubkeys)
@@ -993,25 +818,20 @@ class PubkeyTable:
                 msgs,
                 sigs,
             )
-        # the one-time decisions (a profile, a probe: seconds) come before
-        # the span, which times a dispatch and nothing else
-        tab = self._tabulated_active(n)
+        # the one-time decision (the RTT probe: seconds) comes before the
+        # span, which times a dispatch and nothing else
         cs = self.verifier.effective_chunk()
-        use_chunked = self.chunked_single_shot
-        chunk_eligible = not tab and n >= 2 * cs
-        if use_chunked is None and chunk_eligible:
-            use_chunked = self.verifier.chunked_auto()
-        chunked = bool(use_chunked and chunk_eligible)
+        chunked = n >= 2 * cs and bool(
+            self.verifier.chunked_auto()
+            if self.chunked_single_shot is None
+            else self.chunked_single_shot
+        )
         if chunked:
             path, b = "chunked", cs
-        elif tab:
-            tile = min(_PALLAS_TILE, 256)
-            path, b = "tabulated", ((n + tile - 1) // tile) * tile
         else:
             path, b = "indexed", self.verifier._bucket(n)
-        # a chunk gives every chip whole tiles; the tabulated kernel is unsharded
-        shards = 1 if tab else self.verifier._shards_for(b)
-        span = self.verifier._device_span(n, b, path, shards, "tabulated" if tab else None)
+        shards = self.verifier._shards_for(b)  # a chunk gives every chip whole tiles
+        span = self.verifier._device_span(n, b, path, shards)
 
         idx_arr = np.asarray(idxs, dtype=np.int32)
         # Host prep for everything except pubkey limbs (gathered on device);
@@ -1083,24 +903,14 @@ class PubkeyTable:
         if b > n:
             idx_arr = np.concatenate([idx_arr, np.zeros(b - n, dtype=np.int32)])
         idx_arr = np.clip(idx_arr, 0, pk_count - 1)
-        if tab:
-            from ..ops import ed25519_table
-
-            tables = self.build_tables()
-            span.lap("pack_ms")
-            dev_ok = ed25519_table.verify_tabulated(
-                tables, idx_arr, h_digits, s_digits, r_y, r_sign,
-                tile=tile, interpret=self._interpret,
-            )
+        fused = self._fused(shards)
+        h_digits, s_digits = _pack_digits(h_digits), _pack_digits(s_digits)
+        span.lap("pack_ms")
+        args = (idx_arr, h_digits, s_digits, r_y, r_sign)
+        if shards > 1:
+            dev_ok = fused(self.neg_a_rows, *self.verifier._put(span, shards, args))
         else:
-            fused = self._fused(shards)
-            h_digits, s_digits = _pack_digits(h_digits), _pack_digits(s_digits)
-            span.lap("pack_ms")
-            args = (idx_arr, h_digits, s_digits, r_y, r_sign)
-            if shards > 1:
-                dev_ok = fused(self.neg_a_rows, *self.verifier._put(span, shards, args))
-            else:
-                dev_ok = fused(self._rows_one, *args)
+            dev_ok = fused(self._rows_one, *args)
         span.lap("launch_ms")
         out = list(np.logical_and(np.asarray(dev_ok)[:n], valid))
         span.lap("fetch_ms")
@@ -1112,25 +922,19 @@ class TableCache:
     """Per-validator-set device tables for indexed commit verification.
 
     verify_commit knows (validator-set hash, row indices); routing through
-    this cache lets the steady-state commit path gather pubkey rows (and,
-    tabulated, precomputed window tables) on-device instead of shipping
-    pubkeys every call.  Keyed by the set hash; small LRU — consensus
-    touches at most current + last validator sets, lite2 a few more.
+    this cache lets the steady-state commit path gather pubkey rows
+    on-device instead of shipping pubkeys every call.  Keyed by the set
+    hash; small LRU — consensus touches at most current + last validator
+    sets, lite2 a few more.
 
     Installed process-wide via `install()` (crypto.batch.set_indexed_verifier);
     returns None (declining, caller falls back to the flat batch) while the
     engine is cold or when a set exceeds the table budget.
     """
 
-    def __init__(
-        self,
-        verifier: Optional[BatchVerifier] = None,
-        max_sets: int = 4,
-        tabulated: Optional[bool] = None,
-    ):
+    def __init__(self, verifier: Optional[BatchVerifier] = None, max_sets: int = 4):
         self.verifier = verifier or BatchVerifier()
         self.max_sets = max_sets
-        self.tabulated = tabulated
         self._tables: "_collections.OrderedDict[bytes, PubkeyTable]" = (
             _collections.OrderedDict()
         )
@@ -1147,10 +951,7 @@ class TableCache:
         return self._publish(set_key, self._new_table(pubkeys))
 
     def _new_table(self, pubkeys: Sequence[bytes]) -> PubkeyTable:
-        tab = PubkeyTable(pubkeys, verifier=self.verifier, tabulated=self.tabulated)
-        if tab.tabulated:
-            tab.build_tables()
-        return tab
+        return PubkeyTable(pubkeys, verifier=self.verifier)
 
     def _publish(self, set_key: bytes, tab: PubkeyTable) -> PubkeyTable:
         with self._lock:
@@ -1261,22 +1062,18 @@ class TableCache:
         moment an update lands so the table for the INCOMING set is warm
         before its first commit arrives, instead of that commit paying
         the decline-while-building miss.  Also re-probes the warmup
-        bucket and, when the set size changed, invalidates the tabulated
-        break-even profile (both are shaped by the commit batch size).
+        bucket, which is shaped by the commit batch size.
 
         Returns True when a background build was kicked off; False when
         the set's table is already cached or building."""
         pk_copy = [bytes(pk) for pk in self._rows(pubkeys)]
         n = len(pk_copy)
         with self._lock:
-            known_sizes = {len(tab.pubkeys) for tab in self._tables.values()}
             if set_key in self._tables or set_key in self._building:
                 # table already live/underway; the bucket may still be stale
                 self.verifier.rewarm(n)
                 return False
             self._building.add(set_key)
-        if known_sizes and n not in known_sizes:
-            invalidate_tabulated_profile()
         self.verifier.rewarm(n)
         self.verifier.metrics.table_rebuilds.inc()
         # warm at the whole-commit shape (one row per validator — what

@@ -38,8 +38,8 @@ Event kinds currently emitted:
                                                fetch_ms (blocked in np.asarray)
                                                split device_ms on device paths.
                                                Device paths also carry kernel
-                                               (ladder | straus | tabulated: the
-                                               inner verify), shard_n (useful
+                                               (ladder | straus: the inner
+                                               verify), shard_n (useful
                                                rows per shard; shards is how
                                                many devices THIS dispatch was
                                                split over), device (the one a

@@ -312,12 +312,8 @@ class Node(Service):
                 chunk_depth=cfg.tpu.chunk_depth,
             ).install()
             # steady-state commit path: per-valset device tables (HBM rows,
-            # replicated across the mesh; tabulated zero-doubling windows
-            # auto-profiled on a TPU backend)
-            self.table_cache = TableCache(
-                self.batch_verifier,
-                tabulated={"auto": None, "on": True, "off": False}[cfg.tpu.tabulated],
-            ).install()
+            # replicated across the mesh)
+            self.table_cache = TableCache(self.batch_verifier).install()
             self.async_verifier = AsyncBatchVerifier(
                 self.batch_verifier,
                 max_batch=cfg.tpu.max_batch,

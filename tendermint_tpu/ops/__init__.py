@@ -25,6 +25,13 @@ if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         ),
     )
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+# A Pallas kernel's body goes into the cache key with its source locations,
+# and a location holds the caller's frames as well: the ladder is traced once
+# a process, by whichever of the warm-up and table-build threads gets there
+# first, so with full tracebacks the same program had two keys (a warm cache
+# missed on the chip; PERF.md, PR 28).  One frame, the kernel's own line, is
+# the same from every caller.
+jax.config.update("jax_include_full_tracebacks_in_locations", False)
 
 from . import fe  # noqa: E402
 from . import ed25519 as ed25519_kernel  # noqa: E402

@@ -63,8 +63,8 @@ def mixed_batch_verify(
     `indexed=(set_key, set_pubkey_rows, row_idxs)` lets callers that know
     the validator-set identity and row indices (verify_commit*) route
     through the per-valset device table engine (crypto/batch.py indexed
-    hook: HBM pubkey rows / precomputed window tables) — the steady-state
-    path gathers pubkeys on-device instead of shipping them per call."""
+    hook: HBM pubkey rows) — the steady-state path gathers pubkeys
+    on-device instead of shipping them per call."""
     from ..crypto.keys import Ed25519PubKey
 
     n = len(msgs)

@@ -278,40 +278,8 @@ class TestPubkeyTable:
             batch_hook.set_verifier(None)
 
 
-class TestTabulated:
-    """ops/ed25519_table.py: per-validator window tables, zero-doubling
-    verification — differential against the same signatures the ladder
-    kernels verify (pallas interpret mode on CPU)."""
-
-    @pytest.mark.slow  # interpret-mode table verify: minutes on a small host
-    def test_tabulated_differential(self, verifier):
-        pubkeys, msgs, sigs = make_sigs(5)
-        table = PubkeyTable(pubkeys, verifier, tabulated=True)
-        table._interpret = True
-        idxs = [0, 3, 1, 4, 2, 0]
-        ms = [msgs[i] for i in idxs]
-        ss = [sigs[i] for i in idxs]
-        # corrupt one signature, point one index at the wrong key
-        ss[2] = ss[2][:5] + bytes([ss[2][5] ^ 1]) + ss[2][6:]
-        idxs[4] = 1
-        got = table.verify_indexed(idxs, ms, ss)
-        assert got == [True, True, False, True, False, True]
-
-    def test_chunked_table_build_equals_whole(self, verifier, monkeypatch):
-        """Committees above BUILD_CHUNK are built a slice at a time (the
-        build's temporaries are ~9x its output): same rows as one build,
-        identity padding behind them."""
-        from tendermint_tpu.ops import ed25519_table
-
-        pubkeys, _, _ = make_sigs(10)
-        rows = PubkeyTable(pubkeys, verifier, tabulated=False).neg_a_rows
-        whole = np.asarray(ed25519_table.build_window_tables(rows))
-        monkeypatch.setattr(ed25519_table, "BUILD_CHUNK", 4)
-        sliced = np.asarray(ed25519_table.build_window_tables(rows))
-        per_validator = ed25519_table.N_WINDOWS * ed25519_table.N_DIGITS
-        assert whole.shape[0] == 10 * per_validator
-        assert sliced.shape[0] == 12 * per_validator
-        np.testing.assert_array_equal(sliced[: whole.shape[0]], whole)
+class TestTableCacheCommits:
+    """ValidatorSet.verify_commit through the installed indexed hook."""
 
     def test_table_cache_routes_verify_commit(self, verifier):
         """verify_commit uses the installed indexed hook (device-resident
@@ -326,7 +294,7 @@ class TestTabulated:
         for pv in pvs:
             vs.add_vote(signed_vote(pv, vset, PRECOMMIT_TYPE, 5, 0, bid))
         commit = vs.make_commit()
-        cache = TableCache(verifier, tabulated=False)
+        cache = TableCache(verifier)
         calls = {"n": 0}
         orig = cache.verify_indexed
 
@@ -360,7 +328,7 @@ class TestTabulated:
         import dataclasses
 
         commit.signatures[0] = dataclasses.replace(commit.signatures[0], signature=bytes(64))
-        cache = TableCache(verifier, tabulated=False)
+        cache = TableCache(verifier)
         try:
             batch_hook.set_indexed_verifier(cache.verify_indexed)
             with pytest.raises(ValueError, match="wrong signature"):
@@ -625,7 +593,6 @@ class TestMeshConfigKnobs:
         ("mesh_devices", -1, "mesh_devices"),
         ("chunk_size", -8, "chunk_size"),
         ("chunk_depth", 0, "chunk_depth"),
-        ("tabulated", "maybe", "tabulated"),
     ])
     def test_bad_knob_rejected(self, field, bad, match):
         cfg = self._cfg()
@@ -638,7 +605,31 @@ class TestMeshConfigKnobs:
         cfg.validate_basic()
         assert cfg.tpu.mesh == "auto"
         assert cfg.tpu.chunk_depth == 2
-        assert cfg.tpu.tabulated == "auto"
+
+    def test_a_config_file_with_the_retired_tabulated_key_still_loads(self, tmp_path, caplog):
+        """`save_config` wrote `tabulated = "auto"` under [tpu] into every
+        config.toml until PR 28: such a file loads and validates, silently."""
+        import dataclasses
+        import warnings
+
+        from tendermint_tpu.config import load_config, save_config
+
+        path = str(tmp_path / "config.toml")
+        save_config(self._cfg(), path)
+        with open(path) as fh:
+            text = fh.read()
+        assert "tabulated" not in text
+        old = text.replace("chunk_depth = 2\n", 'chunk_depth = 2\ntabulated = "auto"\n')
+        assert old != text
+        with open(path, "w") as fh:
+            fh.write(old)
+        with warnings.catch_warnings(), caplog.at_level(0):
+            warnings.simplefilter("error")
+            cfg = load_config(path, home=str(tmp_path))
+            cfg.validate_basic()
+        assert not caplog.records
+        assert not hasattr(cfg.tpu, "tabulated")
+        assert cfg.tpu.chunk_depth == 2 and len(dataclasses.fields(cfg.tpu)) == 11
 
 
 # ---------------------------------------------------------------------------

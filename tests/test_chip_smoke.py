@@ -154,15 +154,21 @@ class TestFailuresAreReported:
         assert rec.events(kinds=["verify.dispatch"])[-1]["path"] == "host-cold"
         assert "Mosaic failed" in self._refused(rec)
 
-    def test_table_build_failure(self, monkeypatch, caplog):
+    @pytest.mark.parametrize("where", ["constructor", "warm_dispatch"])
+    def test_table_build_failure(self, where, monkeypatch, caplog):
+        """Whether the table's construction fails or the warm dispatch that
+        precedes its publication: reported, logged, the set not published."""
         verifier, rec = self._engine()
         verifier._warmup_mode = True  # node mode: tables build in the background
-        cache = TableCache(verifier, tabulated=False)
+        cache = TableCache(verifier)
 
-        def oom(pubkeys):
+        def oom(*args):
             raise RuntimeError("RESOURCE_EXHAUSTED: out of HBM")
 
-        monkeypatch.setattr(cache, "_new_table", oom)
+        if where == "constructor":
+            monkeypatch.setattr(cache, "_new_table", oom)
+        else:
+            monkeypatch.setattr(PubkeyTable, "verify_indexed", oom)
         pubkeys, msgs, sigs = _sigs(4)
         with caplog.at_level(logging.ERROR, logger=ENGINE_LOGGER):
             assert cache.verify_indexed(b"k" * 32, pubkeys, [0, 1, 2, 3], msgs, sigs) is None
@@ -178,7 +184,7 @@ class TestFailuresAreReported:
         next verify_commit compile inline on the consensus event loop."""
         verifier, rec = self._engine()
         verifier._warmup_mode = True
-        cache = TableCache(verifier, tabulated=False)
+        cache = TableCache(verifier)
         seen = []
         real = PubkeyTable.verify_indexed
 
@@ -192,23 +198,6 @@ class TestFailuresAreReported:
         assert cache.verify_indexed(b"s" * 32, pubkeys, [0, 1, 2, 3], msgs, sigs) is None
         assert _wait_event(rec, "verify.table_build")["ok"] is True
         assert seen == [False] and cache.has_table(b"s" * 32)
-
-    def test_tabulated_profile_failure(self, monkeypatch, caplog):
-        verifier, rec = self._engine()
-        pubkeys, _, _ = _sigs(4)
-        table = PubkeyTable(pubkeys, verifier)
-
-        def oom():
-            raise RuntimeError("RESOURCE_EXHAUSTED: window tables")
-
-        monkeypatch.setattr(table, "build_tables", oom)
-        with caplog.at_level(logging.ERROR, logger=ENGINE_LOGGER):
-            assert table._profile_tabulated(4) is False
-        ev = rec.events(kinds=["verify.tabulated_profile"])[-1]
-        assert ev["ok"] is False and ev["engaged"] is False
-        assert "RESOURCE_EXHAUSTED" in ev["error"]
-        assert any("tabulated profile failed" in r.message for r in caplog.records)
-        assert "RESOURCE_EXHAUSTED" in self._refused(rec)
 
     def test_rtt_probe_failure(self, monkeypatch, caplog):
         verifier, rec = self._engine()

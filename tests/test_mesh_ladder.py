@@ -80,7 +80,7 @@ def engines(signed):
     for m in (mesh, None):
         engine = bv.BatchVerifier(mesh=m, recorder=rec if m is not None else None)
         engine._pallas = True
-        tables.append(bv.PubkeyTable(signed[0], engine, tabulated=False))
+        tables.append(bv.PubkeyTable(signed[0], engine))
     yield tables[0], tables[1], rec
     patch.undo()
 
@@ -194,21 +194,21 @@ def test_buckets_give_every_shard_whole_tiles(monkeypatch):
     assert straus._shards_for(16) == 4
 
 
-def test_auto_tabulated_is_off_under_a_mesh_without_a_profile(engines, monkeypatch):
-    """The tabulated kernel is unsharded: under a mesh `tabulated = auto`
-    answers no, and builds no window table to find out."""
-    mesh_tab, solo_tab, rec = engines
-    table = bv.PubkeyTable(mesh_tab.pubkeys, mesh_tab.verifier)  # tabulated=None: auto
-
-    def profiled(n):
-        raise AssertionError("a tabulated profile ran under a mesh")
-
-    monkeypatch.setattr(table, "_profile_tabulated", profiled)
-    monkeypatch.setattr(table, "build_tables", profiled)
-    bv.invalidate_tabulated_profile()
-    assert table._auto_tabulated(64) is False
-    assert table._tabulated_active(64) is False
-    assert not [e for e in rec.events() if e["kind"] == "verify.tabulated_profile"]
+def test_a_fresh_one_chip_table_dispatches_the_ladder_and_nothing_else(engines, signed):
+    """On one chip a table's first `verify_indexed` is one ladder dispatch:
+    no other kernel is built, timed or reported before it.  (`engines` is
+    asked for its stand-in for the ladder, not for its tables.)"""
+    rec = FlightRecorder(size=64)
+    engine = bv.BatchVerifier(recorder=rec)
+    engine._pallas = True
+    table = bv.PubkeyTable(signed[0], engine)
+    idxs, ms, ss = _batch(signed, 20, tampered=(3, 11))
+    want = batch_hook.host_batch_verify([signed[0][i] for i in idxs], ms, ss)
+    assert [bool(ok) for ok in table.verify_indexed(idxs, ms, ss)] == [bool(ok) for ok in want]
+    events = [e for e in rec.events() if e["kind"].startswith("verify.")]
+    assert [e["kind"] for e in events] == ["verify.dispatch"]
+    assert events[0]["path"] == "indexed" and events[0]["kernel"] == "ladder"
+    assert events[0].get("ok", True) is True
 
 
 @pytest.mark.slow
@@ -219,7 +219,7 @@ def test_the_real_ladder_under_the_interpreter_on_the_mesh(signed, monkeypatch):
     monkeypatch.setattr(bv, "_PALLAS_TILE", TILE)
     engine = bv.BatchVerifier(mesh=_mesh4())
     engine._pallas, engine._interpret = True, True
-    table = bv.PubkeyTable(signed[0], engine, tabulated=False)
+    table = bv.PubkeyTable(signed[0], engine)
     idxs, ms, ss = _batch(signed, 30, tampered=(0, 7, 8, 15, 16, 23, 24, 29))
     want = batch_hook.host_batch_verify([signed[0][i] for i in idxs], ms, ss)
     assert [bool(ok) for ok in table.verify_indexed(idxs, ms, ss)] == [bool(ok) for ok in want]

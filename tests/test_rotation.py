@@ -129,7 +129,7 @@ class TestTableRebuild:
 
     def test_rebuild_fires_recorder_event_and_counter(self):
         verifier, rec, reg = self._engine()
-        cache = TableCache(verifier, tabulated=False)
+        cache = TableCache(verifier)
         vset, _ = rand_validator_set(5)
         key = vset.pubkeys_digest()
         rows = [v.pub_key.bytes() for v in vset.validators]
@@ -155,7 +155,7 @@ class TestTableRebuild:
         NEW set must verify through the rebuilt table (the engine's
         indexed hook), not the cold fallback."""
         verifier, rec, _ = self._engine()
-        cache = TableCache(verifier, tabulated=False)
+        cache = TableCache(verifier)
         vset, pvs = rand_validator_set(4)
         bid = make_block_id()
 

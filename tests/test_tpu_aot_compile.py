@@ -69,3 +69,45 @@ def test_the_sharded_ladder_compiles_for_four_chips_at_10k(topo, no_compile_cach
     assert "tpu_custom_call" in text
     for collective in ("all-gather", "all-reduce", "all-to-all", "collective-permute"):
         assert collective not in text, collective
+
+
+def test_the_ladders_lowering_does_not_depend_on_which_dispatch_traced_it_first(topo):
+    """The ladder is traced once a process, by whichever of the warm-up (the
+    flat dispatch) and the table build (the fused one) gets there first, and a
+    Pallas kernel's body is lowered with its source locations: both dispatches
+    must lower to the same module either way, or the same program has two
+    compile-cache keys and a warm cache misses (PR 28, on the chip)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from tendermint_tpu.crypto import batch_verifier as bv
+
+    engine = bv.BatchVerifier()
+    engine._pallas = True
+    inner, bucket = engine._inner(), 512
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arg(*shape_dtype):
+        return jax.ShapeDtypeStruct(*shape_dtype, sharding=one)
+
+    rows = (arg((bucket, 20), jnp.int16), arg((bucket,), jnp.uint8))
+
+    def flat():
+        digits = arg((bucket, 64), jnp.uint8)
+        return inner.func.lower(
+            arg((bucket, 4, 20), jnp.int32), digits, digits, *rows, **inner.keywords
+        ).as_text()
+
+    def fused():
+        packed = arg((bucket, 32), jnp.uint8)
+        return bv._shared_fused_jit(inner).lower(
+            arg((175, 4, 20), jnp.int32), arg((bucket,), jnp.int32), packed, packed, *rows
+        ).as_text()
+
+    jax.clear_caches()
+    flat_first = (flat(), fused())
+    jax.clear_caches()
+    fused_first = (fused(), flat())[::-1]
+    assert "tpu_custom_call" in flat_first[0]
+    assert flat_first == fused_first

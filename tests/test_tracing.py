@@ -614,7 +614,7 @@ def _signed(n):
     return [k.pub_key().bytes() for k in keys], msgs, [k.sign(m) for k, m in zip(keys, msgs)]
 
 
-DEVICE_PATHS = ("device", "indexed", "chunked", "tabulated")
+DEVICE_PATHS = ("device", "indexed", "chunked")
 
 
 class TestDispatchSpans:
@@ -625,8 +625,6 @@ class TestDispatchSpans:
 
     @pytest.mark.parametrize("path", ("host", "host-cold") + DEVICE_PATHS)
     def test_prep_and_device_tile_the_calls_wall_time(self, path, monkeypatch):
-        import numpy as np
-
         from tendermint_tpu.crypto import batch_verifier as bv
 
         rec, prep_hist, dev_hist = FlightRecorder(size=256), _Hist(), _Hist()
@@ -649,21 +647,11 @@ class TestDispatchSpans:
         if path == "host-cold":
             engine._warmup_mode = True
             engine._compiling_buckets.add(engine._bucket(n))  # as if its compile were running
-        elif path in ("indexed", "chunked", "tabulated"):
+        elif path in ("indexed", "chunked"):
             if path == "chunked":
                 monkeypatch.setattr(bv, "_CHUNK", 32)
-            table = bv.PubkeyTable(pubkeys, engine, tabulated=path == "tabulated")
+            table = bv.PubkeyTable(pubkeys, engine)
             table.chunked_single_shot = path == "chunked"
-            if path == "tabulated":
-                # the span's accounting is under test, not the kernel (whose
-                # interpreter takes minutes: tests/test_batch_verifier.py, slow)
-                from tendermint_tpu.ops import ed25519_table
-
-                monkeypatch.setattr(table, "build_tables", lambda: None)
-                monkeypatch.setattr(
-                    ed25519_table, "verify_tabulated",
-                    lambda tables, idx, *rows, **kw: np.ones(len(idx), dtype=bool),
-                )
         call()  # a compile, a library's first load: outside the timed call
         seq = rec.snapshot()["next_seq"]
         t0 = time.monotonic_ns()
@@ -676,7 +664,7 @@ class TestDispatchSpans:
         # (and, on the table paths, the row list built from the indices)
         tiled = ev["host_prep_ms"] + ev["device_ms"] + ev.get("rows_ms", 0.0)
         assert tiled == pytest.approx(ev["dur_ns"] / 1e6, abs=0.25)
-        assert ("rows_ms" in ev) == (path in ("indexed", "chunked", "tabulated"))
+        assert ("rows_ms" in ev) == (path in ("indexed", "chunked"))
         # from outside, the call is that span and a few lines around it
         assert wall_ms - 1.0 <= ev["dur_ns"] / 1e6 <= wall_ms
         if path in DEVICE_PATHS:
@@ -684,10 +672,10 @@ class TestDispatchSpans:
             assert ev["pack_ms"] > 0 and ev["launch_ms"] > 0 and ev["fetch_ms"] > 0
             assert ev["pack_ms"] + ev["launch_ms"] + ev["fetch_ms"] == pytest.approx(
                 ev["device_ms"], abs=0.01)
-            assert ev["bucket"] == {"chunked": 32, "tabulated": 256}.get(path, engine._bucket(n))
+            assert ev["bucket"] == (32 if path == "chunked" else engine._bucket(n))
             # one device, no mesh: all the useful rows in its one shard, no device named
             assert ev["shard_n"] == [n] and "device" not in ev
-            assert ev["kernel"] == ("tabulated" if path == "tabulated" else "straus")
+            assert ev["kernel"] == "straus"
             assert ("put_ms" in ev) == (path == "chunked")  # only the chunks are put by hand
             # one reading, two sinks: the histograms saw the event's numbers
             assert prep_hist.seen[-1] * 1e3 == pytest.approx(ev["host_prep_ms"], abs=0.001)
