@@ -11,12 +11,12 @@ import hashlib
 from dataclasses import dataclass, field
 from typing import List, Optional
 
-_LEAF_PREFIX = b"\x00"
+LEAF_PREFIX = b"\x00"
 _INNER_PREFIX = b"\x01"
 
 
 def _leaf_hash(data: bytes) -> bytes:
-    return hashlib.sha256(_LEAF_PREFIX + data).digest()
+    return hashlib.sha256(LEAF_PREFIX + data).digest()
 
 
 def _inner_hash(left: bytes, right: bytes) -> bytes:
@@ -31,16 +31,31 @@ def _split_point(n: int) -> int:
     return k
 
 
+def hash_from_leaf_hashes(leaves: List[bytes]) -> bytes:
+    """Merkle root over leaf hashes already taken (SHA256(LEAF_PREFIX || item)
+    for each item, in order); no leaves hash to the empty-input SHA256 like
+    the reference's emptyHash (crypto/merkle/simple_tree.go:15).
+
+    hash_from_byte_slices' tree too: simple_tree.go's, the left half of a
+    range the largest power of two short of it, folded over (lo, hi) on the
+    one list with no slice copied on the way down."""
+    sha256 = hashlib.sha256
+
+    def fold(lo: int, hi: int) -> bytes:
+        n = hi - lo
+        if n == 1:
+            return leaves[lo]
+        if n == 2:
+            return sha256(_INNER_PREFIX + leaves[lo] + leaves[lo + 1]).digest()
+        k = lo + (1 << ((n - 1).bit_length() - 1))  # lo + _split_point(n)
+        return sha256(_INNER_PREFIX + fold(lo, k) + fold(k, hi)).digest()
+
+    return fold(0, len(leaves)) if leaves else sha256(b"").digest()
+
+
 def hash_from_byte_slices(items: List[bytes]) -> bytes:
-    """Merkle root; empty list hashes to the empty-input SHA256 like the
-    reference's emptyHash (crypto/merkle/simple_tree.go:15)."""
-    n = len(items)
-    if n == 0:
-        return hashlib.sha256(b"").digest()
-    if n == 1:
-        return _leaf_hash(items[0])
-    k = _split_point(n)
-    return _inner_hash(hash_from_byte_slices(items[:k]), hash_from_byte_slices(items[k:]))
+    """Merkle root of the items (simple_tree.go:9 SimpleHashFromByteSlices)."""
+    return hash_from_leaf_hashes([_leaf_hash(item) for item in items])
 
 
 @dataclass

@@ -62,11 +62,15 @@ Event kinds currently emitted:
                       validate_ms, abci_req_ms (BeginBlock's request built),
                       deliver_ms (BeginBlock's call to Commit's return),
                       mempool_ms, save_state_ms, events_ms; inside
-                      validate_ms, from validate_block, set_hash_ms (the
+                      validate_ms, from validate_block, basic_ms (the whole
+                      of Block.validate_basic) and commit_hashes (whether
+                      the LastCommit's Merkle root was built in it, 0 or 1:
+                      a Commit keeps its root), set_hash_ms (the
                       header's two validator-set hashes held against the
                       state's sets) and set_hashes (how many of the two
                       Merkle roots were built for it, a count: 0 on a set
-                      that has not changed since its root was taken);
+                      that has not changed since its root was taken),
+                      median_ms (the LastCommit's weighted median time);
                       carried with the block bytes, decode_ms, download_ms
                       (request to receipt),
                       queued_ms (receipt to peek_two), peer; wait_ms (since the
@@ -687,8 +691,8 @@ def format_budget(budget: Optional[dict]) -> str:
 
 #: The rows of the replay budget, outermost first: what tiles a block's
 #: interval (the wait since the block before, then the in-span stages),
-#: apply_block's stages inside apply_ms, the validator sets' roots inside
-#: validate_ms (set_hashes is a count of roots built, not milliseconds), the
+#: apply_block's stages inside apply_ms, validate_block's inside validate_ms
+#: (commit_hashes and set_hashes are counts of roots built, not milliseconds), the
 #: two commit verifications inside verify_ms and validate_ms, the engine's
 #: calls inside those, and what the receive path measured before the block
 #: was queued.
@@ -696,7 +700,7 @@ REPLAY_ROWS = (
     ("fastsync.block", ("wait_ms", "parts_ms", "verify_ms", "store_ms", "apply_ms")),
     ("fastsync.block", ("validate_ms", "abci_req_ms", "deliver_ms", "mempool_ms",
                         "save_state_ms", "events_ms")),
-    ("fastsync.block", ("set_hash_ms", "set_hashes")),
+    ("fastsync.block", ("basic_ms", "commit_hashes", "set_hash_ms", "set_hashes", "median_ms")),
     ("verify.commit", ("sign_bytes_ms", "engine_ms", "tally_ms")),
     ("verify.dispatch", ("rows_ms", "host_prep_ms", "pack_ms", "launch_ms", "put_ms",
                          "fetch_ms")),

@@ -22,10 +22,17 @@ class InvalidBlockError(Exception):
 
 
 def validate_block(state: State, block: Block, state_store=None, evidence_pool=None) -> None:
+    # The block's own checks are a stage inside the caller's validate_ms, as
+    # the set roots and the median are below.  A Commit keeps its Merkle root,
+    # so `commit_hashes` says whether the LastCommit's was built here (1) or
+    # the object had been hashed before (0).
+    t0 = time.monotonic_ns()
+    built = block.last_commit is not None and block.last_commit._hash is None
     try:
         block.validate_basic()
     except ValueError as e:
         raise InvalidBlockError(str(e)) from e
+    tracing.annotate(basic_ms=(time.monotonic_ns() - t0) / 1e6, commit_hashes=int(built))
 
     h = block.header
     if h.version_block != state.version_block:
@@ -87,7 +94,9 @@ def validate_block(state: State, block: Block, state_store=None, evidence_pool=N
                 f"block time {block.time_ns} not greater than last block time "
                 f"{state.last_block_time_ns}"
             )
+        t0 = time.monotonic_ns()
         expected = median_time(block.last_commit, state.last_validators)
+        tracing.annotate(median_ms=(time.monotonic_ns() - t0) / 1e6)
         if block.time_ns != expected:
             raise InvalidBlockError(
                 f"invalid block time: expected {expected}, got {block.time_ns}"

@@ -8,12 +8,14 @@ Times are integer unix nanoseconds throughout (deterministic, no tz).
 
 from __future__ import annotations
 
+import hashlib
 from dataclasses import dataclass, field, replace
 from typing import List, NamedTuple, Optional, Sequence
 
 from ..crypto import merkle, tmhash
 from ..encoding import codec
 from ..encoding.proto import field_bytes, field_time, field_varint, length_prefixed
+from ..encoding.varint import encode_uvarint
 from ..libs.bitarray import BitArray
 from . import canonical
 from .params import (
@@ -284,6 +286,54 @@ class CommitSig:
         return cls(d["block_id_flag"], d["validator_address"], d["timestamp_ns"], d["signature"])
 
 
+_BYTE = [bytes((i,)) for i in range(256)]
+
+
+def commit_sig_leaf_hashes(signatures: Sequence[CommitSig]) -> List[bytes]:
+    """Each slot's Merkle leaf hash, SHA256(LEAF_PREFIX || cs.encode()), in one
+    pass that does not go through the generic field encoders: constant tags,
+    one-byte lengths, the seconds' field encoded once per distinct second and
+    the nanos' varint inline.  CommitSig.encode() is the definition; a slot
+    that is not of the common shape (a flag outside 1..127, an address or a
+    signature of 128 bytes or more, a field of another type: a peer's block
+    gets here before validate_basic has seen it) is encoded by it."""
+    sha256 = hashlib.sha256
+    byte = _BYTE
+    leaf = merkle.LEAF_PREFIX + b"\x08"  # field 1, varint: the flag
+    seconds: dict = {}  # second -> field_varint(1, second)
+    out = []
+    for cs in signatures:
+        flag, addr, ns, sig = cs.block_id_flag, cs.validator_address, cs.timestamp_ns, cs.signature
+        if not (
+            flag.__class__ is int and ns.__class__ is int
+            and addr.__class__ is bytes and sig.__class__ is bytes
+            and 0 < flag < 128 and len(addr) < 128 and len(sig) < 128
+        ):
+            out.append(sha256(merkle.LEAF_PREFIX + cs.encode()).digest())
+            continue
+        secs, nanos = divmod(ns, 1_000_000_000)
+        ts = seconds.get(secs)
+        if ts is None:
+            ts = seconds[secs] = field_varint(1, secs)
+        # field 2 of the timestamp: 7 bits a byte, low end first
+        if nanos >= 1 << 28:
+            ts = (ts + b"\x10" + byte[nanos & 0x7F | 0x80] + byte[nanos >> 7 & 0x7F | 0x80]
+                  + byte[nanos >> 14 & 0x7F | 0x80] + byte[nanos >> 21 & 0x7F | 0x80]
+                  + byte[nanos >> 28])
+        elif nanos >= 1 << 21:
+            ts = (ts + b"\x10" + byte[nanos & 0x7F | 0x80] + byte[nanos >> 7 & 0x7F | 0x80]
+                  + byte[nanos >> 14 & 0x7F | 0x80] + byte[nanos >> 21])
+        elif nanos:
+            ts = ts + b"\x10" + encode_uvarint(nanos)
+        out.append(sha256(
+            leaf + byte[flag]
+            + (b"\x12" + byte[len(addr)] + addr if addr else b"")
+            + b"\x1a" + byte[len(ts)] + ts
+            + (b"\x22" + byte[len(sig)] + sig if sig else b"")
+        ).digest())
+    return out
+
+
 class VoteBatch(NamedTuple):
     """A commit's verification batch (Commit.vote_batch), position-aligned."""
 
@@ -442,7 +492,7 @@ class Commit:
 
     def hash(self) -> bytes:
         if self._hash is None:
-            self._hash = merkle.hash_from_byte_slices([cs.encode() for cs in self.signatures])
+            self._hash = merkle.hash_from_leaf_hashes(commit_sig_leaf_hashes(self.signatures))
         return self._hash
 
     def to_dict(self) -> dict:

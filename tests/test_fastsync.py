@@ -372,6 +372,59 @@ class TestSetHashStage:
         _, changed = state.validators.get_by_address(pvs[0].address())
         assert changed.voting_power == 15
 
+    async def test_a_block_from_the_wire_builds_its_commit_root_once(self):
+        """`basic_ms`, `commit_hashes` and `median_ms`, PR 29's stages of
+        `validate_ms`: a block decoded from a peer's bytes brings a Commit
+        that has no root yet, and `validate_basic` builds it (1); the object
+        keeps it, so validating the same block again builds none (0)."""
+        from tendermint_tpu.types import Block
+
+        pvs = sorted((MockPV() for _ in range(4)), key=lambda pv: pv.address())
+        made = await self._chain(pvs)
+        wire = [(bid, Block.deserialize(block.serialize())) for bid, block in made]
+        events, state, ex = await self._replay(pvs, wire)
+        assert [e["commit_hashes"] for e in events] == [0, 1, 1, 1, 1, 1]  # height 1 has none
+        assert "median_ms" not in events[0]  # nor a median
+        for ev in events:
+            assert 0 < ev["basic_ms"] <= ev["validate_ms"]
+            assert ev["basic_ms"] + ev["set_hash_ms"] + ev.get("median_ms", 0) <= ev["validate_ms"]
+        for ev in events[1:]:
+            assert ev["median_ms"] > 0
+        # the proposer's own objects were hashed by make_block: nothing left to build
+        events, _, _ = await self._replay(pvs, made)
+        assert [e["commit_hashes"] for e in events] == [0] * 6
+        # and a block validated twice (consensus: prevote, then finalize) pays once
+        block = Block.deserialize(made[-1][1].serialize())
+        before = await self._replay(pvs, wire[:-1])
+        rec = tracing.FlightRecorder(size=32)
+        for _ in range(2):
+            with rec.span("fastsync.block", id=block.height):
+                before[2].validate_block(before[1], block)
+        first, second = (e for e in rec.events() if e["kind"] == "fastsync.block")
+        assert (first["commit_hashes"], second["commit_hashes"]) == (1, 0)
+        # stages are fields: the only other events are the LastCommit's own
+        assert {e["kind"] for e in rec.events()} <= {
+            "fastsync.block", "verify.commit", "verify.dispatch", "verify.table"}
+        assert block.last_commit.hash() == made[-1][1].header.last_commit_hash
+
+    async def test_a_header_that_lies_about_the_last_commit_is_rejected(self):
+        from dataclasses import replace
+
+        from tendermint_tpu.state.validation import InvalidBlockError
+        from tendermint_tpu.types import Block, Commit
+
+        pvs = sorted((MockPV() for _ in range(4)), key=lambda pv: pv.address())
+        chain = await self._chain(pvs)
+        _, state, ex = await self._replay(pvs, chain[:3])
+        block_id, block = chain[3]
+        lc = block.last_commit
+        moved = replace(lc.signatures[2], timestamp_ns=lc.signatures[2].timestamp_ns + 1)
+        forged = Block(block.header, block.txs, block.evidence, Commit(
+            lc.height, lc.round, lc.block_id, lc.signatures[:2] + [moved] + lc.signatures[3:]))
+        with pytest.raises(InvalidBlockError, match=r"Header\.LastCommitHash$"):
+            await ex.apply_block(state, block_id, forged)
+        ex.validate_block(state, block)  # and the honest block still passes
+
     @pytest.mark.parametrize("field", ["validators_hash", "next_validators_hash"])
     async def test_a_header_that_lies_about_a_set_is_rejected_with_the_memo_warm(self, field):
         from dataclasses import replace

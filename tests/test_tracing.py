@@ -519,6 +519,31 @@ class TestReplayBudget:
         st = tracing.replay_budget(events)["stages"]
         assert "set_hashes" not in st and st["set_hash_ms"]["mean_ms"] == 0.002
 
+    def test_the_basic_and_median_stages_are_listed_and_printed(self):
+        """`basic_ms`, `commit_hashes` and `median_ms` (state/validation.py,
+        PR 29) are fields of `fastsync.block` like PR 27's two: in the rows
+        `trace --replay` prints, in validate_block's order, and in the
+        module's list of the span's fields."""
+        (inside_validate,) = [names for kind, names in tracing.REPLAY_ROWS if "set_hash_ms" in names]
+        assert inside_validate == ("basic_ms", "commit_hashes", "set_hash_ms", "set_hashes",
+                                   "median_ms")
+        listed = tracing.__doc__.split("fastsync.block    SPAN", 1)[1].split("gossip (", 1)[0]
+        assert all(name in listed for name in inside_validate)
+        events = self._events()
+        stages = tracing.replay_budget(events)["stages"]  # an older node's events
+        assert not {"basic_ms", "commit_hashes", "median_ms"} & set(stages)
+        blocks = [ev for ev in events if ev["kind"] == "fastsync.block"]
+        blocks[0].update(validate_ms=3.0, basic_ms=1.25, commit_hashes=1, median_ms=0.5)
+        blocks[1].update(validate_ms=1.0, basic_ms=0.25, commit_hashes=0, median_ms=0.25)
+        st = tracing.replay_budget(events)["stages"]
+        assert st["basic_ms"]["mean_ms"] == 0.75 and st["median_ms"]["mean_ms"] == 0.375
+        assert st["commit_hashes"]["mean_ms"] == 0.5  # a count a block, under the table's heading
+        order = list(st)
+        assert order.index("validate_ms") < order.index("basic_ms") < order.index("commit_hashes") \
+            < order.index("median_ms") < order.index("commit.sign_bytes_ms")
+        table = tracing.format_replay_budget(tracing.replay_budget(events))
+        assert "  basic_ms " in table and "  commit_hashes " in table and "  median_ms " in table
+
     def test_nothing_to_budget_without_a_block_span(self):
         assert tracing.replay_budget([{"kind": "verify.commit", "id": 3}]) is None
         assert "nothing to budget" in tracing.format_replay_budget(None)
@@ -797,6 +822,31 @@ class TestCommitSpans:
         assert 0 < second["set_hash_ms"] < first["set_hash_ms"] <= first["dur_ns"] / 1e6
         validate_block(state, block)  # outside any span: nothing to annotate, nothing raised
         assert len(rec.events()) == 2
+
+    def test_the_basic_stage_is_fields_and_no_event_and_height_1_has_no_commit(self):
+        """`validate_block` under an open span leaves `basic_ms` and
+        `commit_hashes` on that span's one event; height 1 has no LastCommit,
+        so no root is built and no median taken.  (A block with one:
+        tests/test_fastsync.py::TestSetHashStage.)"""
+        from tendermint_tpu.state import make_genesis_state
+        from tendermint_tpu.state.validation import validate_block
+        from tendermint_tpu.types import GenesisDoc, GenesisValidator, MockPV
+
+        pvs = [MockPV() for _ in range(7)]
+        state = make_genesis_state(GenesisDoc(
+            chain_id=self.CHAIN, genesis_time_ns=1_700_000_000_000_000_000,
+            validators=[GenesisValidator(pv.address(), pv.get_pub_key(), 10) for pv in pvs]))
+        block = state.make_block(1, [b"a=b"], None, [], state.validators.get_proposer().address)
+        rec = FlightRecorder(size=16)
+        kinds_before = set(tracing.__doc__.split())
+        with rec.span("fastsync.block", id=1):
+            validate_block(state, block)
+        (ev,) = rec.events()
+        assert ev["kind"] == "fastsync.block" and ev["commit_hashes"] == 0
+        assert 0 < ev["basic_ms"] <= ev["dur_ns"] / 1e6 and "median_ms" not in ev
+        assert set(tracing.__doc__.split()) == kinds_before
+        validate_block(state, block)  # outside any span: nothing to annotate, nothing raised
+        assert len(rec.events()) == 1
 
     def test_the_trusting_check_closes_the_same_span(self):
         rec = FlightRecorder(size=16)
