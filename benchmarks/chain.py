@@ -6,13 +6,17 @@ wire carries it, back to back) and `<dir>/meta.json` (offsets, block ids,
 what the reference needs).  The same seed gives the same bytes.
 
 The chain is real: every header links to the last, every commit is signed
-by the committee over that block's id, block time is the commit's weighted
-median.  It is built without a node: a static validator set makes every
-header field a function of the height, the transaction count and the last
-commit, so nothing is executed and nothing verified here — the node under
-test does both.  Signing runs in worker processes, a slice of the
-committee each; a height cannot start before the last one's signatures
-are in, so the workers run in step.
+by the set that holds at its height over that block's id, block time is the
+commit's weighted median.  It is built without a node: every header field
+is a function of the height, the transaction count, the last commit and
+the validator set, and the set is a function of the seed (static, or moved
+by the configuration's `validator_set_changes`: reference.validator_sets),
+so nothing is executed and nothing verified here — the node under test does
+both.  Where the set changes, the changes ride in the blocks as the
+kvstore's own `val:` transactions, after the traffic mix's, and the set they
+make signs two heights later.  Signing runs in worker processes, a slice of
+the key pool (committee and standby) each; a height cannot start before the
+last one's signatures are in, so the workers run in step.
 
 This process and its workers never import JAX.
 """
@@ -20,6 +24,7 @@ This process and its workers never import JAX.
 from __future__ import annotations
 
 import argparse
+import base64
 import hashlib
 import json
 import multiprocessing
@@ -69,20 +74,54 @@ def powers(config: dict) -> List[int]:
     raise ValueError(f"unknown power rule {rule['kind']!r}")
 
 
-def committee(seed: int, config: dict) -> Tuple[List[bytes], List[bytes], List[int]]:
-    """(secrets, public keys, powers) in validator-set order, which is by
-    address: sha256(pubkey)[:20]."""
+def public_key(secret: bytes) -> bytes:
     from cryptography.hazmat.primitives import serialization
     from cryptography.hazmat.primitives.asymmetric.ed25519 import Ed25519PrivateKey
 
+    return Ed25519PrivateKey.from_private_bytes(secret).public_key().public_bytes(
+        serialization.Encoding.Raw, serialization.PublicFormat.Raw
+    )
+
+
+def committee(seed: int, config: dict) -> Tuple[List[bytes], List[bytes], List[int]]:
+    """(secrets, public keys, powers) of the genesis set in validator-set
+    order, which is by address: sha256(pubkey)[:20]."""
     members = []
     for secret, power in zip(secrets(seed, config["validators"]), powers(config)):
-        pub = Ed25519PrivateKey.from_private_bytes(secret).public_key().public_bytes(
-            serialization.Encoding.Raw, serialization.PublicFormat.Raw
-        )
+        pub = public_key(secret)
         members.append((hashlib.sha256(pub).digest()[:20], secret, pub, power))
     members.sort()
     return [m[1] for m in members], [m[2] for m in members], [m[3] for m in members]
+
+
+def set_changes(config: dict) -> dict:
+    """The configuration's `validator_set_changes`; empty for a static set
+    (the key absent, or "none")."""
+    changes = config.get("validator_set_changes")
+    return changes if isinstance(changes, dict) else {}
+
+
+def standby(seed: int, config: dict) -> Tuple[List[bytes], List[bytes]]:
+    """(secrets, public keys) of the keys waiting to join, in the order they
+    will: the ranks after the committee's."""
+    n = config["validators"]
+    spare = set_changes(config).get("membership", {}).get("standby", 0)
+    waiting = secrets(seed, n + spare)[n:]
+    return waiting, [public_key(s) for s in waiting]
+
+
+def set_history(genesis, meta: dict) -> "reference.SetHistory":
+    """The sets by height of a generated chain, from its genesis set
+    [(pubkey, power)] and the updates its meta.json holds (none: static)."""
+    return reference.SetHistory(list(genesis), {
+        int(h): [(bytes.fromhex(pub), power) for pub, power in updates]
+        for h, updates in meta.get("set_updates", {}).items()
+    })
+
+
+def val_tx(pubkey: bytes, power: int) -> bytes:
+    """The kvstore's validator-update transaction (persistent_kvstore.go)."""
+    return b"val:" + base64.b64encode(pubkey) + b"!%d" % power
 
 
 def block_txs(seed: int, height: int, traffic: dict) -> List[bytes]:
@@ -168,37 +207,47 @@ def _sign_worker(conn, slice_secrets: List[bytes]) -> None:
         job = conn.recv()
         if job is None:
             return
-        head, tail, present, stamps = job
+        head, tail, who, stamps = job
         conn.send_bytes(b"".join(
-            keys[j].sign(head + stamps[8 * j: 8 * j + 8] + tail)
-            for j in range(len(keys)) if present[j]
+            keys[j].sign(head + stamps[8 * k: 8 * k + 8] + tail)
+            for k, j in enumerate(np.frombuffer(who, dtype="<u4"))
         ))
 
 
 class Signers:
-    """The committee's signing keys, spread over worker processes."""
+    """The signing keys of the pool (committee and standby), spread over
+    worker processes: a key stays with its worker wherever its validator
+    stands in the set."""
 
-    def __init__(self, set_secrets: List[bytes], workers: int):
+    def __init__(self, pool_secrets: List[bytes], workers: int):
         ctx = multiprocessing.get_context("spawn")
-        n = len(set_secrets)
+        n = len(pool_secrets)
         workers = max(1, min(workers, n // 16 or 1))
         bounds = [n * w // workers for w in range(workers + 1)]
         self.slices = list(zip(bounds[:-1], bounds[1:]))
+        self.worker_of = np.repeat(np.arange(workers), np.diff(bounds))
         self.procs, self.conns = [], []
         for a, b in self.slices:
             parent, child = ctx.Pipe()
-            proc = ctx.Process(target=_sign_worker, args=(child, set_secrets[a:b]), daemon=True)
+            proc = ctx.Process(target=_sign_worker, args=(child, pool_secrets[a:b]), daemon=True)
             proc.start()
             child.close()
             self.procs.append(proc)
             self.conns.append(parent)
 
-    def sign(self, head: bytes, tail: bytes, present: np.ndarray, stamps: np.ndarray) -> bytes:
-        """Signatures of the present validators, in set order, 64 bytes each."""
-        wire = stamps.astype("<i8").tobytes()
-        for (a, b), conn in zip(self.slices, self.conns):
-            conn.send((head, tail, present[a:b].tobytes(), wire[8 * a: 8 * b]))
-        return b"".join(conn.recv_bytes() for conn in self.conns)
+    def sign(self, head: bytes, tail: bytes, members: np.ndarray, present: np.ndarray,
+             stamps: np.ndarray) -> bytes:
+        """Signatures of the present validators, in set order, 64 bytes
+        each; `members` is each set position's place in the pool."""
+        who, when = members[present], stamps[present].astype("<i8")
+        worker = self.worker_of[who]
+        theirs = [np.flatnonzero(worker == w) for w in range(len(self.conns))]
+        for (a, _), conn, mine in zip(self.slices, self.conns, theirs):
+            conn.send((head, tail, (who[mine] - a).astype("<u4").tobytes(), when[mine].tobytes()))
+        sigs = np.empty((len(who), 64), dtype=np.uint8)
+        for conn, mine in zip(self.conns, theirs):
+            sigs[mine] = np.frombuffer(conn.recv_bytes(), dtype=np.uint8).reshape(-1, 64)
+        return sigs.tobytes()
 
     def close(self) -> None:
         for conn in self.conns:
@@ -238,45 +287,83 @@ def generate(config: dict, traffic: dict, seed: int, heights: int, out_dir: str,
     vset = ValidatorSet([Validator.new(Ed25519PubKey(p), w) for p, w in zip(pubs, pows)])
     if [v.pub_key.bytes() for v in vset.validators] != pubs:
         raise RuntimeError("the program orders the validator set otherwise than by address")
-    addresses = [v.address for v in vset.validators]
-    vals_hash = vset.hash()
+    spare_secrets, spare_pubs = standby(seed, config)
+    pool_pubs = pubs + spare_pubs
+    pool_place = {p: i for i, p in enumerate(pool_pubs)}
+    pool_addresses = [Ed25519PubKey(p).address() for p in pool_pubs]
+    history, updates = reference.validator_sets(
+        set_changes(config), seed, heights, list(zip(pubs, pows)), spare_pubs
+    )
     cons_hash = ConsensusParams().hash()
-    set_powers = np.asarray(pows, dtype=np.int64)
     if workers is None:
         workers = max(1, min(8, (os.cpu_count() or 2) - 2))
-    signers = Signers(set_secrets, workers)
+    signers = Signers(set_secrets + spare_secrets, workers)
+
+    # A set's hash is a Merkle root over one encoded validator per leaf; a
+    # change moves a few leaves, so the leaves are kept and the root folded
+    # here, over the program's own encoding of a validator.
+    leaf_of, root_of = {}, {}
+
+    def set_root(index: int) -> bytes:
+        if index not in root_of:
+            leaves = []
+            for member in zip(*history.sets[index]):
+                if member not in leaf_of:
+                    pub, power = member
+                    leaf_of[member] = hashlib.sha256(
+                        b"\x00" + Validator.new(Ed25519PubKey(pub), power).bytes()
+                    ).digest()
+                leaves.append(leaf_of[member])
+            root_of[index] = merkle_root(leaves)
+        return root_of[index]
+
+    if set_root(0) != vset.hash():
+        raise RuntimeError("the program hashes a validator set otherwise than this generator")
 
     # A commit's hash is a Merkle root over one encoded slot per validator.
     # Flag and address never change, so they are encoded once, by the
     # program's own encoder; time and signature are spliced in per height.
     absent_leaf = hashlib.sha256(b"\x00" + CommitSig.absent().encode()).digest()
-    probe = CommitSig.for_block(bytes(64), addresses[0], GENESIS_TIME_NS + 7).encode()
+    probe = CommitSig.for_block(bytes(64), pool_addresses[0], GENESIS_TIME_NS + 7).encode()
     # (an empty signature and a zero time leave just the 2-byte empty time field behind)
-    slot_heads = [b"\x00" + CommitSig.for_block(b"", a, 0).encode()[:-2] for a in addresses]
-    if slot_heads[0][1:] + _time_field(GENESIS_TIME_NS + 7) + b"\x22\x40" + bytes(64) != probe:
+    pool_heads = [b"\x00" + CommitSig.for_block(b"", a, 0).encode()[:-2] for a in pool_addresses]
+    if pool_heads[0][1:] + _time_field(GENESIS_TIME_NS + 7) + b"\x22\x40" + bytes(64) != probe:
         raise RuntimeError("the program encodes a commit's slots otherwise than this generator")
 
     os.makedirs(out_dir, exist_ok=True)
     offsets, hashes, parts = [0], [], []
     n_txs = traffic["txs_per_block"]
-    results = results_hash([ABCIResult(0, b"")] * n_txs)
+    results = {}  # by how many transactions the block before delivered, all accepted
     last_id, last_commit = BlockID(), Commit(0, 0, BlockID(), [])
     last_commit_hash = merkle_root([])
     time_ns = GENESIS_TIME_NS
+    held = -1  # which of history's sets the per-set values below belong to
     try:
         with open(os.path.join(out_dir, "blocks.bin"), "wb") as out:
             for h in range(1, heights + 1):
+                if history.index(h) != held:
+                    held = history.index(h)
+                    set_pubs, set_pows = history.sets[held]
+                    members = np.asarray([pool_place[p] for p in set_pubs])
+                    set_powers = np.asarray(set_pows, dtype=np.int64)
+                    addresses = [pool_addresses[i] for i in members]
+                    slot_heads = [pool_heads[i] for i in members]
+                delivered = n_txs + len(updates.get(h - 1, ()))
+                if delivered not in results:
+                    results[delivered] = results_hash([ABCIResult(0, b"")] * delivered)
                 header = Header(
                     version_block=BLOCK_PROTOCOL, version_app=0, chain_id=cid, height=h,
                     time_ns=time_ns, last_block_id=last_id,
-                    validators_hash=vals_hash, next_validators_hash=vals_hash,
+                    validators_hash=set_root(held),
+                    next_validators_hash=set_root(history.index(h + 1)),
                     consensus_hash=cons_hash,
                     app_hash=b"" if h == 1 else reference.kvstore_app_hash(n_txs * (h - 1), h - 1),
-                    last_results_hash=b"" if h == 1 else results,
+                    last_results_hash=b"" if h == 1 else results[delivered],
                     last_commit_hash=last_commit_hash,
                     proposer_address=addresses[h % len(addresses)],
                 )
-                block = Block(header, block_txs(seed, h, traffic), last_commit=last_commit)
+                txs = block_txs(seed, h, traffic) + [val_tx(*u) for u in updates.get(h, ())]
+                block = Block(header, txs, last_commit=last_commit)
                 block.fill_header()
                 raw = block.serialize()
                 psh = PartSet.from_data(raw, BLOCK_PART_SIZE_BYTES).header()
@@ -289,7 +376,7 @@ def generate(config: dict, traffic: dict, seed: int, heights: int, out_dir: str,
                 head, tail = reference.vote_sign_parts(
                     cid, h, 0, block.hash(), psh.total, psh.hash
                 )
-                sigs = signers.sign(head, tail, present, stamps)
+                sigs = signers.sign(head, tail, members, present, stamps)
                 slots, leaves, k = [], [], 0
                 sha256 = hashlib.sha256
                 for i, (here, ts) in enumerate(zip(present.tolist(), stamps.tolist())):
@@ -315,6 +402,11 @@ def generate(config: dict, traffic: dict, seed: int, heights: int, out_dir: str,
         "txs_per_block": n_txs, "offsets": offsets, "hashes": hashes, "parts": parts,
         "generate_s": round(time.monotonic() - t0, 3),
     }
+    if updates:  # what the reference needs of a set that changes
+        meta["set_updates"] = {
+            str(h): [[pub.hex(), power] for pub, power in ups] for h, ups in updates.items()
+        }
+        meta["membership_heights"] = history.membership_heights
     with open(os.path.join(out_dir, "meta.json"), "w") as f:
         json.dump(meta, f)
     return meta

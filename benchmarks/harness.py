@@ -14,6 +14,11 @@ and hold it and the engine's verdicts to the plain reference
 (benchmarks/reference.py).  Every number compared is exact, so its limit is
 0.
 
+A configuration whose `validator_set_changes` declares changes (see
+chain.set_changes) is held to the reference's set for each height, reports
+the validator sets it replayed, and may build a table where the chain
+brings a new membership (window_rules); one without is held as ever.
+
 Copied from chip_smoke.py (the yardstick may not import what later PRs may
 change): the rules on engine events, EngineWatch, until_device, the commit
 tampers and engine_first_bad.
@@ -58,14 +63,20 @@ REQUESTS_PER_PEER = 20
 
 # verify.dispatch `path` values: through a device-resident PubkeyTable (the
 # indexed hook), the flat device path, and the host tier
-TABLE_PATHS = ("indexed", "chunked", "tabulated")
+TABLE_PATHS = ("indexed", "chunked")
 DEVICE_PATHS = TABLE_PATHS + ("device",)
 HOST_PATHS = ("host", "host-cold")
-# engine events that mean a compile, a table build or a profile ran
-BUILD_EVENTS = (
-    "verify.bucket_compile", "verify.table_build", "verify.table_rebuild",
-    "verify.tabulated_profile",
-)
+# engine events that mean a table was built (on a miss, or ahead of one by
+# the node's watch on validator-set updates), and that a compile ran too
+TABLE_BUILD_EVENTS = ("verify.table_build", "verify.table_rebuild")
+BUILD_EVENTS = ("verify.bucket_compile",) + TABLE_BUILD_EVENTS
+# What a new membership may cost a window.  Table builds: the program has
+# two builders (the miss in TableCache.verify_indexed, the watch's rebuild),
+# and whether both fire is a reading, not a failed run.  Dispatches on the
+# flat path while the table builds: sound runs read at most 1.0 a membership
+# and a table never built two a block from there on (PERF.md section 2).
+BUILDS_PER_MEMBERSHIP = 2
+FLAT_DISPATCHES_PER_MEMBERSHIP = 10
 
 WARM_DEADLINE_S = 900.0  # the engine's allowance to reach the device, compiles included
 CHAIN_DEADLINE_S = 600.0
@@ -105,6 +116,11 @@ class Cell:
     end_to_end: List[dict]  # the BENCHMARK.json entries this cell reports
     per_layer: List[dict]
 
+    @property
+    def rotating(self) -> bool:
+        """Whether the configuration's validator set changes along the chain."""
+        return bool(chainlib.set_changes(self.config))
+
 
 def _load_json(path: str) -> dict:
     with open(path) as f:
@@ -124,6 +140,24 @@ def chain_heights(config: dict, traffic: dict) -> int:
     raise HarnessFailure(
         f"no chain length for {config['name']} under {traffic['name']}: give it in "
         "the traffic file's `heights` or the configuration's `traffic_heights`"
+    )
+
+
+def warm_in_blocks(config: dict, traffic: dict) -> int:
+    """Blocks applied per source dialed before the window may open: the
+    traffic file's number, or its entry for the configuration, else the
+    configuration file's entry for the traffic (as chain_heights)."""
+    by_config = traffic["warm_in_blocks"]
+    if not isinstance(by_config, dict):
+        return int(by_config)
+    if config["name"] in by_config:
+        return int(by_config[config["name"]])
+    by_traffic = config.get("traffic_warm_in_blocks", {})
+    if traffic["name"] in by_traffic:
+        return int(by_traffic[traffic["name"]])
+    raise HarnessFailure(
+        f"no warm-in for {config['name']} under {traffic['name']}: give it in the traffic "
+        "file's `warm_in_blocks` or the configuration's `traffic_warm_in_blocks`"
     )
 
 
@@ -173,19 +207,41 @@ def engine_failures(events: Sequence[dict], min_device_batch: int, warm: bool) -
     return problems
 
 
-def window_failures(events: Sequence[dict], min_device_batch: int) -> Dict[str, int]:
+def window_failures(
+    events: Sequence[dict], min_device_batch: int, membership_changes: Optional[int] = None,
+) -> Dict[str, int]:
     """The counts a measured window must keep at 0: failed engine events,
-    engine-sized batches on the host tier, and compiles, table builds or
-    profiles that ran inside it."""
-    return {
+    engine-sized batches on the host tier, and compiles or table builds that
+    ran inside it.  Where the chain's validator set changes
+    (`membership_changes` is how many new memberships the window met), the
+    table builds the chain asks for are not held against the run: what is
+    counted is the builds beyond BUILDS_PER_MEMBERSHIP for each, and every
+    compile; and the dispatches on the flat device path beyond
+    FLAT_DISPATCHES_PER_MEMBERSHIP for each (a membership whose table never
+    comes rides that path for good)."""
+    checks = {
         "engine_errors": sum(1 for ev in events if ev.get("ok") is False),
         "host_tier_dispatches": sum(
             1 for ev in events
             if ev["kind"] == "verify.dispatch" and ev["path"] in HOST_PATHS
             and ev["n"] >= min_device_batch
         ),
-        "builds_in_window": sum(1 for ev in events if ev["kind"] in BUILD_EVENTS),
     }
+    builds = sum(1 for ev in events if ev["kind"] in BUILD_EVENTS)
+    if membership_changes is None:
+        checks["builds_in_window"] = builds
+    else:
+        tables = sum(1 for ev in events if ev["kind"] in TABLE_BUILD_EVENTS)
+        checks["builds_beyond_membership_changes"] = builds - tables + max(
+            0, tables - BUILDS_PER_MEMBERSHIP * membership_changes
+        )
+        flat = sum(
+            1 for ev in events if ev["kind"] == "verify.dispatch" and ev["path"] == "device"
+        )
+        checks["flat_dispatches_beyond_membership_changes"] = max(
+            0, flat - FLAT_DISPATCHES_PER_MEMBERSHIP * membership_changes
+        )
+    return checks
 
 
 class EngineWatch:
@@ -451,6 +507,9 @@ class Window:
     buffered: List[int]  # blocks waiting in the replay queue, read as each block applied
     trace: Optional[object] = None  # benchmarks.trace.TraceSummary of a traced run
     device_kind: str = ""
+    # heights at which a new membership signs first and whose table build
+    # could land inside the window (cut_window); empty for a static set
+    membership_changes: List[int] = dataclasses.field(default_factory=list)
 
     @property
     def blocks(self) -> int:
@@ -468,23 +527,46 @@ def plant_fault(name: str, node, shards: int = 1) -> None:
     exception that breaks nothing: it stands in for the chip in a CPU test
     (serial host verification that reports itself as an indexed device
     dispatch over `shards` chips), so that the rest of a run can be driven
-    without one."""
+    without one.  Like the engine it keeps a table per membership: the first
+    commit of one it has not met is a miss that builds that membership's
+    table and is declined to the flat device path, which it stands in for
+    too."""
     from tendermint_tpu.crypto import batch as crypto_batch
 
-    def dispatched(n: int, path: str) -> None:
+    def dispatched(n: int, path: str, shards: int = shards) -> None:
         node.flight_recorder.record(
             "verify.dispatch", n=n, bucket=n, path=path, host_prep_ms=0.0, device_ms=0.0,
             shards=shards,
         )
 
+    def table_built(set_key: bytes, rows: int, ms: float) -> None:
+        node.flight_recorder.record(
+            "verify.table_build", set_key=set_key.hex()[:16], validators=rows, ms=ms, ok=True,
+            error=None, shards=shards,
+        )
+
     if name == "stub_device":
+        tables = set()
+
         def stub(set_key, pubkeys, idxs, msgs, sigs):
-            node.flight_recorder.record("verify.table", hit=True, n=len(sigs))
-            dispatched(len(sigs), "indexed")
             rows = pubkeys() if callable(pubkeys) else pubkeys  # verify_commit passes them lazily
+            hit = set_key in tables
+            node.flight_recorder.record("verify.table", hit=hit, n=len(sigs))
+            if not hit:  # build the table and decline this commit, as the engine does
+                t0 = time.perf_counter()
+                tables.add(bytes(set_key))
+                table_built(set_key, len(rows), (time.perf_counter() - t0) * 1e3)
+                return None
+            dispatched(len(sigs), "indexed", stub.shards)
             return crypto_batch.host_batch_verify([rows[i] for i in idxs], msgs, sigs)
 
+        def flat(pubkeys, msgs, sigs):  # where a declined commit goes: pubkeys shipped
+            dispatched(len(sigs), "device", stub.shards)
+            return crypto_batch.host_batch_verify(pubkeys, msgs, sigs)
+
+        stub.shards = shards  # `one_chip` takes the mesh away under a run
         crypto_batch.set_indexed_verifier(stub)
+        crypto_batch.set_verifier(flat)
     elif name == "host_tier":
         # the engine sends every batch to the serial host path
         def on_host(pubkeys, msgs, sigs):
@@ -515,10 +597,41 @@ def plant_fault(name: str, node, shards: int = 1) -> None:
         crypto_batch.set_verifier(flat_fault)
         if indexed is not None:
             crypto_batch.set_indexed_verifier(indexed_fault)
+    elif name == "build_always":
+        # the engine builds a table for every commit it is handed, as one
+        # keyed by the set's powers would where they change block by block
+        indexed = crypto_batch.get_indexed_verifier()
+
+        def rebuilding(set_key, pubkeys, idxs, msgs, sigs):
+            table_built(set_key, len(idxs), 0.0)
+            return indexed(set_key, pubkeys, idxs, msgs, sigs)
+
+        crypto_batch.set_indexed_verifier(rebuilding)
+    elif name == "table_never_built":
+        # the engine serves the membership it meets first from its table and
+        # declines every other without building one: a new membership's
+        # commits ride the flat path for good
+        indexed = crypto_batch.get_indexed_verifier()
+        served = []
+
+        def declining(set_key, pubkeys, idxs, msgs, sigs):
+            if not served:
+                served.append(bytes(set_key))
+            if bytes(set_key) != served[0]:
+                return None
+            return indexed(set_key, pubkeys, idxs, msgs, sigs)
+
+        crypto_batch.set_indexed_verifier(declining)
+    elif name == "stale_validator_sets":
+        # the state store answers every height with the genesis set, as one
+        # whose records all point back at their first would: the chain goes
+        # on (the live path holds its sets in hand) and no val: write is read back
+        load = node.state_store.load_validators
+        node.state_store.load_validators = lambda height: load(1)
     elif name == "one_chip":
-        # the stand-in with the mesh left out: every dispatch answers
-        # correctly and reports itself on one chip
-        plant_fault("stub_device", node, shards=1)
+        # the stand-in (planted before this) with the mesh left out: every
+        # dispatch answers correctly and reports itself on one chip
+        crypto_batch.get_indexed_verifier().shards = 1
     elif name == "state_unchanged":
         # the app acknowledges every transaction and applies none
         from tendermint_tpu.abci import types as abci
@@ -709,9 +822,7 @@ async def dial_and_warm_in(node, addrs: Sequence[str], stamps: "BlockStamps", ce
     That is the program's start-up and redial behaviour, not replay; one
     wave at a time lands.  Should the peers drop all the same, the count of
     warm-in blocks starts again when they are back."""
-    warm_in = cell.traffic["warm_in_blocks"]
-    if isinstance(warm_in, dict):  # by configuration, as `heights` is
-        warm_in = warm_in[cell.config["name"]]
+    warm_in = warm_in_blocks(cell.config, cell.traffic)
     t0 = time.monotonic()
     held, redials = connections(node), 0
     before = len(held)  # the peer at the tip
@@ -724,6 +835,11 @@ async def dial_and_warm_in(node, addrs: Sequence[str], stamps: "BlockStamps", ce
             raise HarnessFailure(f"could not dial source {addr}")
         held, base = connections(node), len(stamps.times_ns)
         while len(held) < dialed or len(stamps.times_ns) - base < warm_in:
+            if not node.blockchain_reactor.fast_sync:  # no further block will come
+                raise HarnessFailure(
+                    f"the node left fast sync during warm-in, at height {stamps.heights[-1:]}: "
+                    "the chain is too short for this cell"
+                )
             if time.monotonic() - t0 > WARM_IN_DEADLINE_S:
                 raise HarnessFailure(
                     f"only {len(stamps.times_ns) - base} blocks applied over {len(held) - before} "
@@ -744,13 +860,21 @@ def window_rules(
 ) -> Dict[str, int]:
     """The counts a run must keep at 0 for its window to be the one the cell
     describes (see window_failures for the first three)."""
-    checks = dict(window_failures(window.events, min_device_batch))
+    rotating = cell.rotating
+    checks = window_failures(
+        window.events, min_device_batch, len(window.membership_changes) if rotating else None
+    )
     checks["compiles_in_window"] = compiles
     table_dispatches = [
         ev for ev in window.events
         if ev["kind"] == "verify.dispatch" and ev["path"] in TABLE_PATHS
     ]
-    checks["blocks_without_device_dispatch"] = max(0, window.blocks - len(table_dispatches))
+    # a new membership's commits ride the flat device path while its table builds
+    served = table_dispatches if not rotating else [
+        ev for ev in window.events
+        if ev["kind"] == "verify.dispatch" and ev["path"] in DEVICE_PATHS
+    ]
+    checks["blocks_without_device_dispatch"] = max(0, window.blocks - len(served))
     tip_margin = 2 * REQUESTS_PER_PEER * cell.config["source_peers"]
     checks["left_fast_sync"] = int(not still_syncing)
     checks["chain_exhausted"] = int(last_height > chain_heights_ - tip_margin)
@@ -764,10 +888,18 @@ def window_rules(
 
 def cut_window(
     cell: Cell, stamps: "BlockStamps", first: int, seconds: float, events: Sequence[dict],
-    deliver_spans: Sequence[tuple],
+    deliver_spans: Sequence[tuple], membership_heights: Sequence[int] = (),
 ) -> "Window":
     """The window, read off the stamps: from arrival `first` to the last
-    arrival within `seconds` of it, with the events and spans inside."""
+    arrival within `seconds` of it, with the events and spans inside, and
+    those of the chain's `membership_heights` that it met: the new
+    memberships whose table build could land inside it.  A membership that
+    signs first at height h is known once h - 2 is applied (the watch's build
+    starts there, the miss's as h is verified); the watch's builds take three
+    to four of the hub's blocks (36-48 ms, PERF.md section 6).  So: from two
+    heights before the first block inside to one past the last.  The count
+    caps builds and flat dispatches, so one too many loosens a cap that a
+    fault passes a hundred times over, and one too few fails a sound run."""
     import jax
 
     t_open_ns = stamps.times_ns[first]
@@ -785,6 +917,10 @@ def cut_window(
         deliver_spans=[s for s in deliver_spans if t_open_ns <= s[1] and s[2] <= t_close_ns],
         buffered=[stamps.buffered[i] for i in inside],
         device_kind=jax.devices()[0].device_kind,
+        membership_changes=[
+            h for h in membership_heights
+            if inside and stamps.heights[inside[0]] - 2 <= h <= stamps.heights[inside[-1]] + 1
+        ],
     )
 
 
@@ -867,6 +1003,7 @@ async def run_cell(
         # -- sources, warm-in -----------------------------------------------
         meta = await wait_chain(chain_dir, chain_proc, CHAIN_DEADLINE_S)
         chain_proc = None
+        history = chainlib.set_history(zip(pubs, powers), meta)
         sink = sorted(
             d.id for d in node.switch.channel_descs
             if d.id not in {c.id for c in node.blockchain_reactor.get_channels()}
@@ -906,7 +1043,10 @@ async def run_cell(
 
         def window_over() -> bool:
             if len(stamps.times_ns) == n_blocks_before:  # not open yet
-                return time.monotonic_ns() - t_decided_ns >= WARM_IN_DEADLINE_S * 1e9
+                return (
+                    not node.blockchain_reactor.fast_sync  # and never will: it has caught up
+                    or time.monotonic_ns() - t_decided_ns >= WARM_IN_DEADLINE_S * 1e9
+                )
             return time.monotonic_ns() - stamps.times_ns[n_blocks_before] >= seconds * 1e9
 
         while not window_over():
@@ -925,10 +1065,15 @@ async def run_cell(
         sources.stop()
         watch.poll()
         if len(stamps.times_ns) == n_blocks_before:
-            raise HarnessFailure(f"no block applied in {WARM_IN_DEADLINE_S:.0f} s: the replay stalled")
+            raise HarnessFailure(
+                "no block applied once warm-in was over: " + (
+                    f"the replay stalled for {WARM_IN_DEADLINE_S:.0f} s" if still_syncing
+                    else "the node had left fast sync, the chain is too short for this cell"
+                )
+            )
         window = cut_window(
             cell, stamps, n_blocks_before, seconds, watch.events[n_events_before:],
-            deliver.spans if deliver else [],
+            deliver.spans if deliver else [], history.membership_heights,
         )
         t_open_ns, t_close_ns = window.t_open_ns, window.t_close_ns
         setup_s = t_open_ns / 1e9 - t_start
@@ -949,8 +1094,10 @@ async def run_cell(
             still_syncing, stamps.heights[-1], meta["heights"],
         )
         async with HTTPClient(node.rpc_server.listen_addr) as client:
+            spare_secrets, spare_pubs = chainlib.standby(seed, cell.config)
             checks.update(await compare_with_reference(
-                client, node, window, meta, seed, chain_id, vset, secrets, pubs, powers,
+                client, node, window, meta, seed, chain_id, vset,
+                dict(zip(pubs + spare_pubs, secrets + spare_secrets)), history,
             ))
         problems = engine_failures(watch.events, watch.min_device_batch, warm=False)
         checks["engine_errors"] = max(checks["engine_errors"], len(problems))
@@ -976,9 +1123,11 @@ async def run_cell(
             "seed": seed, "warm": warm, "compile_s": round(clock.seconds, 2),
             "persistent_cache_hits": clock.cache_hits, "chain_generate_s": meta["generate_s"],
             "rtt_probe": getattr(node.batch_verifier, "rtt_probe", None),
-            "tabulated_profile": next(
-                ({k: v for k, v in ev.items() if k not in ("seq", "t_ns", "kind")}
-                 for ev in watch.events if ev["kind"] == "verify.tabulated_profile"), None),
+            "membership_changes": window.membership_changes,
+            "table_builds_in_window": [
+                {k: ev.get(k) for k in ("kind", "ms", "ok")}
+                for ev in window.events if ev["kind"] in TABLE_BUILD_EVENTS
+            ],
             "heights_replayed": [window.block_heights[0], window.block_heights[-1]]
             if window.blocks else [],
             "buffered_blocks_min": min(window.buffered) if window.buffered else None,
@@ -1016,6 +1165,11 @@ async def run_cell(
                 await asyncio.wait_for(node.stop(), NODE_STOP_DEADLINE_S)
             except Exception as exc:
                 log(f"the node did not stop cleanly: {exc!r}")
+        if faults:  # what plant_fault hooked into the process goes with the run
+            from tendermint_tpu.crypto import batch as crypto_batch
+
+            crypto_batch.set_verifier(None)
+            crypto_batch.set_indexed_verifier(None)
         shutil.rmtree(home, ignore_errors=True)
         for path in glob.glob(os.path.join(OUT_DIR, "*.spec.*.json")):
             os.remove(path)
@@ -1023,9 +1177,38 @@ async def run_cell(
             chainlib.prune_cache(CACHE_DIR, CACHE_KEEP_BYTES)
 
 
+def accepted_wrongly(
+    chain_id: str, history: reference.SetHistory, commit, block_hash: str
+) -> bool:
+    """Whether a commit the node accepted fails the reference: some
+    signature rejected by OpenSSL under the reference's set for the commit's
+    height, too little of that set's power, or another block's id."""
+    pubs, powers = history.at(commit.height)
+    first_bad, enough = reference.commit_verdict(chain_id, pubs, powers, commit_view(commit))
+    return first_bad is not None or not enough or commit.block_id.hash.hex() != block_hash
+
+
+async def reported_set(client, height: int) -> Optional[list]:
+    """[(pubkey, power)] of the validator set the node reports at `height`
+    (`/validators`, every page), or None where it has none."""
+    from tendermint_tpu.rpc import RPCError
+
+    members, page = [], 1
+    while True:
+        try:
+            got = await client.validators(height, page=page, per_page=100)
+        except RPCError as exc:  # the node's answer is the finding
+            log(f"/validators at height {height}: {exc!r}")
+            return None
+        members += [(v["pub_key"]["value"], v["voting_power"]) for v in got["validators"]]
+        if len(members) >= got["total"] or not got["validators"]:
+            return members
+        page += 1
+
+
 async def compare_with_reference(
     client, node, window: Window, meta: dict, seed: int, chain_id: str, vset,
-    secrets, pubs, powers,
+    secret_of: Dict[bytes, bytes], history: reference.SetHistory,
 ) -> Dict[str, int]:
     """The comparisons that decide `correct`, each a count that must be 0.
 
@@ -1033,15 +1216,22 @@ async def compare_with_reference(
     the app hash at the node's tip against the source chain and the
     reference kvstore; sampled replayed writes read back.  The engine's
     verdicts: the commits it accepted in the window, re-verified serially
-    by the reference, and tampered copies of the window's last commit sent
+    by the reference against the reference's set for their height
+    (`history`), and tampered copies of the window's last commit sent
     through ValidatorSet.verify_commit on the warm table, which must name
-    the validator the reference names."""
+    the validator the reference names.  Where the set changes, the tampers
+    go through the set the node's own state store holds for that height
+    (`vset` is the genesis set, which a static chain keeps), and the sets
+    the node reports at the sampled heights are held to the reference's."""
     if window.blocks == 0:
         return {"reference_not_reached": 1}
     rng = random.Random(seed ^ 0x5EED)
     cell = window.cell
+    rotating = cell.rotating
     heights = window.block_heights
-    n_commits = max(SAMPLE_COMMITS[0], min(SAMPLE_COMMITS[1], SAMPLE_SIGNATURES // len(pubs)))
+    n_commits = max(
+        SAMPLE_COMMITS[0], min(SAMPLE_COMMITS[1], SAMPLE_SIGNATURES // len(history.at(1)[0]))
+    )
     sample = sorted(set(rng.sample(heights, min(n_commits - 1, len(heights))) + [heights[-1]]))
 
     status = (await client.status())["sync_info"]
@@ -1050,21 +1240,23 @@ async def compare_with_reference(
     wrong_blocks = 0
     if tip < heights[-1]:
         wrong_blocks += 1
-    # the tip's header carries the app hash after the block before it
+    # the tip's header carries the app hash after the block before it; the
+    # kvstore counts key=value transactions only, so `val:` ones do not move it
     if status["latest_app_hash"] != reference.kvstore_app_hash(n_txs * (tip - 1), tip - 1):
         wrong_blocks += 1
     if status["latest_block_hash"].hex() != meta["hashes"][tip - 1]:
         wrong_blocks += 1
-    accepted_wrongly = 0
+    wrong_commits = wrong_sets = 0
     for h in sample:
         got = await client.block(h)
         if got["block_id"] is None or got["block_id"]["hash"].hex() != meta["hashes"][h - 1]:
             wrong_blocks += 1
         # the commit the node verified for height h, as it stored it
         commit = (await client.commit(h))["signed_header"].commit
-        first_bad, enough = reference.commit_verdict(chain_id, pubs, powers, commit_view(commit))
-        if first_bad is not None or not enough or commit.block_id.hash.hex() != meta["hashes"][h - 1]:
-            accepted_wrongly += 1
+        if commit.height != h or accepted_wrongly(chain_id, history, commit, meta["hashes"][h - 1]):
+            wrong_commits += 1
+        if rotating and await reported_set(client, h) != list(zip(*history.at(h))):
+            wrong_sets += 1
 
     wrong_reads = 0
     for _ in range(SAMPLE_WRITES):
@@ -1078,27 +1270,44 @@ async def compare_with_reference(
     # quarter of its signatures, at the timed size on the warm table
     commit = node.block_store.load_block_commit(heights[-1] - 1) or \
         node.block_store.load_seen_commit(heights[-1])
+    pubs, powers = history.at(commit.height)
+    secrets = [secret_of[p] for p in pubs]
+    if rotating:
+        vset = node.state_store.load_validators(commit.height)
+
+    def verdict(of) -> Optional[int]:
+        """engine_first_bad; -1 where the node holds no set for the height,
+        or one that refuses the commit before any signature (not its own)."""
+        try:
+            return -1 if vset is None else engine_first_bad(chain_id, vset, of)
+        except ValueError as exc:
+            log(f"the node's set for height {commit.height} refuses the commit: {exc}")
+            return -1
+
     signed = [i for i, cs in enumerate(commit.signatures) if not cs.is_absent()]
     kinds = list(TAMPERS)
     rng.shuffle(kinds)
     verdict_mismatches = 0
-    if engine_first_bad(chain_id, vset, commit) is not None:
+    if verdict(commit) is not None:
         verdict_mismatches += 1
     for q, kind in enumerate(kinds):
         quarter = signed[len(signed) * q // 4: len(signed) * (q + 1) // 4] or signed
         pos = rng.choice(quarter)
         bad = tamper(kind, chain_id, commit, secrets, pos)
         want, _ = reference.commit_verdict(chain_id, pubs, powers, commit_view(bad))
-        got = engine_first_bad(chain_id, vset, bad)
+        got = verdict(bad)
         if want != pos:
             raise HarnessFailure(f"the reference misses the {kind} tamper at #{pos} (says {want})")
         if got != want:
             log(f"tamper {kind} at #{pos}: engine says {got}, reference {want}")
             verdict_mismatches += 1
-    return {
-        "wrong_block_ids": wrong_blocks, "commits_accepted_wrongly": accepted_wrongly,
+    checks = {
+        "wrong_block_ids": wrong_blocks, "commits_accepted_wrongly": wrong_commits,
         "wrong_reads": wrong_reads, "verdict_mismatches": verdict_mismatches,
     }
+    if rotating:
+        checks["wrong_validator_sets"] = wrong_sets
+    return checks
 
 
 # ---------------------------------------------------------------------------

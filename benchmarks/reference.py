@@ -2,9 +2,11 @@
 
 Nothing here imports the program.  Signatures are checked one at a time by
 OpenSSL (through `cryptography`), sign-bytes are formed by this file's own
-copy of the canonical vote layout, and the kvstore's app hash is worked out
-from the transaction count.  The program's engine (JAX kernels, host prep,
-table cache) shares no code with any of it.
+copy of the canonical vote layout, the kvstore's app hash is worked out
+from the transaction count, and a validator set that changes is kept as a
+plain {pubkey: power} under the ABCI's update rule.  The program's engine
+(JAX kernels, host prep, table cache) and its `ValidatorSet` share no code
+with any of it.
 
 Also here, because it is part of the yardstick: the count of integer
 operations and bytes one plain ed25519 verification needs, which the
@@ -13,10 +15,13 @@ kernels' roofline share divides by.
 
 from __future__ import annotations
 
+import bisect
+import functools
 import hashlib
+import random
 import struct
 from dataclasses import dataclass
-from typing import List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 from cryptography.exceptions import InvalidSignature
 from cryptography.hazmat.primitives.asymmetric.ed25519 import Ed25519PublicKey
@@ -142,6 +147,113 @@ def kvstore_app_hash(tx_count: int, height: int) -> bytes:
     """App hash of the builtin kvstore after `height` commits that
     delivered `tx_count` key=value transactions in all."""
     return hashlib.sha256(struct.pack("<QQ", tx_count, height)).digest()
+
+
+# ---------------------------------------------------------------------------
+# validator sets that change (Tendermint v0.33: ABCI validator updates
+# delivered at height H change the set that signs H + 2)
+# ---------------------------------------------------------------------------
+
+Update = Tuple[bytes, int]  # (pubkey, new power); power 0 removes the validator
+
+
+@functools.lru_cache(maxsize=4096)
+def address(pubkey: bytes) -> bytes:
+    return hashlib.sha256(pubkey).digest()[:20]
+
+
+def apply_updates(members: Dict[bytes, int], updates: Sequence[Update]) -> Dict[bytes, int]:
+    """A set held as {pubkey: power} after one block's validator updates."""
+    out = dict(members)
+    for pubkey, power in updates:
+        if power < 0 or (power == 0 and pubkey not in out):
+            raise ValueError(f"update ({pubkey.hex()[:16]}, {power}) fits no validator set")
+        if power == 0:
+            del out[pubkey]
+        else:
+            out[pubkey] = power
+    return out
+
+
+def in_address_order(members: Dict[bytes, int]) -> Tuple[List[bytes], List[int]]:
+    pubkeys = sorted(members, key=address)
+    return pubkeys, [members[p] for p in pubkeys]
+
+
+def draw_updates(
+    changes: dict, seed: int, height: int, members: Dict[bytes, int], standby: List[bytes],
+) -> List[Update]:
+    """The validator updates block `height` delivers, drawn from the seed
+    under a configuration's `validator_set_changes`, against the newest set
+    there is (the one the last updates made).  A swap takes the next standby
+    key and puts the leaver at the queue's end: `standby` is changed."""
+    rng = random.Random(f"bench-valset-{seed}-{height}")
+    updates: List[Update] = []
+    swapped = set()
+    rule = changes.get("membership")
+    if rule and height % rule["every_heights"] == 0:  # one validator swapped
+        lowest = sorted(members, key=lambda p: (members[p], address(p)))
+        leaver, joiner = rng.choice(lowest[: rule["leaver_among_lowest"]]), standby.pop(0)
+        standby.append(leaver)
+        updates += [(leaver, 0), (joiner, members[leaver])]
+        swapped = {leaver, joiner}
+    rule = changes.get("power")
+    if rule and rng.random() < rule["share_of_blocks"]:
+        few, many = (int(x) for x in rule["validators"].split("-"))
+        staying = sorted((p for p in members if p not in swapped), key=address)
+        for pubkey in rng.sample(staying, rng.randint(few, many)):
+            power = members[pubkey]
+            step = max(1, round(rng.random() * rule["delta_share"] * power))
+            up = rng.random() < 0.5 or power - step < 1
+            updates.append((pubkey, power + step if up else power - step))
+    return updates
+
+
+class SetHistory:
+    """The validator set at every height of a chain: the genesis set and the
+    updates each block delivered, applied two heights later."""
+
+    def __init__(self, genesis: Sequence[Update], updates: Dict[int, Sequence[Update]]):
+        members = dict(genesis)
+        self._first = [1]  # the height each set signs first, ascending
+        self.sets = [in_address_order(members)]
+        self.membership_heights: List[int] = []  # where a new membership signs first
+        for height in sorted(updates):
+            if not updates[height]:
+                continue
+            changed = apply_updates(members, updates[height])
+            if changed.keys() != members.keys():
+                self.membership_heights.append(height + 2)
+                pubkeys, powers = in_address_order(changed)
+            else:  # powers only: the order stands
+                pubkeys = self.sets[-1][0]
+                powers = [changed[p] for p in pubkeys]
+            members = changed
+            self._first.append(height + 2)
+            self.sets.append((pubkeys, powers))
+
+    def index(self, height: int) -> int:
+        """Which of `sets` signs `height`."""
+        return bisect.bisect_right(self._first, height) - 1
+
+    def at(self, height: int) -> Tuple[List[bytes], List[int]]:
+        """(pubkeys, powers) in address order of the set that signs `height`."""
+        return self.sets[self.index(height)]
+
+
+def validator_sets(
+    changes: dict, seed: int, heights: int, genesis: Sequence[Update], standby: Sequence[bytes],
+) -> Tuple[SetHistory, Dict[int, List[Update]]]:
+    """The seeded schedule of a chain of `heights` blocks: the sets by
+    height, and the updates by the height that delivers them."""
+    members, queue = dict(genesis), list(standby)
+    updates: Dict[int, List[Update]] = {}
+    for height in range(1, heights + 1):
+        drawn = draw_updates(changes, seed, height, members, queue)
+        if drawn:
+            updates[height] = drawn
+            members = apply_updates(members, drawn)
+    return SetHistory(genesis, updates), updates
 
 
 # ---------------------------------------------------------------------------

@@ -37,6 +37,19 @@ def toy_chain(tmp_path_factory):
     return out, chain.generate(CONFIG, TRAFFIC, SEED, HEIGHTS, out, workers=1)
 
 
+def test_a_static_sets_bytes_are_the_ones_the_generator_always_wrote(toy_chain):
+    """Pinned on the generator as PR 29 left it (chain.FORMAT 3): teaching it
+    sets that change moved no byte of a chain whose set stands still, so no
+    cached chain's name had to."""
+    out, meta = toy_chain
+    assert chain.FORMAT == 3
+    assert digest(out) == "5bcfd89f5b1d5ac1989d9cf7f31711dcf2be9dd62f1f5db1bc75ff5f65751258"
+    assert meta["offsets"][-1] == 23664
+    assert "set_updates" not in meta and "membership_heights" not in meta
+    assert chain.cache_key(CONFIG, TRAFFIC, SEED, HEIGHTS) == "toy-8.replay.s2147483659.h12.4cd42b0868"
+    assert chain.set_changes(CONFIG) == chain.set_changes({"validator_set_changes": "none"}) == {}
+
+
 def test_the_same_seed_gives_the_same_chain(toy_chain, tmp_path):
     out, meta = toy_chain
     again = chain.generate(CONFIG, TRAFFIC, SEED, HEIGHTS, str(tmp_path / "b"), workers=2)

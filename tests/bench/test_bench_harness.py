@@ -7,6 +7,7 @@ No child that a test starts loads libtpu: sources and the chain generator
 run with JAX_PLATFORMS=cpu and never import JAX.
 """
 
+import gc
 import importlib
 import json
 import os
@@ -27,14 +28,24 @@ with open(os.path.join(REPO, "BENCHMARK.json")) as f:
     BENCH = json.load(f)
 
 
-def toy_cell(heights=400, validators=24, chips=1):
+HEIGHTS = 1200  # the toy loop applies 170-300 blocks in its 1 s window: room for twice that
+# a power change in half the blocks and a validator swapped every 10 heights
+ROTATING = {
+    "power": {"share_of_blocks": 0.5, "validators": "1-3", "delta_share": 0.005},
+    "membership": {"every_heights": 10, "leaver_among_lowest": 6, "standby": 5},
+}
+
+def toy_cell(heights=HEIGHTS, validators=24, chips=1, changes=None):
     """A cell of the committee's shape at toy size: 24 validators put ~22
-    signatures in a commit, above min_device_batch."""
+    signatures in a commit, above min_device_batch.  With `changes`, its
+    validator set moves as that `validator_set_changes` says."""
     config = {
         "name": "toy-24", "validators": validators, "absent_share": 0.05,
         "power": {"kind": "zipf", "top": 1000, "s": 0.8},
         "app": "kvstore", "node": {"db_backend": "memdb"}, "source_peers": 2,
     }
+    if changes is not None:
+        config["validator_set_changes"] = changes
     traffic = {"name": "replay", "txs_per_block": 4, "tx_bytes": 60,
                "warm_in_blocks": 3, "heights": {"toy-24": heights}}
     return harness.Cell(
@@ -56,10 +67,10 @@ def on_the_cpu(cfg):
     cfg.tpu.enabled = False  # the stand-in takes the engine's place
 
 
-async def drive(faults, seconds=1.0, trace_run=False, heights=400, chips=1):
+async def drive(faults, seconds=1.0, trace_run=False, heights=HEIGHTS, chips=1, changes=None):
     return await harness.run_cell(
-        toy_cell(heights, chips=chips), SEED, seconds, trace_run, time.monotonic(),
-        faults=faults, configure=on_the_cpu,
+        toy_cell(heights, chips=chips, changes=changes), SEED, seconds, trace_run,
+        time.monotonic(), faults=faults, configure=on_the_cpu,
     )
 
 
@@ -177,6 +188,24 @@ def test_every_configuration_states_its_source_and_guarantees(config):
     assert spec["reduced"] == entry["reduced"] == ["heights"]
     assert len(spec["guarantees"]) >= 3 and spec["assumed"]
     assert "default" in spec["node"]["tpu"]  # no [tpu] knob is turned
+    assert "tabulated" not in spec["node"]["tpu"]  # a knob the program lost in PR 28
+
+
+def test_a_later_configuration_brings_its_own_warm_in_and_chain_length():
+    """What a traffic file keys by configuration name (it cannot know a later
+    one), the configuration's file may give: so a later deployment can use the
+    `replay` mix as it stands instead of a copy under another name."""
+    with open(os.path.join(REPO, "benchmarks", "traffic", "replay.json")) as f:
+        replay = json.load(f)
+    assert harness.warm_in_blocks({"name": "hub-175"}, replay) == 20
+    assert harness.warm_in_blocks({"name": "committee-10k"}, replay) == 12
+    later = {"name": "later", "traffic_warm_in_blocks": {"replay": 7},
+             "traffic_heights": {"replay": 900}}
+    assert harness.warm_in_blocks(later, replay) == 7
+    assert harness.chain_heights(later, replay) == 900
+    assert harness.warm_in_blocks(later, {"name": "other", "warm_in_blocks": 3}) == 3
+    with pytest.raises(harness.HarnessFailure):
+        harness.warm_in_blocks({"name": "unknown"}, replay)
 
 
 def test_the_benchmark_file_keeps_to_the_contract():
@@ -218,6 +247,46 @@ def test_window_rules_count_what_a_window_may_not_hold():
     compiled = {"kind": "verify.bucket_compile", "bucket": 512, "ok": False, "error": "boom"}
     counts = harness.window_failures([build, compiled], 16)
     assert counts["builds_in_window"] == 2 and counts["engine_errors"] == 1
+    assert list(counts) == ["engine_errors", "host_tier_dispatches", "builds_in_window"]
+
+
+@pytest.mark.parametrize("builds,rebuilds,compiles,memberships,beyond", [
+    (0, 0, 0, 0, 0),
+    (3, 3, 0, 3, 0),  # both of the program's builders fired for each new membership
+    (1, 0, 0, 0, 1),  # a table built though no membership changed (a power change, say)
+    (5, 2, 0, 3, 1),
+    (2, 2, 1, 3, 1),  # a compile is never the chain's asking
+    (1200, 0, 0, 12, 1176),  # a build for every block
+])
+def test_a_rotating_window_may_build_what_the_chain_asks_for(
+        builds, rebuilds, compiles, memberships, beyond):
+    events = (
+        [{"kind": "verify.table_build", "ok": True, "ms": 5.0}] * builds
+        + [{"kind": "verify.table_rebuild", "ok": True, "ms": 5.0}] * rebuilds
+        + [{"kind": "verify.bucket_compile", "bucket": 512, "ok": True}] * compiles
+    )
+    counts = harness.window_failures(events, 16, membership_changes=memberships)
+    assert counts["builds_beyond_membership_changes"] == beyond
+    assert "builds_in_window" not in counts and counts["engine_errors"] == 0
+    assert counts["flat_dispatches_beyond_membership_changes"] == 0
+
+
+@pytest.mark.parametrize("flat,memberships,beyond", [
+    (0, 0, 0),
+    (12, 12, 0),  # what sound runs read on the chip: at most one a membership
+    (1, 0, 1),  # a commit declined though no membership changed
+    (120, 12, 0),  # ten a membership: a slow build, still a build
+    (4600, 12, 4480),  # a table never built: two a block from the first change on
+])
+def test_a_rotating_window_may_ride_the_flat_path_while_a_table_builds(flat, memberships, beyond):
+    def dispatch(path):
+        return {"kind": "verify.dispatch", "n": 166, "bucket": 512, "path": path, "shards": 1}
+
+    events = [dispatch("device")] * flat + [dispatch("indexed")] * 50
+    counts = harness.window_failures(events, 16, membership_changes=memberships)
+    assert counts["flat_dispatches_beyond_membership_changes"] == beyond
+    assert counts["builds_beyond_membership_changes"] == 0
+    assert "flat_dispatches_beyond_membership_changes" not in harness.window_failures(events, 16)
 
 
 def test_percentile_and_intervals():
@@ -248,9 +317,17 @@ async def test_a_clean_run_is_correct_and_reports_every_metric(scratch):
     assert all(m["value"] > 0 for m in result["metrics"].values())
     assert list(result)[-1] == "checks"
     assert set(result["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
+    assert set(result["checks"]) == {
+        "engine_errors", "host_tier_dispatches", "builds_in_window", "compiles_in_window",
+        "blocks_without_device_dispatch", "left_fast_sync", "chain_exhausted", "window_empty",
+        "wrong_block_ids", "commits_accepted_wrongly", "wrong_reads", "verdict_mismatches",
+    }  # a static set: the checks it always had, under their names
     # the generated chain was cached, and nothing else left behind
     assert len(os.listdir(scratch / "cache")) == 1
     assert not [f for f in os.listdir(scratch / "out") if f.endswith(".json")]
+    # a loop twice as fast would still end short of the chain's last 80 blocks
+    first, last = result["context"]["heights_replayed"]
+    assert first + 2 * (last - first) <= HEIGHTS - 2 * harness.REQUESTS_PER_PEER * 2
 
 
 async def test_a_traced_run_reads_the_recorders_metrics_and_skips_the_devices(scratch):
@@ -263,8 +340,87 @@ async def test_a_traced_run_reads_the_recorders_metrics_and_skips_the_devices(sc
     # a CPU trace has no device plane: no device metric is made up
     assert not got & {"device_idle_share", "verify_kernel_ms_per_block", "verify_kernel_roofline"}
     assert "breakdown" not in result and "busy_s" not in result["device"]
-    assert result["metrics"]["dispatches_per_block"]["value"] == pytest.approx(2.0, abs=0.2)
+    # what the harness itself guarantees: a table-path dispatch for every block
+    assert 1.0 <= result["metrics"]["dispatches_per_block"]["value"] <= 2.2
     assert result["metrics"]["table_hit_share"]["value"] == 100.0
+    # a static set hashes its set once: the two comparisons a block, no root
+    # (the ring's readers read nothing while an earlier test's node still lives)
+    set_hash = result["metrics"].get("set_hash_ms_per_block")
+    assert set_hash is None or 0 <= set_hash["value"] < 0.5
+
+
+# -- a validator set that changes ------------------------------------------------
+
+
+ROTATING_CHECKS = {
+    "engine_errors", "host_tier_dispatches", "builds_beyond_membership_changes",
+    "flat_dispatches_beyond_membership_changes", "compiles_in_window",
+    "blocks_without_device_dispatch", "left_fast_sync", "chain_exhausted", "window_empty",
+    "wrong_block_ids", "commits_accepted_wrongly", "wrong_reads", "verdict_mismatches",
+    "wrong_validator_sets",
+}
+
+
+async def test_a_rotating_chain_replays_correct_and_shows_the_miss_path(scratch):
+    gc.collect()  # the ring's readers want one live recorder: earlier tests' nodes go now
+    result = await drive(["stub_device"], trace_run=True, changes=ROTATING)
+    assert result["correct"] is True, result["checks"]
+    assert set(result["checks"]) == ROTATING_CHECKS
+    assert all(c == {"value": 0, "limit": 0} for c in result["checks"].values())
+    got = result["metrics"]
+    assert got["table_hit_share"]["value"] < 100.0  # a miss for each membership it met
+    assert 1.0 <= got["dispatches_per_block"]["value"] <= 2.2  # table paths only
+    assert got["set_hash_ms_per_block"]["value"] > 0  # a root per changed set
+    first, last = result["context"]["heights_replayed"]
+    changes = result["context"]["membership_changes"]
+    assert len(changes) >= 4
+    assert all(first - 2 <= h <= last + 1 and h % 10 == 2 for h in changes)
+    # the stand-in builds one table for each membership it meets
+    assert 1 <= len(result["context"]["table_builds_in_window"]) <= len(changes)
+
+
+async def test_a_table_built_for_every_commit_fails_a_rotating_run(scratch):
+    result = await drive(["stub_device", "build_always"], changes=ROTATING)
+    assert result["correct"] is False
+    assert failed_checks(result) == {"builds_beyond_membership_changes"}
+    assert result["checks"]["builds_beyond_membership_changes"]["value"] > result["attempted"]
+
+
+async def test_a_table_never_built_fails_a_rotating_run(scratch):
+    """Every block has its dispatch on a device path and no build is one too
+    many, yet the new memberships' tables never come.  A swap every 40 heights,
+    so that a loop with one dispatch a block (S3) would still read over the limit."""
+    sparse = dict(ROTATING, membership=dict(ROTATING["membership"], every_heights=40))
+    result = await drive(["stub_device", "table_never_built"], changes=sparse)
+    assert result["correct"] is False
+    assert failed_checks(result) == {"flat_dispatches_beyond_membership_changes"}
+    assert result["checks"]["flat_dispatches_beyond_membership_changes"]["value"] >= 20
+
+
+async def test_a_store_that_answers_with_the_genesis_set_fails_a_rotating_run(scratch):
+    """The fifth guarantee broken and nothing else: the chain goes on (the
+    live path holds its sets in hand), every header root agrees, and
+    `/validators` reads back none of the `val:` writes."""
+    result = await drive(["stub_device", "stale_validator_sets"], changes=ROTATING)
+    assert result["correct"] is False
+    failed = failed_checks(result)
+    # the tampers go through the set the store holds for the last height too
+    assert "wrong_validator_sets" in failed and failed <= {
+        "wrong_validator_sets", "verdict_mismatches"}
+    assert result["checks"]["wrong_validator_sets"]["value"] >= 4  # every sampled height
+
+
+async def test_a_window_that_met_no_new_membership_may_build_nothing(scratch):
+    """Powers move in half the blocks and no validator is swapped, as in most
+    windows of a chain at the Hub's cadence: the run is held to no build and
+    no flat dispatch at all, and to the sets the powers made."""
+    still = {"power": ROTATING["power"],
+             "membership": dict(ROTATING["membership"], every_heights=5000)}
+    result = await drive(["stub_device"], changes=still)
+    assert result["correct"] is True, result["checks"]
+    assert set(result["checks"]) == ROTATING_CHECKS
+    assert result["context"]["membership_changes"] == []
+    assert result["context"]["table_builds_in_window"] == []
 
 
 @pytest.mark.parametrize("fault,caught_by", [
@@ -300,10 +456,18 @@ async def test_a_host_tier_dispatch_in_the_window_fails_the_run(scratch):
 
 async def test_catching_up_before_the_window_ends_fails_the_run(scratch):
     """A chain too short for the window: the node runs out of blocks, and
-    the run is failed, never short."""
-    result = await drive(["stub_device"], seconds=2.0, heights=60)
-    assert result["correct"] is False
-    assert {"chain_exhausted", "left_fast_sync"} <= failed_checks(result)
+    the run is failed, never short.  An idle machine replays the 60 blocks
+    before the window opens: the harness then says so at once (it used to
+    wait out WARM_IN_DEADLINE_S for a block that could not come)."""
+    t0 = time.monotonic()
+    try:
+        result = await drive(["stub_device"], seconds=2.0, heights=60)
+    except harness.HarnessFailure as exc:
+        assert "left fast sync" in str(exc) and "too short" in str(exc)
+    else:
+        assert result["correct"] is False
+        assert {"chain_exhausted", "left_fast_sync"} <= failed_checks(result)
+    assert time.monotonic() - t0 < harness.WARM_IN_DEADLINE_S / 2
 
 
 async def test_the_tip_peer_reports_the_chains_length_and_holds_no_block():

@@ -164,7 +164,7 @@ def test_useful_rows_counts_a_chunked_dispatch_by_its_chunks():
         dispatch(20, 5, n=9498, bucket=2048, path="chunked"),  # 5 chunks of 2048
         dispatch(30, 5, n=40, bucket=0, path="host"),
     ])
-    paths = {"paths": ["indexed", "chunked", "tabulated"]}
+    paths = {"paths": ["indexed", "chunked"]}
     assert useful_rows.read(w, paths) == pytest.approx(100.0 * (166 + 9498) / (512 + 10240))
     assert useful_rows.read(window(), paths) is None
 
@@ -173,12 +173,11 @@ def test_kernel_pattern_files_name_the_programs_jitted_entry_points():
     """One file per kernel; each pattern matches the module name XLA gives
     the jitted function it stands for, and no other's."""
     patterns = {p["name"]: p for p in trace.load_kernel_patterns()}
-    assert set(patterns) >= {"indexed_run", "ladder_flat", "straus_flat", "tabulated"}
+    assert set(patterns) == {"indexed_run", "ladder_flat", "straus_flat"}
     names = {
         "indexed_run": "jit_run(5163829213)",
         "ladder_flat": "jit_verify_prepared_pallas(77)",
         "straus_flat": "jit_verify_prepared",
-        "tabulated": "jit_verify_tabulated(3)",
     }
     for kernel, module in names.items():
         hits = [k for k, p in patterns.items() if p["regex"].search(module)]
