@@ -172,15 +172,18 @@ class BlockchainReactor(Reactor):
             if not self.fast_sync:
                 return
             _, requested_at = self.scheduler.pending.get(block.height, (None, None))
-            if self.scheduler.block_received(peer.id, block.height):
-                self.processor.add_block(block.height, block, peer.id, {
+            now = time.monotonic()
+            took = self.scheduler.block_received(peer.id, block.height, now)
+            if took:
+                received = {
                     "peer": peer.id[:8], "bytes": len(msg["block"]),
-                    "decode_ms": (received_ns - t_ns) / 1e6,
-                    "download_ms": (time.monotonic() - requested_at) * 1e3,
-                    "received_ns": received_ns,
-                })
+                    "decode_ms": (received_ns - t_ns) / 1e6, "received_ns": received_ns,
+                }
+                if requested_at is not None:  # else a late copy, its request given up on
+                    received["download_ms"] = (now - requested_at) * 1e3
+                self.processor.add_block(block.height, block, peer.id, received)
                 self._wake_pool()
-            else:
+            elif took is False:  # None: a late copy of a block asked for, dropped
                 await self._report(
                     behaviour.message_out_of_order(peer.id, "unsolicited block")
                 )

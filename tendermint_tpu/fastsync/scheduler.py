@@ -17,6 +17,10 @@ class PeerInfo:
     height: int = 0  # best height the peer claims
     base: int = 0  # lowest height the peer retains
     pending: Set[int] = field(default_factory=set)  # heights requested from it
+    last_block_at: float = 0.0  # when it last delivered a block it was asked for
+    # heights asked of it whose request was given up on (timed out, or another
+    # peer's copy came first): its copy may still arrive and is no offence
+    late: Set[int] = field(default_factory=set)
 
 
 class Scheduler:
@@ -76,16 +80,32 @@ class Scheduler:
         return freed
 
     # -- block events ------------------------------------------------------
-    def block_received(self, peer_id: str, height: int) -> bool:
-        """False = unsolicited/wrong peer (punishable)."""
+    def block_received(self, peer_id: str, height: int, now: float = 0.0) -> Optional[bool]:
+        """True = take the block.  False = unsolicited: never asked of this
+        peer (punishable).  None = a copy that was asked for and is no longer
+        wanted (its request timed out and another copy came first): drop it,
+        it is no fault of the peer's."""
+        p = self.peers.get(peer_id)
         owner = self.pending.get(height)
         if owner is None or owner[0] != peer_id:
-            return False
-        del self.pending[height]
+            if p is None or height not in p.late:
+                return False
+            p.late.discard(height)
+            if owner is None:
+                if height < self.height or height in self.received:
+                    return None
+            else:
+                # asked again of another peer since: the first copy to arrive
+                # is the block, and the other's will be the late one
+                other = self.peers.get(owner[0])
+                if other is not None:
+                    other.pending.discard(height)
+                    other.late.add(height)
+        self.pending.pop(height, None)
         self.received[height] = peer_id
-        p = self.peers.get(peer_id)
         if p is not None:
             p.pending.discard(height)
+            p.last_block_at = now
         return True
 
     def no_block(self, peer_id: str, height: int) -> None:
@@ -120,13 +140,24 @@ class Scheduler:
     def next_requests(self, now: float) -> List[Tuple[str, int]]:
         """(peer, height) pairs to request next; also re-assigns timed-out
         pending requests."""
-        # prune timeouts
+        # prune timeouts: a request is given up on when its peer has
+        # delivered nothing for `request_timeout` since it was made (v0
+        # pool.go's per-peer timer, reset by every block).  A peer that is
+        # delivering is slow at worst: with 20 requests out, blocks that take
+        # long to apply (1.5 MB ones, or 1,000 txs on disk stores) are that
+        # long on the way, and a second copy would only be longer
         for h, (owner, at) in list(self.pending.items()):
-            if now - at > self.request_timeout:
+            p = self.peers.get(owner)
+            heard = at if p is None else max(at, p.last_block_at)
+            if now - heard > self.request_timeout:
                 del self.pending[h]
-                p = self.peers.get(owner)
                 if p is not None:
                     p.pending.discard(h)
+                    p.late.add(h)
+        stale = self.height - self.max_total_pending  # a copy this late never comes
+        for p in self.peers.values():
+            if p.late and min(p.late) < stale:
+                p.late = {h for h in p.late if h >= stale}
 
         out: List[Tuple[str, int]] = []
         target = self.max_peer_height()

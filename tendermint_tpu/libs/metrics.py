@@ -307,6 +307,8 @@ class StateMetrics:
             self.block_processing_time = _NOP
             self.valset_updates = _NOP
             self.valset_size = _NOP
+            self.tx_index_lag = _NOP
+            self.tx_index_cuts = _NOP
             return
         from prometheus_client import Counter, Gauge, Histogram
 
@@ -324,6 +326,16 @@ class StateMetrics:
         ).labels(chain_id=chain_id)
         self.valset_size = Gauge(
             "valset_size", "Validators in the upcoming (next) validator set.", **kw
+        ).labels(chain_id=chain_id)
+        self.tx_index_lag = Gauge(
+            "tx_index_lag_blocks",
+            "Last block applied less the last height whose txs are all in the tx index.",
+            **kw,
+        ).labels(chain_id=chain_id)
+        self.tx_index_cuts = Counter(
+            "tx_index_cuts",
+            "Times the tx indexer's subscription was cancelled under it (indexing stops).",
+            **kw,
         ).labels(chain_id=chain_id)
 
 

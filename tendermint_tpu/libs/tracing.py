@@ -74,7 +74,25 @@ Event kinds currently emitted:
                       carried with the block bytes, decode_ms, download_ms
                       (request to receipt),
                       queued_ms (receipt to peek_two), peer; wait_ms (since the
-                      previous block's end), pending (blocks queued)
+                      previous block's end), pending (blocks queued).  On
+                      on-disk stores (libs/kvstore.py WriteMeter; absent on
+                      memdb), what they wrote between the previous block's
+                      end and this one's, the indexer's work between spans
+                      included: db_ms (inside write transactions, <= the
+                      block interval), db_ms.<store> (app, blockstore,
+                      state, tx_index, evidence; they add up to db_ms),
+                      db_txns, db_rows, db_bytes (counts), db_max_ms (the
+                      slowest single transaction: a WAL checkpoint).  With
+                      the kv tx indexer on (memdb too): txs (in this block),
+                      txs_indexed (taken in by the indexer since the block
+                      before closed), index_lag (this height less the last
+                      whose txs are all indexed)
+    txindex.cut       height, reason, indexed_through
+                                               the indexer's subscription was
+                                               cancelled under it (a full buffer:
+                                               "out of capacity"); nothing after
+                                               indexed_through's queued txs is
+                                               indexed until a restart
   gossip (consensus/reactor.py, event-driven path):
     gossip.wakeup     peer                     routine woken by an event (not the
                                                fallback sleep cap); HIGH-RATE —
@@ -705,6 +723,8 @@ REPLAY_ROWS = (
     ("verify.dispatch", ("rows_ms", "host_prep_ms", "pack_ms", "launch_ms", "put_ms",
                          "fetch_ms")),
     ("fastsync.block", ("decode_ms", "download_ms", "queued_ms")),
+    ("fastsync.block", ("db_ms", "db_ms.app", "db_ms.blockstore", "db_ms.state",
+                        "db_ms.tx_index", "db_max_ms", "db_txns", "txs_indexed", "index_lag")),
 )
 
 

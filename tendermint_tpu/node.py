@@ -15,7 +15,7 @@ from .abci import types as abci_types
 from .config import Config
 from .consensus import ConsensusState, Handshaker
 from .consensus.wal import WAL
-from .libs.kvstore import open_db
+from .libs.kvstore import WriteMeter, open_db
 from .libs.log import get_logger
 from .libs.service import Service
 from .mempool import Mempool
@@ -96,9 +96,15 @@ class Node(Service):
         self.storage_health = StorageHealth(
             data_dir=config.db_dir() if home is not None else None
         )
-        self.block_store = BlockStore(self._wrap_db(open_db("blockstore", home, backend), "blockstore"))
+        # the on-disk stores' write counters, read as each block ends
+        # (BlockExecutor.apply_block); on memdb it meters nothing
+        self.db_writes = WriteMeter()
+        metered = self.db_writes.add
+        self.block_store = BlockStore(
+            self._wrap_db(metered(open_db("blockstore", home, backend)), "blockstore")
+        )
         self.block_store.storage_health = self.storage_health
-        self.state_db = self._wrap_db(open_db("state", home, backend), "state")
+        self.state_db = self._wrap_db(metered(open_db("state", home, backend)), "state")
         self.state_store = StateStore(self.state_db)
 
         self.event_bus = EventBus()
@@ -112,7 +118,7 @@ class Node(Service):
             # opened only for the builtin stateful apps — a socket/gRPC app
             # must not grow a stray empty db under home/data
             app_db=(
-                self._wrap_db(open_db("app", home, backend), "app")
+                self._wrap_db(metered(open_db("app", home, backend)), "app")
                 if config.base.proxy_app in ("kvstore", "bank", "staking")
                 else None
             ),
@@ -126,7 +132,7 @@ class Node(Service):
 
         # tx indexer
         if config.tx_index.indexer == "kv":
-            self.tx_indexer = TxIndexer(open_db("tx_index", home, backend))
+            self.tx_indexer = TxIndexer(metered(open_db("tx_index", home, backend)))
         else:
             self.tx_indexer = NullTxIndexer()
         self.indexer_service = IndexerService(self.tx_indexer, self.event_bus)
@@ -331,6 +337,8 @@ class Node(Service):
         if isinstance(self.priv_validator, Service) and not self.priv_validator.is_running:
             await self.priv_validator.start()
         await self.event_bus.start()
+        self.indexer_service.recorder = self.flight_recorder
+        self.indexer_service.metrics = self.metrics_provider.state
         await self.indexer_service.start()
         await self.proxy_app.start()
 
@@ -374,7 +382,7 @@ class Node(Service):
 
         home = None if cfg.base.db_backend == "memdb" else cfg.home
         self.evidence_pool = EvidencePool(
-            open_db("evidence", home, cfg.base.db_backend), self.state_store
+            self.db_writes.add(open_db("evidence", home, cfg.base.db_backend)), self.state_store
         )
         self.evidence_pool.metrics = self.metrics_provider.evidence
         self.evidence_pool.recorder = self.flight_recorder
@@ -393,6 +401,10 @@ class Node(Service):
             evidence_pool=self.evidence_pool,
             event_bus=self.event_bus,
             metrics=self.metrics_provider.state,
+            db_writes=self.db_writes,
+            indexer_service=(
+                self.indexer_service if cfg.tx_index.indexer == "kv" else None
+            ),
         )
 
         self.consensus = ConsensusState(

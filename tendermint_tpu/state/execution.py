@@ -98,6 +98,8 @@ class BlockExecutor:
         evidence_pool=None,
         event_bus=None,
         metrics=None,
+        db_writes=None,
+        indexer_service=None,
     ):
         self.state_store = state_store
         self.proxy_app = proxy_app
@@ -105,6 +107,12 @@ class BlockExecutor:
         self.evidence_pool = evidence_pool
         self.event_bus = event_bus
         self.metrics = metrics
+        # what the open span gets besides the stage times, as each block ends:
+        # the on-disk stores' writes since the previous block's end (a
+        # libs.kvstore.WriteMeter; the indexer's work between two blocks is
+        # inside) and how far the tx index has come (state.txindex.IndexerService)
+        self.db_writes = db_writes
+        self.indexer_service = indexer_service
         self.log = get_logger("state")
         self._abci_committed_ns = 0
 
@@ -174,7 +182,12 @@ class BlockExecutor:
 
         await self._fire_events(block, abci_responses, validator_updates)
         laps.lap("events_ms")
-        tracing.annotate(**laps.fields)
+        fields = laps.fields
+        if self.db_writes is not None:
+            fields.update(self.db_writes.lap())
+        if self.indexer_service is not None:
+            fields.update(self.indexer_service.block_closed(block.height, len(block.txs)))
+        tracing.annotate(**fields)
         return state, retain_height
 
     async def commit(

@@ -8,8 +8,8 @@ powering tx_search).
 
 from __future__ import annotations
 
-import asyncio
-from typing import Dict, List, Optional
+import collections
+from typing import Deque, Dict, List, Optional, Tuple
 
 from ..encoding import codec
 from ..libs.events import Query
@@ -121,21 +121,36 @@ class NullTxIndexer:
 
 class IndexerService(Service):
     """Subscribes to the event bus and feeds the indexer
-    (state/txindex/indexer_service.go)."""
+    (state/txindex/indexer_service.go).
+
+    The subscription is buffered and the bus cancels one that is full
+    (libs/events.py, "out of capacity"): under sustained load the index then
+    stops for good.  That is counted here, not mended: `block_closed()` says
+    how many blocks the index is behind and how many txs it took in since
+    the block before, and the cut leaves one `txindex.cut` event and one
+    error line."""
 
     SUBSCRIBER = "tx-indexer"
+    BUFFER = 10000
 
     def __init__(self, indexer, event_bus: tme.EventBus):
         super().__init__("indexer-service")
         self.indexer = indexer
         self.event_bus = event_bus
+        self.recorder = None  # the node's FlightRecorder, for `txindex.cut`
+        self.metrics = None  # the node's libs.metrics.StateMetrics
         self._task = None
+        self._last = (0, -1)  # (height, index) of the last tx indexed
+        self._indexed = 0  # txs indexed since the service started
+        self._indexed_at_close = 0  # of them, by the last block closed
+        # blocks closed and not yet wholly indexed, oldest first: (height, its last tx's index)
+        self._closed: Deque[Tuple[int, int]] = collections.deque()
+        self._height = 0  # the last block closed
+        self.indexed_through: Optional[int] = None  # last height whose txs are all indexed
 
     async def on_start(self) -> None:
-        import asyncio
-
         sub = await self.event_bus.subscribe(
-            self.SUBSCRIBER, tme.query_for_event(tme.EVENT_TX), buffer=10000
+            self.SUBSCRIBER, tme.query_for_event(tme.EVENT_TX), buffer=self.BUFFER
         )
         self._sub = sub
 
@@ -151,14 +166,53 @@ class IndexerService(Service):
                     },
                     msg.events,
                 )
+                self._last = (data["height"], data["index"])
+                self._indexed += 1
+            if not self._stopped:
+                self._cut(sub.cancel_reason)
 
-        self._task = asyncio.create_task(run())
+        # spawned, so that the loop profiler accounts its time (category `other`)
+        self._task = self.spawn(run(), name="tx-indexer")
+
+    def _cut(self, reason: str) -> None:
+        """The subscription was cancelled under the service: nothing further
+        will be indexed.  What was queued before the cut has been."""
+        self._settle()
+        self._closed.clear()
+        through = self.indexed_through or 0
+        self.logger.error(
+            "tx indexing stopped: subscription cancelled (%s) at height %d, indexed through %d",
+            reason, self._height, through,
+        )
+        if self.recorder is not None:
+            self.recorder.record(
+                "txindex.cut", height=self._height, reason=reason, indexed_through=through
+            )
+        if self.metrics is not None:
+            self.metrics.tx_index_cuts.inc()
+
+    def _settle(self) -> None:
+        closed = self._closed
+        while closed and (closed[0][1] < 0 or closed[0] <= self._last):
+            self.indexed_through = closed.popleft()[0]
+
+    def block_closed(self, height: int, n_txs: int) -> Dict[str, int]:
+        """Block `height` (of `n_txs` txs, all published by now) has been
+        applied.  Returns the open span's fields: `txs`, `txs_indexed` (txs
+        indexed since the block before was closed) and `index_lag` (this
+        height less the last one whose txs are all indexed)."""
+        if self.indexed_through is None:
+            self.indexed_through = height - 1
+        self._height = height
+        if self._task is not None and not self._task.done():
+            self._closed.append((height, n_txs - 1))
+            self._settle()
+        lag = height - self.indexed_through
+        if self.metrics is not None:
+            self.metrics.tx_index_lag.set(lag)
+        indexed = self._indexed - self._indexed_at_close
+        self._indexed_at_close = self._indexed
+        return {"txs": n_txs, "txs_indexed": indexed, "index_lag": lag}
 
     async def on_stop(self) -> None:
         await self.event_bus.unsubscribe_all(self.SUBSCRIBER)
-        if self._task:
-            self._task.cancel()
-            try:
-                await self._task
-            except (asyncio.CancelledError, Exception):
-                pass

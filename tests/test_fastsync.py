@@ -81,6 +81,70 @@ class TestScheduler:
         reqs2 = s.next_requests(now=5.0)
         assert {h for _, h in reqs2} == set(reqs.keys())
 
+    def test_a_peer_that_keeps_delivering_is_not_timed_out(self):
+        """The timeout is the peer's silence, not the request's age (v0
+        pool.go's per-peer timer): 20 requests out and blocks that take 0.9 s
+        to apply are 17 s on the way."""
+        s = Scheduler(1, max_pending_per_peer=20, request_timeout=15.0)
+        s.set_peer_range("p1", 1, 20)
+        for pid, h in s.next_requests(0.0):
+            s.mark_requested(pid, h, 0.0)
+        for h in range(1, 20):  # a block every 0.9 s: the 19th at 17.1 s
+            assert s.next_requests(h * 0.9) == []
+            assert s.block_received("p1", h, h * 0.9)
+        # the 20th is 17.1 s old and its peer was heard a moment ago
+        assert 20 in s.pending and not s.peers["p1"].late
+        # silent from then on: given up on 15 s after its last block
+        assert s.next_requests(17.1 + 14.9) == [] and 20 in s.pending
+        assert s.next_requests(17.1 + 15.1) == [("p1", 20)]
+        assert 20 not in s.pending and s.peers["p1"].late == {20}
+
+    @pytest.mark.parametrize(
+        "then", ["not_asked_again", "asked_of_another", "the_other_copy_first", "processed"]
+    )
+    def test_a_late_copy_of_a_block_asked_for_is_no_offence(self, then):
+        s = Scheduler(1, max_pending_per_peer=1, request_timeout=1.0)
+        s.set_peer_range("slow", 1, 5)
+        assert s.next_requests(0.0) == [("slow", 1)]
+        s.mark_requested("slow", 1, 0.0)
+        s.set_peer_range("other", 1, 5)
+        s.mark_requested("other", 2, 1.5)
+        s.peers["other"].pending.add(2)  # busy with height 2 for now
+        assert s.next_requests(2.0) == [("slow", 1)]  # timed out, and asked for again
+        assert s.peers["slow"].late == {1} and 1 not in s.pending
+        if then == "not_asked_again":
+            assert s.block_received("slow", 1, 2.5) is True  # still wanted: it is the block
+            assert s.received[1] == "slow"
+        elif then == "asked_of_another":
+            s.peers["slow"].pending.clear()
+            s.peers["other"].pending.add(1)
+            s.mark_requested("other", 1, 2.0)
+            assert s.block_received("slow", 1, 2.5) is True  # the first copy to arrive
+            assert s.received[1] == "slow" and 1 not in s.pending
+            assert 1 not in s.peers["other"].pending
+            assert s.block_received("other", 1, 2.6) is None  # and the other's is the late one
+            assert s.received[1] == "slow" and s.peers["other"].late == set()
+        else:
+            s.mark_requested("other", 1, 2.0)
+            assert s.block_received("other", 1, 2.1) is True
+            if then == "processed":
+                s.block_processed(1)
+            assert s.block_received("slow", 1, 2.5) is None  # asked for, no longer wanted
+            assert s.received.get(1, "other") == "other"
+        assert s.peers["slow"].late == set()
+        assert s.block_received("slow", 1, 3.0) is False  # a further copy was never asked for
+        assert s.block_received("slow", 4, 3.0) is False
+
+    def test_what_was_given_up_on_is_forgotten_far_behind_the_tip(self):
+        s = Scheduler(1, max_pending_per_peer=1, max_total_pending=3, request_timeout=1.0)
+        s.set_peer_range("mute", 1, 50)
+        s.mark_requested("mute", 1, 0.0)
+        s.next_requests(2.0)
+        assert s.peers["mute"].late == {1}
+        s.height = 5  # as if heights 1-4 came from elsewhere
+        s.next_requests(2.0)
+        assert s.peers["mute"].late == set()
+
     def test_peer_base_respected(self):
         s = Scheduler(1)
         s.set_peer_range("pruned", base=50, height=100)
@@ -476,6 +540,45 @@ class TestBehaviourReporting:
         await reactor.receive(BLOCKCHAIN_CHANNEL, _Peer(), b"\x00garbage")
         reports = reactor.reporter.get("peerX")
         assert len(reports) == 1 and reports[0].kind == BAD_MESSAGE
+
+    @pytest.mark.parametrize("asked,reported,taken", [
+        ("never", True, 0), ("given_up_on", False, 0), ("given_up_on_and_still_wanted", False, 1),
+    ])
+    async def test_only_a_block_never_asked_for_is_reported(self, asked, reported, taken):
+        """A copy of a block that was asked for and arrives after its request
+        was given up on is dropped without a word: reporting it stopped both
+        sources of a joining node whenever blocks took long to apply."""
+        from tendermint_tpu.fastsync.processor import Processor
+        from tendermint_tpu.fastsync.reactor import BLOCKCHAIN_CHANNEL, BlockchainReactor, _enc
+        from tendermint_tpu.p2p.behaviour import MESSAGE_OUT_OF_ORDER, MockReporter
+        from tendermint_tpu.types import Block, Header
+
+        class _Peer:
+            id = "peerX"
+
+        reactor = BlockchainReactor.__new__(BlockchainReactor)
+        reactor.reporter = MockReporter()
+        reactor.fast_sync = True
+        reactor.refill_heights = set()
+        reactor._wake = None
+        reactor.scheduler = Scheduler(1, request_timeout=1.0)
+        reactor.processor = Processor(1)
+        reactor.scheduler.set_peer_range("peerX", 1, 9)
+        reactor.scheduler.set_peer_range("peerY", 1, 9)
+        if asked != "never":
+            reactor.scheduler.mark_requested("peerX", 3, 0.0)
+            reactor.scheduler.next_requests(2.0)
+        if asked == "given_up_on":
+            reactor.scheduler.mark_requested("peerY", 3, 2.0)
+            assert reactor.scheduler.block_received("peerY", 3, 2.1)
+        block = Block(Header(chain_id="c", height=3), [])
+        msg = _enc("block_response", {"block": block.serialize()})
+        await reactor.receive(BLOCKCHAIN_CHANNEL, _Peer(), msg)
+        reports = reactor.reporter.get("peerX")
+        assert [r.kind for r in reports] == ([MESSAGE_OUT_OF_ORDER] if reported else [])
+        assert reactor.processor.pending_range() == taken
+        if taken:  # with what the receive path measured, less the time since a request it no longer has
+            assert set(reactor.processor.received(3)) == {"peer", "bytes", "decode_ms", "received_ns"}
 
     async def test_switch_reporter_stops_bad_and_marks_good(self):
         from tendermint_tpu.p2p.behaviour import (
