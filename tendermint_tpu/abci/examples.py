@@ -39,6 +39,21 @@ class KVStoreApplication(t.Application):
     validator-change tests drive).  app_hash commits to (size, update
     count) deterministically.
 
+    A block reaches the store in one transaction.  Every write made between
+    `BeginBlock` and `Commit` (the `kv:` rows, validator sets and deletes,
+    the evidence row, `__state__`) is staged in memory, last write to a key
+    winning, and `commit()` hands the lot to the store as ONE
+    `KVStore.write_batch` before it takes a snapshot and before it returns:
+    what a committed block wrote is in the store no later than `Commit`'s
+    response, `__state__` and its rows together or not at all.  A kill in
+    mid-block therefore leaves none of that block's rows and `__state__` at
+    the height before (the handshake re-delivers the block); a `write_batch`
+    that raises leaves the same and raises out of `commit()`.  `Query` and
+    `BeginBlock`'s read of the evidence row look in the stage first, so a
+    `Query` between `DeliverTx` and `Commit` reads its own block's writes.
+    Outside a block nothing is held back: `init_chain` flushes its
+    validators as it ends, a snapshot restore writes straight to the store.
+
     With `snapshot_interval` > 0 the app takes a state snapshot at every
     multiple of that height during `commit` (abci/example/kvstore
     PersistentKVStoreApplication snapshot flavor): the full key space is
@@ -66,6 +81,8 @@ class KVStoreApplication(t.Application):
         self.tx_count = 0
         self.validators: Dict[bytes, int] = {}  # pubkey -> power
         self._pending_updates: List[t.ValidatorUpdate] = []
+        # the open block's writes, key -> value or None (deleted); see _flush
+        self._staged: Dict[bytes, Optional[bytes]] = {}
         # in-flight restore: {"snapshot", "app_hash", "hashes", "buf", "next"}
         self._restore: Optional[dict] = None
         self._load_state()
@@ -81,10 +98,26 @@ class KVStoreApplication(t.Application):
             self.validators[k[len(b"__val__"):]] = struct.unpack("<q", v)[0]
 
     def _save_state(self) -> None:
-        self.db.set(
-            b"__state__",
-            struct.pack("<QQB", self.height, self.tx_count, len(self.app_hash)) + self.app_hash,
+        """Stage the `__state__` row: it goes out with the block's rows."""
+        self._staged[b"__state__"] = (
+            struct.pack("<QQB", self.height, self.tx_count, len(self.app_hash)) + self.app_hash
         )
+
+    def _get(self, key: bytes) -> Optional[bytes]:
+        """The store as the open block sees it: its own writes first."""
+        if key in self._staged:
+            return self._staged[key]
+        return self.db.get(key)
+
+    def _flush(self) -> None:
+        """Everything staged, as one atomic batch.  A key is in one list
+        only (the stage keeps its last write), so `write_batch` applying
+        sets before deletes cannot delete a key that was set again.  The
+        stage empties only once the store has taken the batch."""
+        sets = [(k, v) for k, v in self._staged.items() if v is not None]
+        deletes = [k for k, v in self._staged.items() if v is None]
+        self.db.write_batch(sets, deletes)
+        self._staged.clear()
 
     # -- ABCI --------------------------------------------------------------
     def info(self, req: t.RequestInfo) -> t.ResponseInfo:
@@ -99,6 +132,7 @@ class KVStoreApplication(t.Application):
     def init_chain(self, req: t.RequestInitChain) -> t.ResponseInitChain:
         for vu in req.validators:
             self._set_validator(vu)
+        self._flush()
         return t.ResponseInitChain()
 
     def begin_block(self, req: t.RequestBeginBlock) -> t.ResponseBeginBlock:
@@ -111,14 +145,14 @@ class KVStoreApplication(t.Application):
             # pipeline reached ABCI: query data=b"__byzantine__" returns
             # the hex addresses BeginBlock reported.
             key = b"kv:__byzantine__"
-            existing = self.db.get(key)
+            existing = self._get(key)
             addrs = set(existing.split(b",")) if existing else set()
             for ev in req.byzantine_validators:
                 addr = ev.get("address", b"") if isinstance(ev, dict) else b""
                 if isinstance(addr, bytes) and addr:
                     addrs.add(addr.hex().encode())
             if addrs:
-                self.db.set(key, b",".join(sorted(addrs)))
+                self._staged[key] = b",".join(sorted(addrs))
         return t.ResponseBeginBlock()
 
     def _is_validator_tx(self, tx: bytes) -> bool:
@@ -157,7 +191,7 @@ class KVStoreApplication(t.Application):
             key, value = req.tx.split(b"=", 1)
         else:
             key, value = req.tx, req.tx
-        self.db.set(b"kv:" + key, value)
+        self._staged[b"kv:" + key] = value
         self.tx_count += 1
         events = [
             t.Event(
@@ -173,10 +207,10 @@ class KVStoreApplication(t.Application):
     def _set_validator(self, vu: t.ValidatorUpdate) -> None:
         if vu.power == 0:
             self.validators.pop(vu.pub_key, None)
-            self.db.delete(b"__val__" + vu.pub_key)
+            self._staged[b"__val__" + vu.pub_key] = None
         else:
             self.validators[vu.pub_key] = vu.power
-            self.db.set(b"__val__" + vu.pub_key, struct.pack("<q", vu.power))
+            self._staged[b"__val__" + vu.pub_key] = struct.pack("<q", vu.power)
 
     def end_block(self, req: t.RequestEndBlock) -> t.ResponseEndBlock:
         return t.ResponseEndBlock(validator_updates=list(self._pending_updates))
@@ -187,6 +221,7 @@ class KVStoreApplication(t.Application):
             struct.pack("<QQ", self.tx_count, self.height)
         ).digest()
         self._save_state()
+        self._flush()
         if self.snapshot_interval > 0 and self.height % self.snapshot_interval == 0:
             self._take_snapshot()
         retain = 0
@@ -332,7 +367,7 @@ class KVStoreApplication(t.Application):
         if req.path == "/val":
             power = self.validators.get(req.data, 0)
             return t.ResponseQuery(code=t.CODE_TYPE_OK, value=struct.pack("<q", power))
-        value = self.db.get(b"kv:" + req.data)
+        value = self._get(b"kv:" + req.data)
         if value is None:
             return t.ResponseQuery(code=t.CODE_TYPE_OK, key=req.data, log="does not exist")
         return t.ResponseQuery(code=t.CODE_TYPE_OK, key=req.data, value=value, log="exists", height=self.height)
