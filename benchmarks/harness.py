@@ -1,5 +1,14 @@
-"""The rig: one process holds the chip and runs the node under test, which
-joins a generated chain by fast sync from source peers in child processes.
+"""The rig: one process holds the chip and runs the process under test.
+
+A traffic file states its `kind` (absent: `replay`).  `run_cell` finds the
+rig by it: `replay` is `run_replay`, below; any other kind is the module
+`benchmarks/rigs/<kind>.py`, whose `run_cell` builds its run from the parts
+here (the chain, the engine's watch and warm-up, the stamps and the window,
+the tampers, the trace, the result's assembly) and returns the same result
+object.  A later PR brings a rig as new files and edits nothing.
+
+`replay`: the node under test joins a generated chain by fast sync from
+source peers in child processes.
 
 Set-up (all of it counted in `setup_s`): generate or load the chain (a
 child process, while this one goes on), start the node with the default
@@ -88,6 +97,8 @@ SAMPLE_SIGNATURES = 40_000
 SAMPLE_COMMITS = (4, 64)
 SAMPLE_WRITES = 32  # replayed writes read back over RPC
 POLL_PERIOD_S = 0.25  # the recorder's ring is read this often through the window
+REPLAY = "replay"  # the kind of a traffic file that states none
+_KIND_NAME = re.compile(r"^[a-z][a-z0-9_]*$")
 
 
 class HarnessFailure(Exception):
@@ -120,6 +131,12 @@ class Cell:
     def rotating(self) -> bool:
         """Whether the configuration's validator set changes along the chain."""
         return bool(chainlib.set_changes(self.config))
+
+    @property
+    def kind(self) -> str:
+        """The traffic's kind, which names the rig: `replay` where the file
+        states none."""
+        return self.traffic.get("kind", REPLAY)
 
 
 def _load_json(path: str) -> dict:
@@ -168,7 +185,9 @@ def load_cell(workload: str, root: str = ROOT) -> Cell:
         raise HarnessFailure(f"BENCHMARK.json has no workload {workload!r}")
     conf = next(c for c in bench["configs"] if c["name"] == entry["config"])
     config = _load_json(os.path.join(root, conf["file"]))
-    traffic = _load_json(os.path.join(BENCH_DIR, "traffic", entry["traffic"] + ".json"))
+    traffic = _load_json(
+        os.path.join(root, os.path.basename(BENCH_DIR), "traffic", entry["traffic"] + ".json")
+    )
 
     def reported(metric: dict) -> bool:
         return "workloads" not in metric or workload in metric["workloads"]
@@ -180,6 +199,25 @@ def load_cell(workload: str, root: str = ROOT) -> Cell:
         name=workload, chips=entry["chips"], config=config, traffic=traffic,
         heights=chain_heights(config, traffic), end_to_end=end_to_end, per_layer=per_layer,
     )
+
+
+def find_rig(kind: str) -> Callable:
+    """The `run_cell` of a traffic kind: `run_replay` here, else that of the
+    module `benchmarks/rigs/<kind>.py`."""
+    if kind == REPLAY:
+        return run_replay
+    module = f"benchmarks.rigs.{kind}"
+    missing = HarnessFailure(
+        f"no rig for traffic kind {kind!r}: there is no benchmarks/rigs/{kind}.py"
+    )
+    if not _KIND_NAME.match(kind):
+        raise missing
+    try:
+        return importlib.import_module(module).run_cell
+    except ModuleNotFoundError as exc:
+        if exc.name != module:  # the rig is there, and what it imports is not
+            raise
+        raise missing from None
 
 
 # ---------------------------------------------------------------------------
@@ -307,11 +345,11 @@ async def until_device(
             "cold_calls": calls, "cold_s": round(cold_s, 1)}
 
 
-async def settle(node, watch: EngineWatch, deadline_s: float) -> None:
-    """Wait for background compiles and table builds to land, so that none
-    lands in the window."""
+async def settle(verifier, cache, watch: EngineWatch, deadline_s: float) -> None:
+    """Wait for the background compiles of `verifier` (the BatchVerifier) and
+    the table builds of `cache` (its TableCache) to land, so that none lands
+    in the window."""
     t0 = time.monotonic()
-    verifier, cache = node.batch_verifier, node.table_cache
     while verifier._compiling_buckets or cache._building:
         if time.monotonic() - t0 > deadline_s:
             raise HarnessFailure(
@@ -408,24 +446,116 @@ def engine_first_bad(chain_id: str, vset, commit) -> Optional[int]:
     return None
 
 
+async def warm_engine(
+    watch: EngineWatch, verifier, cache, chain_id: str, vset, pubs: Sequence[bytes],
+    powers: Sequence[int], warm_commit, stand_in: Optional[str] = None,
+) -> dict:
+    """Warm the engine on the chain's own validator set: `warm_commit`
+    (make_commit) through `ValidatorSet.verify_commit`, each answer held to
+    the reference's, until the recorder shows a table path for a batch of
+    this size, then until the background work of `verifier` and `cache`
+    (None under a stand-in) has landed.  Returns what until_device saw."""
+    n_sigs = sum(not cs.is_absent() for cs in warm_commit.signatures)
+    want, _ = reference.commit_verdict(chain_id, pubs, powers, commit_view(warm_commit))
+
+    async def warm_call() -> None:
+        got = engine_first_bad(chain_id, vset, warm_commit)
+        if got != want:
+            raise HarnessFailure(f"warm-up: engine names validator {got}, reference {want}")
+
+    if n_sigs < watch.min_device_batch:
+        raise HarnessFailure(
+            f"a commit of {n_sigs} signatures rides the host tier "
+            f"(min_device_batch {watch.min_device_batch}): no engine to measure"
+        )
+    if stand_in == "host_tier":
+        await warm_call()
+        return {"path": "host", "bucket": 0, "shards": 1}
+    warm = await until_device(watch, n_sigs, warm_call, WARM_DEADLINE_S)
+    if verifier is not None:
+        await settle(verifier, cache, watch, WARM_DEADLINE_S)
+    return warm
+
+
+def tamper_mismatches(
+    chain_id: str, vset, commit, pubs: Sequence[bytes], powers: Sequence[int],
+    secrets: Sequence[bytes], rng: random.Random,
+) -> int:
+    """How many of five verdicts differ from the reference's: `commit` as
+    it stands (accepted), and its four tampered copies, one bad slot in each
+    quarter of its signatures, through ValidatorSet.verify_commit on `vset`:
+    at the timed size, on the warm table.  `pubs`, `powers` and `secrets` are
+    the reference's set for the commit's height."""
+
+    def verdict(of) -> Optional[int]:
+        """engine_first_bad; -1 where there is no set for the height, or
+        one that refuses the commit before any signature (not its own)."""
+        try:
+            return -1 if vset is None else engine_first_bad(chain_id, vset, of)
+        except ValueError as exc:
+            log(f"the set for height {commit.height} refuses the commit: {exc}")
+            return -1
+
+    signed = [i for i, cs in enumerate(commit.signatures) if not cs.is_absent()]
+    kinds = list(TAMPERS)
+    rng.shuffle(kinds)
+    mismatches = 0
+    if verdict(commit) is not None:
+        mismatches += 1
+    for q, kind in enumerate(kinds):
+        quarter = signed[len(signed) * q // 4: len(signed) * (q + 1) // 4] or signed
+        pos = rng.choice(quarter)
+        bad = tamper(kind, chain_id, commit, secrets, pos)
+        want, _ = reference.commit_verdict(chain_id, pubs, powers, commit_view(bad))
+        got = verdict(bad)
+        if want != pos:
+            raise HarnessFailure(f"the reference misses the {kind} tamper at #{pos} (says {want})")
+        if got != want:
+            log(f"tamper {kind} at #{pos}: engine says {got}, reference {want}")
+            mismatches += 1
+    return mismatches
+
+
+def sample_size(set_size: int) -> int:
+    """How many accepted commits the serial reference re-verifies: as many
+    as hold SAMPLE_SIGNATURES between them, within SAMPLE_COMMITS."""
+    return max(SAMPLE_COMMITS[0], min(SAMPLE_COMMITS[1], SAMPLE_SIGNATURES // set_size))
+
+
 # ---------------------------------------------------------------------------
 # what the window records
 # ---------------------------------------------------------------------------
 
 
-class BlockStamps:
+class Stamps:
+    """When each height became the process under test's own, by the host
+    clock, whoever stamps it: what cut_window reads.  `buffered` is for a rig
+    whose process queues work behind the height stamped; one without leaves
+    it empty."""
+
+    def __init__(self):
+        self.times_ns: List[int] = []  # time.monotonic_ns() at each arrival
+        self.heights: List[int] = []
+        self.buffered: List[int] = []
+
+    def note(self, heights: Sequence[int]) -> None:
+        """These heights arrived now, together."""
+        now = time.monotonic_ns()
+        self.times_ns.extend([now] * len(heights))
+        self.heights.extend(heights)
+
+
+class BlockStamps(Stamps):
     """Stands in for a NewBlock subscription's queue: notes when each block
     reached the subscriber, by the host clock, and keeps nothing else."""
 
     maxsize = 1 << 30  # never "out of capacity": nothing is kept
 
     def __init__(self, queue_depth: Callable[[], int]):
-        self.times_ns: List[int] = []  # time.monotonic_ns() at each arrival
-        self.heights: List[int] = []
+        super().__init__()
         # downloaded blocks waiting behind the one just applied; read here,
         # not on a timer, because the replay loop yields the event loop only
         # when that queue runs dry, and a timer would see nothing else
-        self.buffered: List[int] = []
         self._queue_depth = queue_depth
 
     def qsize(self) -> int:
@@ -510,6 +640,10 @@ class Window:
     # heights at which a new membership signs first and whose table build
     # could land inside the window (cut_window); empty for a static set
     membership_changes: List[int] = dataclasses.field(default_factory=list)
+    # the flight recorder of the process the rig started: the readers of its
+    # ring (reducers/ring_*.py) take it from here, and look for the one alive
+    # in the process only where none was handed
+    recorder: Optional[object] = None
 
     @property
     def blocks(self) -> int:
@@ -521,26 +655,59 @@ class Window:
 # ---------------------------------------------------------------------------
 
 
+# the faults that sit in the engine's place or on its hooks: they need the
+# flight recorder of the process under test and nothing else of it
+ENGINE_FAULTS = (
+    "stub_device", "host_tier", "accept_all", "half_batch", "build_always",
+    "table_never_built", "one_chip",
+)
+
+
 def plant_fault(name: str, node, shards: int = 1) -> None:
     """Break the timed path underneath the harness, to show `correct` come
-    out false.  Never used by a benchmark run.  `stub_device` is the
-    exception that breaks nothing: it stands in for the chip in a CPU test
-    (serial host verification that reports itself as an indexed device
-    dispatch over `shards` chips), so that the rest of a run can be driven
-    without one.  Like the engine it keeps a table per membership: the first
-    commit of one it has not met is a miss that builds that membership's
-    table and is declined to the flat device path, which it stands in for
-    too."""
+    out false.  Never used by a benchmark run.  The faults of the engine are
+    plant_engine_fault's; the others need the node."""
+    if name in ENGINE_FAULTS:
+        plant_engine_fault(name, node.flight_recorder, shards)
+    elif name == "stale_validator_sets":
+        # the state store answers every height with the genesis set, as one
+        # whose records all point back at their first would: the chain goes
+        # on (the live path holds its sets in hand) and no val: write is read back
+        load = node.state_store.load_validators
+        node.state_store.load_validators = lambda height: load(1)
+    elif name == "state_unchanged":
+        # the app acknowledges every transaction and applies none
+        from tendermint_tpu.abci import types as abci
+
+        client = node.blockchain_reactor.block_exec.proxy_app
+
+        async def deliver_tx(req):
+            return abci.ResponseDeliverTx(code=abci.CODE_TYPE_OK)
+
+        client.deliver_tx = deliver_tx
+    else:
+        raise ValueError(f"unknown fault {name!r}")
+
+
+def plant_engine_fault(name: str, recorder, shards: int = 1) -> None:
+    """One of ENGINE_FAULTS, reporting to `recorder` as the engine would.
+    `stub_device` is the exception that breaks nothing: it stands in for the
+    chip in a CPU test (serial host verification that reports itself as an
+    indexed device dispatch over `shards` chips), so that the rest of a run
+    can be driven without one.  Like the engine it keeps a table per
+    membership: the first commit of one it has not met is a miss that builds
+    that membership's table and is declined to the flat device path, which
+    it stands in for too."""
     from tendermint_tpu.crypto import batch as crypto_batch
 
     def dispatched(n: int, path: str, shards: int = shards) -> None:
-        node.flight_recorder.record(
+        recorder.record(
             "verify.dispatch", n=n, bucket=n, path=path, host_prep_ms=0.0, device_ms=0.0,
             shards=shards,
         )
 
     def table_built(set_key: bytes, rows: int, ms: float) -> None:
-        node.flight_recorder.record(
+        recorder.record(
             "verify.table_build", set_key=set_key.hex()[:16], validators=rows, ms=ms, ok=True,
             error=None, shards=shards,
         )
@@ -551,7 +718,7 @@ def plant_fault(name: str, node, shards: int = 1) -> None:
         def stub(set_key, pubkeys, idxs, msgs, sigs):
             rows = pubkeys() if callable(pubkeys) else pubkeys  # verify_commit passes them lazily
             hit = set_key in tables
-            node.flight_recorder.record("verify.table", hit=hit, n=len(sigs))
+            recorder.record("verify.table", hit=hit, n=len(sigs))
             if not hit:  # build the table and decline this commit, as the engine does
                 t0 = time.perf_counter()
                 tables.add(bytes(set_key))
@@ -622,28 +789,20 @@ def plant_fault(name: str, node, shards: int = 1) -> None:
             return indexed(set_key, pubkeys, idxs, msgs, sigs)
 
         crypto_batch.set_indexed_verifier(declining)
-    elif name == "stale_validator_sets":
-        # the state store answers every height with the genesis set, as one
-        # whose records all point back at their first would: the chain goes
-        # on (the live path holds its sets in hand) and no val: write is read back
-        load = node.state_store.load_validators
-        node.state_store.load_validators = lambda height: load(1)
     elif name == "one_chip":
         # the stand-in (planted before this) with the mesh left out: every
         # dispatch answers correctly and reports itself on one chip
         crypto_batch.get_indexed_verifier().shards = 1
-    elif name == "state_unchanged":
-        # the app acknowledges every transaction and applies none
-        from tendermint_tpu.abci import types as abci
-
-        client = node.blockchain_reactor.block_exec.proxy_app
-
-        async def deliver_tx(req):
-            return abci.ResponseDeliverTx(code=abci.CODE_TYPE_OK)
-
-        client.deliver_tx = deliver_tx
     else:
         raise ValueError(f"unknown fault {name!r}")
+
+
+def unplant_faults() -> None:
+    """What plant_fault hooked into the process goes with the run."""
+    from tendermint_tpu.crypto import batch as crypto_batch
+
+    crypto_batch.set_verifier(None)
+    crypto_batch.set_indexed_verifier(None)
 
 
 STAND_INS = ("stub_device", "host_tier")  # planted before warm-up: they replace the engine
@@ -854,43 +1013,62 @@ async def dial_and_warm_in(node, addrs: Sequence[str], stamps: "BlockStamps", ce
     return held, redials
 
 
-def window_rules(
-    cell: Cell, window: "Window", min_device_batch: int, compiles: int, still_syncing: bool,
-    last_height: int, chain_heights_: int,
+def engine_rules(
+    cell: Cell, window: "Window", min_device_batch: int, compiles: int, dispatches_due: int,
 ) -> Dict[str, int]:
-    """The counts a run must keep at 0 for its window to be the one the cell
-    describes (see window_failures for the first three)."""
+    """What every rig holds a window's engine to (see window_failures for the
+    first three): no compile inside it, and `dispatches_due` dispatches on a
+    table path, the rig saying how many its traffic owes (one a block
+    applied, one a header stored)."""
     rotating = cell.rotating
     checks = window_failures(
         window.events, min_device_batch, len(window.membership_changes) if rotating else None
     )
     checks["compiles_in_window"] = compiles
-    table_dispatches = [
-        ev for ev in window.events
-        if ev["kind"] == "verify.dispatch" and ev["path"] in TABLE_PATHS
-    ]
     # a new membership's commits ride the flat device path while its table builds
-    served = table_dispatches if not rotating else [
+    served = table_dispatches(window) if not rotating else [
         ev for ev in window.events
         if ev["kind"] == "verify.dispatch" and ev["path"] in DEVICE_PATHS
     ]
-    checks["blocks_without_device_dispatch"] = max(0, window.blocks - len(served))
+    checks["blocks_without_device_dispatch"] = max(0, dispatches_due - len(served))
+    return checks
+
+
+def table_dispatches(window: "Window") -> List[dict]:
+    return [
+        ev for ev in window.events
+        if ev["kind"] == "verify.dispatch" and ev["path"] in TABLE_PATHS
+    ]
+
+
+def unsharded_dispatches(cell: Cell, window: "Window") -> int:
+    """What exists only across chips: every table dispatch on all of them."""
+    return sum(1 for ev in table_dispatches(window) if ev["shards"] != cell.chips)
+
+
+def window_rules(
+    cell: Cell, window: "Window", min_device_batch: int, compiles: int, still_syncing: bool,
+    last_height: int, chain_heights_: int,
+) -> Dict[str, int]:
+    """The counts a replay run must keep at 0 for its window to be the one
+    the cell describes: engine_rules with a dispatch due for every block, and
+    the node still in fast sync and short of its sources' tip."""
+    checks = engine_rules(cell, window, min_device_batch, compiles, window.blocks)
     tip_margin = 2 * REQUESTS_PER_PEER * cell.config["source_peers"]
     checks["left_fast_sync"] = int(not still_syncing)
     checks["chain_exhausted"] = int(last_height > chain_heights_ - tip_margin)
     checks["window_empty"] = int(window.blocks == 0)
-    if cell.chips > 1:  # what exists only across chips: every dispatch on all of them
-        checks["unsharded_dispatches"] = sum(
-            1 for ev in table_dispatches if ev["shards"] != cell.chips
-        )
+    if cell.chips > 1:
+        checks["unsharded_dispatches"] = unsharded_dispatches(cell, window)
     return checks
 
 
 def cut_window(
-    cell: Cell, stamps: "BlockStamps", first: int, seconds: float, events: Sequence[dict],
-    deliver_spans: Sequence[tuple], membership_heights: Sequence[int] = (),
+    cell: Cell, stamps: "Stamps", first: int, seconds: float, events: Sequence[dict],
+    deliver_spans: Sequence[tuple], membership_heights: Sequence[int] = (), recorder=None,
 ) -> "Window":
-    """The window, read off the stamps: from arrival `first` to the last
+    """The window, read off the stamps (heights and the times they arrived,
+    whoever made them): from arrival `first` to the last
     arrival within `seconds` of it, with the events and spans inside, and
     those of the chain's `membership_heights` that it met: the new
     memberships whose table build could land inside it.  A membership that
@@ -915,8 +1093,8 @@ def cut_window(
         block_heights=[stamps.heights[i] for i in inside],
         events=[ev for ev in events if t_open_ns < ev["t_ns"] <= t_close_ns],
         deliver_spans=[s for s in deliver_spans if t_open_ns <= s[1] and s[2] <= t_close_ns],
-        buffered=[stamps.buffered[i] for i in inside],
-        device_kind=jax.devices()[0].device_kind,
+        buffered=[stamps.buffered[i] for i in inside] if stamps.buffered else [],
+        device_kind=jax.devices()[0].device_kind, recorder=recorder,
         membership_changes=[
             h for h in membership_heights
             if inside and stamps.heights[inside[0]] - 2 <= h <= stamps.heights[inside[-1]] + 1
@@ -926,13 +1104,112 @@ def cut_window(
 
 async def run_cell(
     cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
-    faults: Sequence[str] = (), configure=None,
+    faults: Sequence[str] = (), **for_tests,
 ) -> dict:
-    """One run of one cell; returns the result object (see run.py).
-    `faults` (see plant_fault) and `configure` are for the tests and the
-    control."""
+    """One run of one cell, by the rig its traffic's kind names; returns the
+    result object (see run.py).  `faults` and what a rig takes besides
+    (run_replay: `configure`) are for the tests and the control."""
+    return await find_rig(cell.kind)(cell, seed, seconds, trace, t_start, faults, **for_tests)
+
+
+def open_trace(cell: Cell, seed: int) -> tuple:
+    """Start the profiler; (the trace's directory, the anchor close_trace
+    needs).  `benchmarks.trace.stop()` ends it."""
+    from benchmarks import trace as tracelib
+
+    trace_dir = os.path.join(OUT_DIR, f"trace.{cell.name}.s{seed}")
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    return trace_dir, tracelib.start(trace_dir)
+
+
+def close_trace(cell: Cell, trace_dir: str, anchor_ns: int, window: Window):
+    """Reduce a stopped trace to the window (None on a CPU), hand it to the
+    window's readers, and delete the trace."""
+    from benchmarks import trace as tracelib
+
+    summary = tracelib.summarize(
+        trace_dir, anchor_ns, window.t_open_ns, window.t_close_ns,
+        names_out=os.path.join(OUT_DIR, f"trace_names.{cell.name}.json"),
+    )
+    window.trace = summary
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    return summary
+
+
+def memory_peak() -> int:
+    """Peak bytes in use on the fullest chip, as JAX reports it."""
     import jax
 
+    return max(
+        ((d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in jax.devices()),
+        default=0,
+    )
+
+
+def result_object(
+    cell: Cell, window: Window, checks: Dict[str, int], setup_s: float, trace: bool,
+    summary, peak: int, context: dict,
+) -> dict:
+    """The result of a run that reached its window's end, with the keys in
+    the order run.py prints them; `context` is the rig's own, to which what
+    every rig can say of a traced window is added."""
+    import jax
+
+    correct = all(v == 0 for v in checks.values())
+    log(f"compared with the reference: correct={correct}")
+    result = {
+        "correct": correct,
+        "attempted": window.blocks,
+        "failed": 0 if correct else max(1, sum(checks.values())),
+        "metrics": read_metrics(cell, window, setup_s, trace),
+        "device": {
+            "platform": jax.devices()[0].platform, "kind": window.device_kind,
+            "count": jax.device_count(), "memory_peak_bytes": peak,
+        },
+    }
+    if summary is not None:
+        result["device"]["busy_s"] = summary.busy_s
+        result["device"]["window_s"] = summary.window_s
+        result["breakdown"] = summary.breakdown(window)
+    result["context"] = context
+    if summary is not None and summary.kernel_intervals:
+        from benchmarks import trace as tracelib
+
+        # the two clocks agree where the kernels ran inside the engine's calls
+        calls = tracelib.host_activity(window)[tracelib.ENGINE_CALL]
+        inside = sum(tracelib.overlap(iv, calls) for iv in summary.kernel_intervals)
+        context["kernel_time_inside_engine_calls_share"] = (
+            inside / tracelib.total(summary.kernel_intervals)
+        )
+        context["busy_s_by_device"] = summary.busy_s_by_device
+        context["kernel_seconds"] = summary.kernel_seconds
+    result["checks"] = {k: {"value": v, "limit": 0} for k, v in checks.items()}
+    return result
+
+
+def release_run(faults: Sequence[str], *chain_procs) -> None:
+    """What every rig's run leaves as it ends, whatever became of it: no
+    generator running, no fault hooked into the process, no spec file, a
+    chain cache within its size."""
+    for proc in chain_procs:
+        if proc is not None and proc.poll() is None:
+            proc.kill()
+            proc.wait()
+    if faults:
+        unplant_faults()
+    for path in glob.glob(os.path.join(OUT_DIR, "*.spec.*.json")):
+        os.remove(path)
+    if os.path.isdir(CACHE_DIR):
+        chainlib.prune_cache(CACHE_DIR, CACHE_KEEP_BYTES)
+
+
+async def run_replay(
+    cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
+    faults: Sequence[str] = (), configure=None,
+) -> dict:
+    """The rig of kind `replay`: a node joins the chain by fast sync.
+    `faults` (see plant_fault) and `configure` are for the tests and the
+    control."""
     from tendermint_tpu import ops  # noqa: F401 — places the compile cache
     from tendermint_tpu.node import Node
     from tendermint_tpu.rpc.client import HTTPClient
@@ -978,26 +1255,10 @@ async def run_cell(
         if [v.pub_key.bytes() for v in vset.validators] != pubs:
             raise HarnessFailure("the node's validator set is not the generated committee")
         warm_commit = make_commit(chain_id, vset, secrets, seed, cell.config["absent_share"])
-        n_sigs = sum(not cs.is_absent() for cs in warm_commit.signatures)
-        want, _ = reference.commit_verdict(chain_id, pubs, powers, commit_view(warm_commit))
-
-        async def warm_call() -> None:
-            got = engine_first_bad(chain_id, vset, warm_commit)
-            if got != want:
-                raise HarnessFailure(f"warm-up: engine names validator {got}, reference {want}")
-
-        if n_sigs < min_device_batch:
-            raise HarnessFailure(
-                f"a commit of {n_sigs} signatures rides the host tier "
-                f"(min_device_batch {min_device_batch}): no engine to measure"
-            )
-        if stand_in == "host_tier":
-            await warm_call()
-            warm = {"path": "host", "bucket": 0, "shards": 1}
-        else:
-            warm = await until_device(watch, n_sigs, warm_call, WARM_DEADLINE_S)
-            if node.batch_verifier is not None:
-                await settle(node, watch, WARM_DEADLINE_S)
+        warm = await warm_engine(
+            watch, node.batch_verifier, node.table_cache, chain_id, vset, pubs, powers,
+            warm_commit, stand_in,
+        )
         deliver = DeliverSpans(node.blockchain_reactor.block_exec.proxy_app) if trace else None
 
         # -- sources, warm-in -----------------------------------------------
@@ -1019,12 +1280,10 @@ async def run_cell(
         watch.poll()
 
         # -- the window -----------------------------------------------------
-        trace_dir = os.path.join(OUT_DIR, f"trace.{cell.name}.s{seed}")
         if trace:
-            shutil.rmtree(trace_dir, ignore_errors=True)
             from benchmarks import trace as tracelib
 
-            trace_anchor_ns = tracelib.start(trace_dir)
+            trace_dir, trace_anchor_ns = open_trace(cell, seed)
             tracing = True
         for fault in faults:
             if fault not in STAND_INS:
@@ -1056,10 +1315,7 @@ async def run_cell(
         if trace:
             tracelib.stop()
             tracing = False
-        peak = max(
-            ((d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in jax.devices()),
-            default=0,
-        )
+        peak = memory_peak()
         still_syncing = node.blockchain_reactor.fast_sync
         peers_lost = connections(node) != held
         sources.stop()
@@ -1074,19 +1330,14 @@ async def run_cell(
         window = cut_window(
             cell, stamps, n_blocks_before, seconds, watch.events[n_events_before:],
             deliver.spans if deliver else [], history.membership_heights,
+            recorder=node.flight_recorder,
         )
-        t_open_ns, t_close_ns = window.t_open_ns, window.t_close_ns
-        setup_s = t_open_ns / 1e9 - t_start
+        setup_s = window.t_open_ns / 1e9 - t_start
         log(f"set-up took {setup_s:.1f} s")
         log(f"window closed: {window.blocks} blocks in {window.seconds:.2f} s, "
             f"heights {window.block_heights[:1]}..{window.block_heights[-1:]} of {meta['heights']}")
         if trace:
-            summary = tracelib.summarize(
-                trace_dir, trace_anchor_ns, t_open_ns, t_close_ns,
-                names_out=os.path.join(OUT_DIR, f"trace_names.{cell.name}.json"),
-            )
-            window.trace = summary
-            shutil.rmtree(trace_dir, ignore_errors=True)
+            summary = close_trace(cell, trace_dir, trace_anchor_ns, window)
 
         # -- correct? -------------------------------------------------------
         checks = window_rules(
@@ -1101,25 +1352,7 @@ async def run_cell(
             ))
         problems = engine_failures(watch.events, watch.min_device_batch, warm=False)
         checks["engine_errors"] = max(checks["engine_errors"], len(problems))
-        correct = all(v == 0 for v in checks.values())
-        log(f"compared with the reference: correct={correct}")
-
-        metrics = read_metrics(cell, window, setup_s, trace)
-        result = {
-            "correct": correct,
-            "attempted": window.blocks,
-            "failed": 0 if correct else max(1, sum(checks.values())),
-            "metrics": metrics,
-            "device": {
-                "platform": jax.devices()[0].platform, "kind": window.device_kind,
-                "count": jax.device_count(), "memory_peak_bytes": peak,
-            },
-        }
-        if summary is not None:
-            result["device"]["busy_s"] = summary.busy_s
-            result["device"]["window_s"] = summary.window_s
-            result["breakdown"] = summary.breakdown(window)
-        result["context"] = {
+        return result_object(cell, window, checks, setup_s, trace, summary, peak, {
             "seed": seed, "warm": warm, "compile_s": round(clock.seconds, 2),
             "persistent_cache_hits": clock.cache_hits, "chain_generate_s": meta["generate_s"],
             "rtt_probe": getattr(node.batch_verifier, "rtt_probe", None),
@@ -1136,26 +1369,12 @@ async def run_cell(
                 f"p{q}": percentile(block_intervals_ms(window), q)
                 for q in (50, 75, 90, 95, 97.5, 99, 100)
             } if window.blocks else None,
-        }
-        if summary is not None and summary.kernel_intervals:
-            # the two clocks agree where the kernels ran inside the engine's calls
-            calls = tracelib.host_activity(window)[tracelib.ENGINE_CALL]
-            inside = sum(tracelib.overlap(iv, calls) for iv in summary.kernel_intervals)
-            result["context"]["kernel_time_inside_engine_calls_share"] = (
-                inside / tracelib.total(summary.kernel_intervals)
-            )
-            result["context"]["busy_s_by_device"] = summary.busy_s_by_device
-            result["context"]["kernel_seconds"] = summary.kernel_seconds
-        result["checks"] = {k: {"value": v, "limit": 0} for k, v in checks.items()}
-        return result
+        })
     finally:
         if tracing:
             from benchmarks import trace as tracelib
 
             tracelib.stop()
-        if chain_proc is not None and chain_proc.poll() is None:
-            chain_proc.kill()
-            chain_proc.wait()
         sources.stop()
         if node is not None and node.is_running:
             # the run has its result or its failure by now: a node that
@@ -1165,16 +1384,8 @@ async def run_cell(
                 await asyncio.wait_for(node.stop(), NODE_STOP_DEADLINE_S)
             except Exception as exc:
                 log(f"the node did not stop cleanly: {exc!r}")
-        if faults:  # what plant_fault hooked into the process goes with the run
-            from tendermint_tpu.crypto import batch as crypto_batch
-
-            crypto_batch.set_verifier(None)
-            crypto_batch.set_indexed_verifier(None)
         shutil.rmtree(home, ignore_errors=True)
-        for path in glob.glob(os.path.join(OUT_DIR, "*.spec.*.json")):
-            os.remove(path)
-        if os.path.isdir(CACHE_DIR):
-            chainlib.prune_cache(CACHE_DIR, CACHE_KEEP_BYTES)
+        release_run(faults, chain_proc)
 
 
 def accepted_wrongly(
@@ -1229,9 +1440,7 @@ async def compare_with_reference(
     cell = window.cell
     rotating = cell.rotating
     heights = window.block_heights
-    n_commits = max(
-        SAMPLE_COMMITS[0], min(SAMPLE_COMMITS[1], SAMPLE_SIGNATURES // len(history.at(1)[0]))
-    )
+    n_commits = sample_size(len(history.at(1)[0]))
     sample = sorted(set(rng.sample(heights, min(n_commits - 1, len(heights))) + [heights[-1]]))
 
     status = (await client.status())["sync_info"]
@@ -1275,32 +1484,7 @@ async def compare_with_reference(
     if rotating:
         vset = node.state_store.load_validators(commit.height)
 
-    def verdict(of) -> Optional[int]:
-        """engine_first_bad; -1 where the node holds no set for the height,
-        or one that refuses the commit before any signature (not its own)."""
-        try:
-            return -1 if vset is None else engine_first_bad(chain_id, vset, of)
-        except ValueError as exc:
-            log(f"the node's set for height {commit.height} refuses the commit: {exc}")
-            return -1
-
-    signed = [i for i, cs in enumerate(commit.signatures) if not cs.is_absent()]
-    kinds = list(TAMPERS)
-    rng.shuffle(kinds)
-    verdict_mismatches = 0
-    if verdict(commit) is not None:
-        verdict_mismatches += 1
-    for q, kind in enumerate(kinds):
-        quarter = signed[len(signed) * q // 4: len(signed) * (q + 1) // 4] or signed
-        pos = rng.choice(quarter)
-        bad = tamper(kind, chain_id, commit, secrets, pos)
-        want, _ = reference.commit_verdict(chain_id, pubs, powers, commit_view(bad))
-        got = verdict(bad)
-        if want != pos:
-            raise HarnessFailure(f"the reference misses the {kind} tamper at #{pos} (says {want})")
-        if got != want:
-            log(f"tamper {kind} at #{pos}: engine says {got}, reference {want}")
-            verdict_mismatches += 1
+    verdict_mismatches = tamper_mismatches(chain_id, vset, commit, pubs, powers, secrets, rng)
     checks = {
         "wrong_block_ids": wrong_blocks, "commits_accepted_wrongly": wrong_commits,
         "wrong_reads": wrong_reads, "verdict_mismatches": verdict_mismatches,

@@ -4,7 +4,9 @@
     python3 benchmarks/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 Refuses to run without a TPU, or with fewer chips than the cell asks for
-(exit 2, nothing on stdout).  Outside a checkout of the program it exits 3.
+(exit 2, nothing on stdout).  Outside a checkout of the program, for a
+workload BENCHMARK.json does not have, and for a traffic file whose `kind`
+has no rig under benchmarks/rigs/, it exits 3.
 Every run that got as far as the chip prints a result line and exits 0: one
 whose answers were wrong, or that broke on the way, says `"correct": false`
 there, with the numbers that say why, where the driver's record keeps them.
@@ -73,6 +75,7 @@ def main(argv=None) -> int:
 
     try:
         cell = harness.load_cell(args.workload)
+        harness.find_rig(cell.kind)  # an unknown traffic kind is refused here, by name
     except (harness.HarnessFailure, OSError, KeyError, StopIteration) as exc:
         print(f"run.py: {exc!r}", file=sys.stderr)
         return 3

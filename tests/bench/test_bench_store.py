@@ -328,19 +328,29 @@ async def drive(backend):
     )
 
 
+def the_runs_recorder():
+    """The flight recorder of the node the run just stopped: the youngest
+    alive (an earlier file's node may still be, on the same worker)."""
+    from tendermint_tpu.libs import tracing
+
+    return max(tracing.live_recorders(), key=lambda r: r.anchor_mono_ns)
+
+
 async def test_a_run_on_sqlite_is_correct_and_reads_the_stores_metrics(scratch):
     result = await drive("sqlite")
     assert result["correct"] is True, result["checks"]
     got = {k: v["value"] for k, v in result["metrics"].items()}
     assert set(NEW_METRICS) <= set(got)
-    assert got["db_txns_per_block"] >= 4  # a transaction for every DeliverTx at the least
+    # five a block since PR 32 (the app's in one at Commit, the block store 1, the
+    # state store 3); a live index adds its own
+    assert got["db_txns_per_block"] >= 4
     assert 0 < got["db_write_ms_per_block"] < got["block_interval_p50_ms"] * 3
     assert 0 <= got["tx_indexed_share"] <= 200  # 4 txs a block: the index keeps up, more or less
     assert got["block_interval_p95_ms.disk"] == got["block_interval_p95_ms.replay"]
     # the identities, block by block, off the node's own ring
     from tendermint_tpu.libs import tracing
 
-    (recorder,) = tracing.live_recorders()
+    recorder = the_runs_recorder()
     blocks = [ev for ev in recorder.events() if ev["kind"] == "fastsync.block"]
     assert len(blocks) > 20
     for ev in blocks[1:]:
@@ -359,7 +369,7 @@ async def test_a_run_on_memdb_reads_nothing_of_the_store(scratch):
     assert "db_write_ms_per_block" not in got and "db_txns_per_block" not in got
     from tendermint_tpu.libs import tracing
 
-    (recorder,) = tracing.live_recorders()
+    recorder = the_runs_recorder()
     blocks = [ev for ev in recorder.events() if ev["kind"] == "fastsync.block"]
     assert blocks and not any(k.startswith("db_") for ev in blocks for k in ev)
     # the indexer runs on memdb too, and its lag is the one store field there
