@@ -67,5 +67,37 @@ def dumps(obj: Any) -> bytes:
     return msgpack.packb(obj, default=_default, use_bin_type=True)
 
 
+class Packed:
+    """A value already encoded by `dumps`, in pieces: `packed_map` splices
+    them in as they are, not copied into one bytes object first."""
+
+    __slots__ = ("parts",)
+
+    def __init__(self, *parts: bytes):
+        self.parts = parts
+
+    def __bytes__(self) -> bytes:
+        return b"".join(self.parts)
+
+
+def packed_map(fields: Dict[str, Any]) -> Packed:
+    """`dumps(fields)` where a `Packed` value goes in as it is instead of
+    being encoded again: a msgpack map is its header, then each key and
+    value in order."""
+    parts = [msgpack.Packer().pack_map_header(len(fields))]
+    for key, value in fields.items():
+        parts.append(dumps(key))
+        if isinstance(value, Packed):
+            parts.extend(value.parts)
+        else:
+            parts.append(dumps(value))
+    return Packed(*parts)
+
+
+def dumps_map(fields: Dict[str, Any]) -> bytes:
+    """`dumps(fields)`, byte for byte, `Packed` values spliced in."""
+    return bytes(packed_map(fields))
+
+
 def loads(data: bytes) -> Any:
     return msgpack.unpackb(data, object_hook=_object_hook, raw=False, strict_map_key=False)

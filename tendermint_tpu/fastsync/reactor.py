@@ -325,7 +325,10 @@ class BlockchainReactor(Reactor):
                 span.set(wait_ms=(span.t0_ns - self._block_done_ns) / 1e6)
             if received:
                 span.set(queued_ms=(span.t0_ns - received.pop("received_ns")) / 1e6, **received)
-            first_id = BlockID(first.hash(), first.make_part_set(BLOCK_PART_SIZE_BYTES).header())
+            # one part set a block: its header is the block id's, and the store
+            # writes its parts and takes the block's size from it
+            parts = first.make_part_set(BLOCK_PART_SIZE_BYTES)
+            first_id = BlockID(first.hash(), parts.header())
             span.lap("parts_ms")
             try:
                 # verify first with second's LastCommit (batched over V sigs)
@@ -347,9 +350,7 @@ class BlockchainReactor(Reactor):
                 return
             span.lap("verify_ms")
             try:
-                self.block_store.save_block(
-                    first, first.make_part_set(BLOCK_PART_SIZE_BYTES), second.last_commit
-                )
+                self.block_store.save_block(first, parts, second.last_commit)
                 span.lap("store_ms")
                 self.state, _ = await self.block_exec.apply_block(self.state, first_id, first)
                 span.lap("apply_ms")

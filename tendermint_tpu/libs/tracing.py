@@ -58,10 +58,18 @@ Event kinds currently emitted:
   fast sync (fastsync/reactor.py, state/execution.py, state/validation.py):
     fastsync.block    SPAN, one per block applied, id = height: peek_two to
                       block_processed.  In-span stages parts_ms, verify_ms,
-                      store_ms, apply_ms (sum <= dur_ns); from apply_block
-                      validate_ms, abci_req_ms (BeginBlock's request built),
-                      deliver_ms (BeginBlock's call to Commit's return),
-                      mempool_ms, save_state_ms, events_ms; inside
+                      store_ms, apply_ms (sum <= dur_ns); inside store_ms,
+                      from BlockStore.save_block, commit_encodes (how many
+                      of C:H-1 and SC:H were encoded for it, 0-2: a commit
+                      keeps its sealed record, so fast sync's LastCommit,
+                      saved as the seen commit a block before, is not); from
+                      apply_block validate_ms, abci_req_ms (BeginBlock's
+                      request built), deliver_ms (BeginBlock's call to
+                      Commit's return), mempool_ms, save_state_ms, events_ms;
+                      inside save_state_ms, from StateStore.save, set_encodes
+                      (how many of the state's three validator sets were
+                      encoded for it, 0-3: a set keeps its encoding until it
+                      changes, so 1 a block on a static set); inside
                       validate_ms, from validate_block, basic_ms (the whole
                       of Block.validate_basic) and commit_hashes (whether
                       the LastCommit's Merkle root was built in it, 0 or 1:
@@ -710,15 +718,17 @@ def format_budget(budget: Optional[dict]) -> str:
 #: The rows of the replay budget, outermost first: what tiles a block's
 #: interval (the wait since the block before, then the in-span stages),
 #: apply_block's stages inside apply_ms, validate_block's inside validate_ms
-#: (commit_hashes and set_hashes are counts of roots built, not milliseconds), the
-#: two commit verifications inside verify_ms and validate_ms, the engine's
-#: calls inside those, and what the receive path measured before the block
-#: was queued.
+#: (commit_hashes and set_hashes are counts of roots built, not milliseconds),
+#: the store's counts of encodings made (commit_encodes inside store_ms,
+#: set_encodes inside save_state_ms), the two commit verifications inside
+#: verify_ms and validate_ms, the engine's calls inside those, and what the
+#: receive path measured before the block was queued.
 REPLAY_ROWS = (
     ("fastsync.block", ("wait_ms", "parts_ms", "verify_ms", "store_ms", "apply_ms")),
     ("fastsync.block", ("validate_ms", "abci_req_ms", "deliver_ms", "mempool_ms",
                         "save_state_ms", "events_ms")),
     ("fastsync.block", ("basic_ms", "commit_hashes", "set_hash_ms", "set_hashes", "median_ms")),
+    ("fastsync.block", ("commit_encodes", "set_encodes")),
     ("verify.commit", ("sign_bytes_ms", "engine_ms", "tally_ms")),
     ("verify.dispatch", ("rows_ms", "host_prep_ms", "pack_ms", "launch_ms", "put_ms",
                          "fetch_ms")),

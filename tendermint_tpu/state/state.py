@@ -60,7 +60,12 @@ class State:
         return self.validators is None
 
     def bytes(self) -> bytes:
-        return codec.dumps(self)
+        """`codec.dumps(self)`, byte for byte, each validator set spliced in
+        as it keeps its encoding (`ValidatorSet.packed`): a set promoted
+        unchanged from the state before is not encoded again."""
+        fields = self._fields(ValidatorSet.packed)
+        fields["@t"] = codec.tag_for(State)
+        return codec.dumps_map(fields)
 
     def equals(self, other: "State") -> bool:
         return self.bytes() == other.bytes()
@@ -102,6 +107,10 @@ class State:
         return block
 
     def to_dict(self) -> dict:
+        return self._fields(ValidatorSet.to_dict)
+
+    def _fields(self, encode_set) -> dict:
+        """`to_dict()` with each non-empty validator set as `encode_set` gives it."""
         return {
             "chain_id": self.chain_id,
             "version_block": self.version_block,
@@ -110,9 +119,9 @@ class State:
             "last_block_height": self.last_block_height,
             "last_block_id": self.last_block_id.to_dict(),
             "last_block_time_ns": self.last_block_time_ns,
-            "next_validators": self.next_validators.to_dict() if self.next_validators else None,
-            "validators": self.validators.to_dict() if self.validators else None,
-            "last_validators": self.last_validators.to_dict() if self.last_validators else None,
+            "next_validators": encode_set(self.next_validators) if self.next_validators else None,
+            "validators": encode_set(self.validators) if self.validators else None,
+            "last_validators": encode_set(self.last_validators) if self.last_validators else None,
             "last_height_validators_changed": self.last_height_validators_changed,
             "consensus_params": self.consensus_params.to_dict(),
             "last_height_consensus_params_changed": self.last_height_consensus_params_changed,

@@ -11,6 +11,7 @@ from __future__ import annotations
 from typing import List, Optional
 
 from ..encoding import codec
+from ..libs import tracing
 from ..libs.kvstore import KVStore
 from ..types import ConsensusParams, GenesisDoc, ValidatorSet
 from .state import State, make_genesis_state
@@ -45,8 +46,15 @@ class StateStore:
         state key land together or not at all — a crash (or injected
         ENOSPC) between separate sets used to leave the validator records
         for height H+2 on disk with the state key still at H-1, a
-        half-applied save the handshake then reads as truth."""
+        half-applied save the handshake then reads as truth.
+
+        A set keeps its encoding until it changes (`ValidatorSet.packed`):
+        `set_encodes`, on whichever span is open, says how many of the
+        state's three were encoded for this save (1 a block while the
+        membership stands: the rotated `next_validators`)."""
         next_height = state.last_block_height + 1
+        vsets = (state.next_validators, state.validators, state.last_validators)
+        tracing.annotate(set_encodes=sum(1 for vs in vsets if vs and vs._packed is None))
         sets = []
         if next_height == 1:
             # genesis bootstrap: heights 1 and 2 both known at this point
@@ -102,11 +110,11 @@ class StateStore:
         self, sets: list, height: int, last_changed: int, vals: ValidatorSet
     ) -> None:
         if height == last_changed or height % self.VALSET_CHECKPOINT_INTERVAL == 0:
-            payload = {"last_changed": last_changed, "validators": vals.to_dict()}
+            validators = vals.packed()
         else:
-            # pointer record only — the full set lives at last_changed
-            payload = {"last_changed": last_changed, "validators": None}
-        sets.append((_k_validators(height), codec.dumps(payload)))
+            validators = None  # pointer record only — the full set lives at last_changed
+        payload = {"last_changed": last_changed, "validators": validators}
+        sets.append((_k_validators(height), codec.dumps_map(payload)))
 
     def load_validators(self, height: int) -> Optional[ValidatorSet]:
         """LoadValidators (state/store.go:295): follow the pointer to the

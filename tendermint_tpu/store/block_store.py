@@ -29,6 +29,7 @@ from dataclasses import dataclass
 from typing import List, Optional
 
 from ..encoding import codec
+from ..libs import tracing
 from ..libs.kvstore import KVStore
 from ..types import Block, BlockID, Commit, Header
 from ..types.part_set import Part, PartSet
@@ -65,6 +66,15 @@ _SEAL = struct.Struct(">I")
 
 def seal(payload: bytes) -> bytes:
     return _SEAL_MAGIC + _SEAL.pack(zlib.crc32(payload) & 0xFFFFFFFF) + payload
+
+
+def commit_record(commit) -> bytes:
+    """`seal(codec.dumps(commit))`, kept on the commit: fast sync writes a
+    commit as SC:H-1, then the same object, block H's LastCommit, as C:H-1
+    one block later."""
+    if commit._record is None:
+        commit._record = seal(codec.dumps(commit))
+    return commit._record
 
 
 def unseal(value: Optional[bytes]):
@@ -202,7 +212,12 @@ class BlockStore:
     # -- saving ------------------------------------------------------------
     def save_block(self, block: Block, part_set: PartSet, seen_commit: Commit) -> None:
         """store/store.go:270 — meta + parts + canonical last-commit of the
-        previous block + our seen-commit for this block."""
+        previous block + our seen-commit for this block.  The block is not
+        serialized here: its size is the part set's, which was built from
+        (fast sync, a proposer) or decodes to (consensus) its bytes.  A
+        commit keeps its record (`commit_record`): `commit_encodes`, on
+        whichever span is open, says how many of the two were encoded for
+        this save (1 a block in fast sync)."""
         if block is None:
             raise ValueError("cannot save nil block")
         height = block.height
@@ -214,21 +229,25 @@ class BlockStore:
                 raise ValueError("cannot save block with incomplete part set")
 
             block_id = BlockID(block.hash(), part_set.header())
-            meta = BlockMeta(block_id, len(block.serialize()), block.header, len(block.txs))
+            meta = BlockMeta(block_id, part_set.byte_size(), block.header, len(block.txs))
             sets = [
                 (_k_meta(height), seal(codec.dumps(meta))),
                 (_k_block_hash(block.hash()), seal(b"%d" % height)),
             ]
             for i in range(part_set.total):
                 sets.append((_k_part(height, i), seal(codec.dumps(part_set.get_part(i)))))
+            encodes = 0
             if block.last_commit is not None:
-                sets.append((_k_commit(height - 1), seal(codec.dumps(block.last_commit))))
-            sets.append((_k_seen_commit(height), seal(codec.dumps(seen_commit))))
+                encodes += block.last_commit._record is None
+                sets.append((_k_commit(height - 1), commit_record(block.last_commit)))
+            encodes += seen_commit._record is None
+            sets.append((_k_seen_commit(height), commit_record(seen_commit)))
             self.db.write_batch(sets)
             if self._base == 0:
                 self._base = height
             self._height = height
             self._save_state()
+        tracing.annotate(commit_encodes=encodes)
 
     def bootstrap_light_block(self, header: Header, block_id: BlockID, seen_commit: Commit) -> None:
         """Statesync bootstrap (store/store.go SaveSeenCommit flavor):
@@ -247,8 +266,8 @@ class BlockStore:
             self.db.write_batch([
                 (_k_meta(height), seal(codec.dumps(meta))),
                 (_k_block_hash(block_id.hash), seal(b"%d" % height)),
-                (_k_commit(height), seal(codec.dumps(seen_commit))),
-                (_k_seen_commit(height), seal(codec.dumps(seen_commit))),
+                (_k_commit(height), commit_record(seen_commit)),
+                (_k_seen_commit(height), commit_record(seen_commit)),
             ])
             self._base = height
             self._height = height
@@ -409,7 +428,7 @@ class BlockStore:
             )
         part_set = block.make_part_set(BLOCK_PART_SIZE_BYTES)
         block_id = BlockID(block.hash(), part_set.header())
-        meta = BlockMeta(block_id, len(block.serialize()), block.header, len(block.txs))
+        meta = BlockMeta(block_id, part_set.byte_size(), block.header, len(block.txs))
         with self._mtx:
             sets = [
                 (_k_meta(height), seal(codec.dumps(meta))),

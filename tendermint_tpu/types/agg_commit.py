@@ -75,6 +75,7 @@ class AggregateCommit:
         self.timestamp_ns = timestamp_ns
         self._hash: Optional[bytes] = None
         self._sigs_view: Optional[List[CommitSig]] = None
+        self._record: Optional[bytes] = None  # the block store's (store/block_store.py)
 
     # -- Commit surface ----------------------------------------------------
     def size(self) -> int:

@@ -359,6 +359,7 @@ class Commit:
         self.signatures = signatures
         self._hash: Optional[bytes] = None
         self._bit_array: Optional[BitArray] = None
+        self._record: Optional[bytes] = None  # the block store's (store/block_store.py)
 
     def size(self) -> int:
         return len(self.signatures)

@@ -107,6 +107,10 @@ class PartSet:
     def is_complete(self) -> bool:
         return self.count == self.total
 
+    def byte_size(self) -> int:
+        """Length of the bytes a complete set was built from and assembles."""
+        return sum(len(p.bytes) for p in self.parts)
+
     def assemble(self) -> bytes:
         if not self.is_complete():
             raise PartSetError("cannot assemble incomplete PartSet")

@@ -173,6 +173,7 @@ class ValidatorSet:
         self._total_voting_power = 0
         self._pk_digest: Optional[bytes] = None
         self._root: Optional[bytes] = None
+        self._packed: Optional[bytes] = None
         if validators:
             self._update_with_change_set(validators, allow_deletes=False)
             self.increment_proposer_priority(1)
@@ -254,6 +255,7 @@ class ValidatorSet:
         new._total_voting_power = self._total_voting_power
         new._pk_digest = self._pk_digest
         new._root = self._root
+        new._packed = self._packed
         return new
 
     def hash(self) -> bytes:
@@ -299,6 +301,7 @@ class ValidatorSet:
         self.proposer = proposer
 
     def _increment_proposer_priority(self) -> Validator:
+        self._packed = None
         for v in self.validators:
             v.proposer_priority = safe_add_clip(v.proposer_priority, v.voting_power)
         # compare_proposer_priority returns one of its operands, so `mostest`
@@ -325,6 +328,7 @@ class ValidatorSet:
 
     def _shift_by_avg_proposer_priority(self) -> None:
         avg = self._compute_avg_proposer_priority()
+        self._packed = None
         for v in self.validators:
             v.proposer_priority = safe_sub_clip(v.proposer_priority, avg)
 
@@ -341,6 +345,7 @@ class ValidatorSet:
         diff = self._compute_max_min_priority_diff()
         ratio = (diff + diff_max - 1) // diff_max
         if diff > diff_max:
+            self._packed = None
             for v in self.validators:
                 # Go truncates toward zero
                 q = abs(v.proposer_priority) // ratio
@@ -373,6 +378,7 @@ class ValidatorSet:
         self._apply_removals(deletes)
         self._pk_digest = None  # membership changed: table cache key rotates
         self._root = None  # and the one place a power changes: hash() builds anew
+        self._packed = None
         self._update_total_voting_power()
         self.rescale_priorities(PRIORITY_WINDOW_SIZE_FACTOR * self.total_voting_power())
         self._shift_by_avg_proposer_priority()
@@ -721,6 +727,20 @@ class ValidatorSet:
         return table
 
     # -- serialization -----------------------------------------------------
+    def packed(self) -> codec.Packed:
+        """`codec.dumps(self.to_dict())` in pieces: the form `State.bytes()`
+        nests.  The members' encoding is kept until a priority, a power or
+        the membership changes (every method that writes one drops it;
+        `copy()` carries it), so a set promoted unchanged from one state to
+        the next is not encoded again.  The proposer is encoded on every
+        call: `copy()` shares it between sets."""
+        if self._packed is None:
+            self._packed = codec.dumps([v.to_dict() for v in self.validators])
+        return codec.packed_map({
+            "validators": codec.Packed(self._packed),
+            "proposer": self.proposer.to_dict() if self.proposer else None,
+        })
+
     def to_dict(self) -> dict:
         return {
             "validators": [v.to_dict() for v in self.validators],

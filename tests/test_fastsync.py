@@ -321,6 +321,12 @@ class TestReplaySpans:
             gap_ms = (ev["t_ns"] - ev["dur_ns"] - prev["t_ns"]) / 1e6
             assert ev["wait_ms"] == pytest.approx(gap_ms, abs=0.002)
         assert "wait_ms" not in blocks[0]
+        # the store encodes what a block changed: the seen commit (its
+        # LastCommit was saved as the seen commit a block before) and the
+        # rotated next_validators; the first block applied may meet both new
+        assert blocks[0]["commit_encodes"] <= 2 and blocks[0]["set_encodes"] <= 3
+        assert [ev["commit_encodes"] for ev in blocks[1:]] == [1] * (len(blocks) - 1)
+        assert [ev["set_encodes"] for ev in blocks[1:]] == [1] * (len(blocks) - 1)
         # every dispatch and table lookup inside says which block it served
         for e in events:
             if e["kind"] in ("verify.dispatch", "verify.table") and "parent" in e:
@@ -470,6 +476,52 @@ class TestSetHashStage:
         assert {e["kind"] for e in rec.events()} <= {
             "fastsync.block", "verify.commit", "verify.dispatch", "verify.table"}
         assert block.last_commit.hash() == made[-1][1].header.last_commit_hash
+
+    async def test_the_replay_loop_encodes_what_a_block_changed_once(self, monkeypatch):
+        """`_try_sync` over a chain decoded from a peer's bytes: one part set
+        a block applied (its header is the block id's, the store writes its
+        parts and takes the size from it), `commit_encodes` 1 (the LastCommit
+        is the object saved as the seen commit one block before) and
+        `set_encodes` 1 once the loop is steady (the rotated next set); every
+        record written is what the parent wrote."""
+        from tendermint_tpu.encoding import codec
+        from tendermint_tpu.fastsync.reactor import BlockchainReactor
+        from tendermint_tpu.libs.kvstore import MemDB
+        from tendermint_tpu.state import make_genesis_state
+        from tendermint_tpu.store import BlockStore
+        from tendermint_tpu.store.block_store import seal
+        from tendermint_tpu.types import Block
+
+        pvs = sorted((MockPV() for _ in range(4)), key=lambda pv: pv.address())
+        wire = [Block.deserialize(block.serialize()) for _, block in await self._chain(pvs)]
+        reactor = BlockchainReactor.__new__(BlockchainReactor)
+        reactor.processor, reactor.scheduler = Processor(1), Scheduler(1)
+        for block in wire:
+            reactor.processor.add_block(block.height, block, "peerX")
+        reactor.recorder = tracing.FlightRecorder(size=64)
+        reactor._block_done_ns, reactor.blocks_synced = 0, 0
+        reactor.state = make_genesis_state(self._genesis(pvs))
+        reactor.block_store = BlockStore(MemDB())
+        reactor.block_exec = await self._executor()
+        built = []
+        make_part_set = Block.make_part_set
+        monkeypatch.setattr(
+            Block, "make_part_set", lambda b, size: built.append(b.height) or make_part_set(b, size))
+        try:
+            await reactor._try_sync()
+        finally:
+            await reactor.block_exec.proxy_app.stop()
+        events = [e for e in reactor.recorder.events() if e["kind"] == "fastsync.block"]
+        assert [e["id"] for e in events] == built == [1, 2, 3, 4, 5]  # 6 has no successor yet
+        assert [e["commit_encodes"] for e in events] == [1] * 5
+        # the first save meets a genesis state no one saved: its three sets are new
+        assert [e["set_encodes"] for e in events] == [3, 1, 1, 1, 1]
+        db = reactor.block_store.db
+        for block, nxt in zip(wire, wire[1:]):
+            h = block.height
+            assert db.get(b"SC:%d" % h) == seal(codec.dumps(nxt.last_commit))
+            assert db.get(b"C:%d" % h) == (seal(codec.dumps(nxt.last_commit)) if h < 5 else None)
+            assert reactor.block_store.load_block_meta(h).block_size == len(block.serialize())
 
     async def test_a_header_that_lies_about_the_last_commit_is_rejected(self):
         from dataclasses import replace

@@ -18,7 +18,7 @@ from tendermint_tpu.types import (
 )
 from tendermint_tpu.types.tx import tx_hash
 
-from tests.test_types import CHAIN_ID, make_commit, make_test_block
+from tests.test_types import CHAIN_ID, make_commit, make_test_block, rand_validator_set
 
 
 @pytest.fixture(params=["memdb", "sqlite"])
@@ -91,6 +91,92 @@ class TestBlockStore:
         assert store.load_block(5) is None
         assert store.load_block_meta(5) is None
         assert store.height() == 0 and store.size() == 0
+
+
+class TestBlockStoreWritesTheParentBytes:
+    """`save_block` serializes no block and encodes a commit once: the
+    meta's size is the part set's, and a commit keeps its sealed record, so
+    fast sync's LastCommit of block H (the object it saved as SC:H-1) is
+    written as C:H-1 without a second encoding.  Every record is held
+    against what the parent wrote, computed here from scratch."""
+
+    PART = 1024
+
+    @staticmethod
+    def _chain(n=5):
+        """Blocks 1..n, each carrying the commit of the one before, as a
+        joining node decodes them from a peer's bytes."""
+        from tendermint_tpu.types import Block, BlockID, Header
+
+        vset, pvs = rand_validator_set(4)
+        blocks, last = [], None
+        for h in range(1, n + 1):
+            header = Header(chain_id=CHAIN_ID, height=h, time_ns=1_700_000_000_000_000_000 + h,
+                            validators_hash=vset.hash(), next_validators_hash=vset.hash(),
+                            proposer_address=vset.get_proposer().address)
+            block = Block(header, [b"tx-%d-%d=%s" % (h, i, b"v" * 200) for i in range(12)],
+                          last_commit=last)
+            block.fill_header()
+            blocks.append(Block.deserialize(block.serialize()))
+            last = make_commit(vset, pvs, h, 0, block.block_id(TestBlockStoreWritesTheParentBytes.PART))
+        return blocks
+
+    def _parent_records(self, block, parts, seen_commit):
+        """What the parent's save_block wrote for this block."""
+        from tendermint_tpu.encoding import codec
+        from tendermint_tpu.store.block_store import BlockMeta, seal
+        from tendermint_tpu.types import BlockID
+
+        meta = BlockMeta(BlockID(block.hash(), parts.header()), len(block.serialize()),
+                         block.header, len(block.txs))
+        h = block.height
+        out = {b"H:%d" % h: seal(codec.dumps(meta)), b"BH:" + block.hash(): seal(b"%d" % h),
+               b"SC:%d" % h: seal(codec.dumps(seen_commit))}
+        for i in range(parts.total):
+            out[b"P:%d:%d" % (h, i)] = seal(codec.dumps(parts.get_part(i)))
+        if block.last_commit is not None:
+            out[b"C:%d" % (h - 1)] = seal(codec.dumps(block.last_commit))
+        return out
+
+    @pytest.mark.parametrize("path", ["fastsync", "consensus"])
+    def test_every_record_is_the_parents(self, db, path):
+        """`fastsync`: SC:H is the next block's LastCommit, the object
+        written as C:H one block later (1 encoding a block); `consensus`:
+        the seen commit is an object of its own (2, as before)."""
+        from tendermint_tpu.libs import tracing
+        from tendermint_tpu.types import Commit
+
+        blocks = self._chain()
+        store, rec = BlockStore(db), tracing.FlightRecorder(size=32)
+        for first, second in zip(blocks, blocks[1:]):
+            parts = first.make_part_set(self.PART)
+            assert parts.total > 1 and parts.byte_size() == len(first.serialize())
+            seen = second.last_commit
+            if path == "consensus":
+                seen = Commit(seen.height, seen.round, seen.block_id, list(seen.signatures))
+            expected = self._parent_records(first, parts, seen)
+            with rec.span("fastsync.block", id=first.height):
+                store.save_block(first, parts, seen)
+            for key, value in expected.items():
+                assert db.get(key) == value, key
+        encodes = [e["commit_encodes"] for e in rec.events()]
+        assert encodes == ([1, 1, 1, 1] if path == "fastsync" else [1, 2, 2, 2])
+        for h in range(1, 5):  # C:H comes with block H + 1: the last saved is 4
+            assert store.load_block(h).hash() == blocks[h - 1].hash()
+            assert store.load_seen_commit(h).hash() == blocks[h].last_commit.hash()
+            assert store.load_block_meta(h).block_size == len(blocks[h - 1].serialize())
+            assert (store.load_block_commit(h) is None) == (h == 4)
+
+    def test_a_kept_record_is_per_object(self):
+        from tendermint_tpu.encoding import codec
+        from tendermint_tpu.store.block_store import commit_record, seal
+
+        commit = self._chain(2)[1].last_commit
+        assert commit._record is None
+        record = commit_record(commit)
+        assert record == seal(codec.dumps(commit)) and commit_record(commit) is record
+        again = codec.loads(codec.dumps(commit))  # what a store or a peer gives back
+        assert again._record is None and commit_record(again) == record
 
 
 class TestStateStore:

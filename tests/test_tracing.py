@@ -519,6 +519,26 @@ class TestReplayBudget:
         st = tracing.replay_budget(events)["stages"]
         assert "set_hashes" not in st and st["set_hash_ms"]["mean_ms"] == 0.002
 
+    def test_the_store_encode_counts_are_listed_and_printed(self):
+        """`commit_encodes` (BlockStore.save_block) and `set_encodes`
+        (StateStore.save) are fields of `fastsync.block`: a row of the
+        budget `trace --replay` prints, and in the module's list of the
+        span's fields."""
+        own = [name for kind, names in tracing.REPLAY_ROWS if kind == "fastsync.block"
+               for name in names]
+        assert {"commit_encodes", "set_encodes"} <= set(own) and len(own) == len(set(own))
+        listed = tracing.__doc__.split("fastsync.block    SPAN", 1)[1].split("gossip (", 1)[0]
+        assert "commit_encodes" in listed and "set_encodes" in listed
+        events = self._events()
+        assert not {"commit_encodes", "set_encodes"} & set(tracing.replay_budget(events)["stages"])
+        blocks = [ev for ev in events if ev["kind"] == "fastsync.block"]
+        blocks[0].update(commit_encodes=2, set_encodes=3)  # the first block applied
+        blocks[1].update(commit_encodes=1, set_encodes=1)  # steady
+        st = tracing.replay_budget(events)["stages"]
+        assert st["commit_encodes"]["mean_ms"] == 1.5 and st["set_encodes"]["p90_ms"] == 3
+        table = tracing.format_replay_budget(tracing.replay_budget(events))
+        assert "  commit_encodes " in table and "  set_encodes " in table
+
     def test_the_basic_and_median_stages_are_listed_and_printed(self):
         """`basic_ms`, `commit_hashes` and `median_ms` (state/validation.py,
         PR 29) are fields of `fastsync.block` like PR 27's two: in the rows
