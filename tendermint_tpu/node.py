@@ -9,13 +9,13 @@ onlyValidatorIsUs:314).
 from __future__ import annotations
 
 import asyncio
-from typing import Optional
+from typing import Dict, Optional
 
 from .abci import types as abci_types
 from .config import Config
 from .consensus import ConsensusState, Handshaker
 from .consensus.wal import WAL
-from .libs.kvstore import WriteMeter, open_db
+from .libs.kvstore import KVStore, WriteMeter, open_db
 from .libs.log import get_logger
 from .libs.service import Service
 from .mempool import Mempool
@@ -99,12 +99,14 @@ class Node(Service):
         # the on-disk stores' write counters, read as each block ends
         # (BlockExecutor.apply_block); on memdb it meters nothing
         self.db_writes = WriteMeter()
-        metered = self.db_writes.add
+        # every store by name, as opened (inside the chaos wrapper):
+        # storage_info reads their counters
+        self.dbs: Dict[str, KVStore] = {}
         self.block_store = BlockStore(
-            self._wrap_db(metered(open_db("blockstore", home, backend)), "blockstore")
+            self._wrap_db(self._open_db("blockstore", home, backend), "blockstore")
         )
         self.block_store.storage_health = self.storage_health
-        self.state_db = self._wrap_db(metered(open_db("state", home, backend)), "state")
+        self.state_db = self._wrap_db(self._open_db("state", home, backend), "state")
         self.state_store = StateStore(self.state_db)
 
         self.event_bus = EventBus()
@@ -118,7 +120,7 @@ class Node(Service):
             # opened only for the builtin stateful apps — a socket/gRPC app
             # must not grow a stray empty db under home/data
             app_db=(
-                self._wrap_db(metered(open_db("app", home, backend)), "app")
+                self._wrap_db(self._open_db("app", home, backend), "app")
                 if config.base.proxy_app in ("kvstore", "bank", "staking")
                 else None
             ),
@@ -132,7 +134,7 @@ class Node(Service):
 
         # tx indexer
         if config.tx_index.indexer == "kv":
-            self.tx_indexer = TxIndexer(metered(open_db("tx_index", home, backend)))
+            self.tx_indexer = TxIndexer(self._open_db("tx_index", home, backend))
         else:
             self.tx_indexer = NullTxIndexer()
         self.indexer_service = IndexerService(self.tx_indexer, self.event_bus)
@@ -167,6 +169,11 @@ class Node(Service):
             enabled=config.instrumentation.flight_recorder,
             sample_high_rate=config.instrumentation.trace_sample_high_rate,
         )
+
+    def _open_db(self, name: str, home: Optional[str], backend: str) -> KVStore:
+        """The store `name`, metered and kept in `dbs`."""
+        db = self.dbs[name] = self.db_writes.add(open_db(name, home, backend))
+        return db
 
     def _wrap_db(self, db, store: str):
         """Chaos disk-fault wrapper (identity when chaos is off)."""
@@ -382,7 +389,7 @@ class Node(Service):
 
         home = None if cfg.base.db_backend == "memdb" else cfg.home
         self.evidence_pool = EvidencePool(
-            self.db_writes.add(open_db("evidence", home, cfg.base.db_backend)), self.state_store
+            self._open_db("evidence", home, cfg.base.db_backend), self.state_store
         )
         self.evidence_pool.metrics = self.metrics_provider.evidence
         self.evidence_pool.recorder = self.flight_recorder

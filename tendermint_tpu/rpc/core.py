@@ -17,6 +17,7 @@ from typing import Any, Dict, Optional
 
 from ..abci.types import RequestInfo, RequestQuery
 from ..libs.flowrate import TokenBucket
+from ..libs.kvstore import MemDB
 from ..libs.log import get_logger
 from ..mempool import MempoolFullError
 from ..types.events import EVENT_TX, EVENT_TYPE_KEY, TX_HASH_KEY
@@ -901,6 +902,10 @@ class RPCCore:
         if spool_stats is not None:
             wals["flight_spool"] = spool_stats
         out["wals"] = wals
+        out["memdb_folds"] = {
+            name: dict(zip(("folds", "keys_folded", "fold_ns"), db.fold_totals()))
+            for name, db in node.dbs.items() if isinstance(db, MemDB)
+        }
         if node.disk_faults is not None:
             out["chaos"] = {
                 "policies": node.disk_faults.policies(),
