@@ -1568,9 +1568,14 @@ def percentile(values: Sequence[float], q: float) -> float:
 
 
 def block_intervals_ms(window: Window) -> List[float]:
-    """Time between consecutive blocks applied, over every block of the
-    window (which opens at a block's arrival)."""
+    """Time between consecutive arrivals of blocks applied, over every block
+    of the window (which opens at a block's arrival).  Heights that share a
+    stamp arrived together and are one edge (a light client's answer that
+    made several its own, the opening edge among them), so no interval reads
+    the 0 between two of them.  A replaying node stamps each block alone, and
+    its list holds every block."""
     edges = [window.t_open_ns / 1e9] + list(window.block_times)
+    edges = edges[:1] + [b for a, b in zip(edges, edges[1:]) if b != a]
     return [(b - a) * 1e3 for a, b in zip(edges, edges[1:])]
 
 
@@ -1580,7 +1585,8 @@ def end_to_end_value(name: str, window: Window, setup_s: float) -> Optional[floa
     if name == "replay_blocks_per_s":
         return window.blocks / window.seconds if window.seconds else None
     if name == "block_interval_p95_ms":
-        return percentile(block_intervals_ms(window), 95) if window.blocks else None
+        intervals = block_intervals_ms(window)
+        return percentile(intervals, 95) if intervals else None
     raise HarnessFailure(f"no end-to-end metric {name!r} in this harness")
 
 

@@ -2,10 +2,13 @@
 `(bench, root)`: `bench` is a BENCHMARK.json as loaded, `root` the directory
 that holds it and its `benchmarks/` data files.  Every test under this
 directory that holds BENCHMARK.json to something holds it at each of `ROOTS`:
-the repository's own files, and two scratch roots (`at`) that hold them plus
-what later PRs will append: the two cells of kind `lite` of `lite_room`, and
-a sixth replay cell besides.  Those PRs may edit no test, so a rule that is
-true of today's cells only has to fail here and not there.
+the repository's own files, and scratch roots (`at`) that hold them plus what
+later PRs will append: the two cells of kind `lite` of `lite_room` over the
+benchmark as it stood when the room was made (`ROOM`), a sixth replay cell
+besides (`LATER`), and the room over the repository as it stands, every entry
+kept (`LANDED`: the repository itself once it holds the room's cells).  Those
+PRs may edit no test, so a rule that is true of today's cells only has to
+fail here and not there.
 
 None of the rules says "every cell is a replay cell" or "the replay cells are
 today's".  What they say of a metric's `workloads` list rests on the metric
@@ -127,21 +130,25 @@ def fed_by_every_rig(bench):
 # -- the roots the rules are held at ------------------------------------------------
 
 REPOSITORY, ROOM, LATER = "the repository", "with two lite cells", "and a later replay cell"
-ROOTS = (REPOSITORY, ROOM, LATER)
+LANDED = "with the lite cells landed"
+ROOTS = (REPOSITORY, ROOM, LATER, LANDED)
 LATER_CELL = lite_room.LATER_CELL
 
 
 @functools.lru_cache(maxsize=None)
 def at(label):
     """`(bench, root)`: the repository's own, or a scratch root (made once a
-    process, removed as it ends) that holds what `lite_room` appends and,
-    under `LATER`, a replay cell that files alone bring after the ten lists
-    were written."""
-    if label == REPOSITORY:
-        return load_bench(REPO), REPO
+    process, removed as it ends) that holds what `lite_room` appends: over the
+    benchmark as the room found it (`ROOM`; under `LATER` with a replay cell
+    that files alone bring after the ten lists were written), or over the
+    repository's whole BENCHMARK.json (`LANDED`; the repository itself where
+    it holds the room's cells already)."""
+    bench = load_bench(REPO)
+    if label == REPOSITORY or (label == LANDED and lite_room.holds_the_room(bench)):
+        return bench, REPO
     root = tempfile.mkdtemp(prefix="bench_room_")
     atexit.register(shutil.rmtree, root, ignore_errors=True)
-    lite_room.build_root(root)
+    lite_room.build_root(root, landed=label == LANDED)
     if label == LATER:
         lite_room.add_a_later_replay_cell(root)
     return load_bench(root), root

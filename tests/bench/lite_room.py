@@ -1,12 +1,21 @@
 #!/usr/bin/env python3
 """The room for the first cells of kind `lite`, as data: what the
 `model_config` PR that gives the light client its engine will APPEND to
-BENCHMARK.json and bring as new files (ISSUE 34), laid over a copy of the
-repository's own benchmark files in a scratch root.  Nothing here is a cell
-of the benchmark: the configuration, the traffic files and the metric files
-exist only in the root `build_root` makes.
+BENCHMARK.json and bring as new files, laid over a copy of the repository's
+own benchmark files in a scratch root.  Nothing here is a cell of the
+benchmark: the configuration, the traffic files and the metric files exist
+only in the roots `build_root` makes.
 
-Two users: `test_bench_harness.py` holds every data-file rule over that root
+The room is held over two bases (`bench_rules.at`): the benchmark as it
+stood when the room was made (`as_pr_34_left_it`: the five replay cells and
+their 32 metrics, so that what is held of the room binds none of the entries
+later PRs bring), and the repository as it stands, every entry of its own
+kept, with the room appended (`landed`; the root `bench_rules.LANDED`: the
+tree that PR leaves, which `next_pr` must still be able to build over).
+Checked over the first base alone, landing the cells on the repository
+failed a test whatever their names.
+
+Two users: `test_bench_harness.py` holds every data-file rule over those roots
 (the room is checked against what it is for), and the builder's chip runs
 drive the two cells from it with the engine installed from outside:
 
@@ -16,9 +25,10 @@ drive the two cells from it with the engine installed from outside:
 which is `benchmarks/run.py` with `load_cell` pointed at the scratch root
 (under `benchmarks/out/`, git-ignored; it also holds `hub-175.replay-later`,
 a sixth replay cell that comes after the ten lists, to show what a run of
-such a cell says) and `lite_start.with_engine` as the lite rig's `start`: a stand-in for that PR's program change, a no-op once the
-program installs its engine.  The span name `lite.header` in the metric
-files is a stand-in too: the program's per-header span is that PR's to name.
+such a cell says) and `lite_start.with_engine` as the lite rig's `start`: a
+stand-in for that PR's program change, a no-op once the program installs its
+engine.  The span name `lite.header` in the metric files is a stand-in too:
+the program's per-header span is that PR's to name.
 """
 
 import copy
@@ -87,6 +97,10 @@ METRICS = {  # name -> (the metric's file, the cells in its `workloads` list)
                 dict(_HEADER, fields=["dur_ns"], scale=1e-06), kinds=["lite"]),
         _metric("lite_fetch_ms_per_block", "program_span", CELLS, "ring_sum_per_block",
                 dict(_HEADER, fields=["fetch_ms", "decode_ms"]), kinds=["lite"]),
+        # The sequence cell alone, as the room was checked on the chip.  The reader
+        # takes equal stamps for one edge, so it reads the time between answers in
+        # either cell at any gap (never the 0s inside an answer): the list is that
+        # PR's to choose.
         _metric("block_interval_p95_ms.lite", "host_clock", [SEQUENCE],
                 "block_interval_percentile", {"q": 95}),
     )
@@ -116,10 +130,11 @@ def entry(name):
 
 def as_pr_34_left_it(bench):
     """`bench` cut back to the five cells, their four configurations and the
-    32 per-layer metrics that PR 34 found and left: entries are only ever
-    appended, so these come first.  The room is laid over that, whatever
-    later PRs have brought since (the room's own cells among it, under these
-    names or others), so that what is held of the room binds none of them."""
+    32 per-layer metrics that were there when the room was made: entries are
+    only ever appended, so these come first.  One base the room is laid over,
+    whatever later PRs have brought since (the room's own cells among it,
+    under these names or others), so that what is held of the room binds
+    none of them."""
     out = copy.deepcopy(bench)
     out["workloads"], out["per_layer"] = out["workloads"][:5], out["per_layer"][:32]
     used = {w["config"] for w in out["workloads"]}
@@ -127,39 +142,57 @@ def as_pr_34_left_it(bench):
     return out
 
 
+CONFIG_ENTRY = {
+    "name": CONFIG, "source": SOURCE, "file": f"benchmarks/configs/{CONFIG}.json",
+    "reduced": ["heights"],
+    "why": "the hub's set seen by a light client: one commit check a header on the 512-row "
+           "bucket the replay cells share, and the proxy's Python around it",
+}
+
+
+def holds_the_room(bench):
+    """Whether `bench` has the room's cells already: the PR has landed them."""
+    return set(CELLS) <= {w["name"] for w in bench["workloads"]}
+
+
 def appended(bench):
-    """`bench` as PR 34 left it with the room's entries appended; nothing
-    that is there edited."""
-    out = as_pr_34_left_it(bench)
-    out["configs"].append({
-        "name": CONFIG, "source": SOURCE, "file": f"benchmarks/configs/{CONFIG}.json",
-        "reduced": ["heights"],
-        "why": "the hub's set seen by a light client: one commit check a header on the 512-row "
-               "bucket the replay cells share, and the proxy's Python around it",
-    })
-    out["workloads"] += copy.deepcopy(WORKLOADS)
-    out["per_layer"] += [entry(name) for name in METRICS]
+    """`bench` with the room's entries appended, each whose name it does not
+    hold yet; nothing that is there edited."""
+    out = copy.deepcopy(bench)
+    for key, entries in (("configs", [CONFIG_ENTRY]), ("workloads", WORKLOADS),
+                         ("per_layer", [entry(name) for name in METRICS])):
+        have = {e["name"] for e in out[key]}
+        out[key] += [copy.deepcopy(e) for e in entries if e["name"] not in have]
     return out
 
 
-def build_root(dest, repo=REPO):
-    """A root that holds the repository's BENCHMARK.json (`as_pr_34_left_it`)
-    and data files plus what the room's PR appends; returns `dest`."""
+def build_root(dest, repo=REPO, landed=False):
+    """A root that holds the repository's data files and its BENCHMARK.json,
+    cut back by `as_pr_34_left_it` or, with `landed`, whole, plus what the
+    room's PR appends: the entries and the files of each the base lacks;
+    returns `dest`."""
     for sub in ("configs", "traffic", "metrics"):
         shutil.copytree(os.path.join(repo, "benchmarks", sub), os.path.join(dest, "benchmarks", sub))
     with open(os.path.join(repo, "BENCHMARK.json")) as f:
         bench = json.load(f)
+    before = bench if landed else as_pr_34_left_it(bench)
+    after = appended(before)
+    added = {e["name"] for key in ("configs", "workloads", "per_layer")
+             for e in after[key][len(before[key]):]}
 
     def write(obj, *path):
         with open(os.path.join(dest, *path), "w") as f:
             json.dump(obj, f, indent=1)
 
-    write(appended(bench), "BENCHMARK.json")
-    write(configuration(repo), "benchmarks", "configs", CONFIG + ".json")
-    for name, traffic in TRAFFIC.items():
-        write(traffic, "benchmarks", "traffic", name + ".json")
+    write(after, "BENCHMARK.json")
+    if CONFIG in added:
+        write(configuration(repo), "benchmarks", "configs", CONFIG + ".json")
+    for w in WORKLOADS:
+        if w["name"] in added:
+            write(TRAFFIC[w["traffic"]], "benchmarks", "traffic", w["traffic"] + ".json")
     for name, (spec, _) in METRICS.items():
-        write(spec, "benchmarks", "metrics", name + ".json")
+        if name in added:
+            write(spec, "benchmarks", "metrics", name + ".json")
     return dest
 
 

@@ -1,28 +1,32 @@
 #!/usr/bin/env python3
 """A checkout as LATER PRs would leave it, to run this directory's tests in:
 the builder's check, before a `benchmark` PR is done, that a PR which may
-edit no file of the benchmark can bring its cells.  (PR 33 said "files
-alone" and four tests said otherwise; PR 34's first round pinned today's
-replay cells in three more.  Each was found by running the tests over such
-a tree, none by reading them.)
+edit no file of the benchmark can bring its cells.  ("Files alone" has been
+said more than once and each time a test said otherwise; each was found by
+running the tests over such a tree, none by reading them.)
 
     python3 tests/bench/next_pr.py            # builds build/next_pr (git-ignored)
     cd build/next_pr && JAX_PLATFORMS=cpu python -m pytest tests/bench -q -n 3
 
-It copies BENCHMARK.json, `benchmarks/`, `tests/bench/` and what they need,
-links the program, and APPENDS, editing nothing that is there:
+It copies `benchmarks/`, `tests/bench/` and what they need, links the
+program, takes BENCHMARK.json and the data files from the base it builds over
+(the repository, or a root of `bench_rules.at`), and APPENDS, editing nothing
+that is there and under names of its own (`names`), which collide with
+nothing the base holds or `lite_room` names:
 
-- the two cells of kind `lite` that `lite_room` describes, but with other
+- two cells of kind `lite` as `lite_room` describes them, but with other
   choices than the room's wherever the PR is free to choose (chain lengths,
   the bisection's gap, a metric of its own under another name and another
-  span), so that a test which binds that PR to the room's numbers fails;
+  span), so that a test which binds that PR to the room's numbers fails; the
+  metric lists every cell of kind `lite` in the tree, from the first on (the
+  base's, if any, then its own), as a list by kind does;
 - a per-layer metric that every cell reports;
 - a sixth replay cell, `hub-175` under a copy of the `replay` mix.
 
 Every test has to pass there as it passes here.
 """
 
-import copy
+import itertools
 import json
 import os
 import shutil
@@ -31,20 +35,44 @@ import sys
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
+import bench_rules  # noqa: E402
 import lite_room  # noqa: E402
 
 REPO = lite_room.REPO
 LEFT_OUT = shutil.ignore_patterns("out", "cache", "__pycache__", ".jax_cache")
+HEIGHTS = {"lite-sequence": 5000, "lite-bisect": 26000}  # not the room's
+BISECT_GAP = 8  # not the room's 4
 
 
-def build(dest):
+def names(bench):
+    """What `build` appends over `bench`, by role: every name with the first
+    suffix of `-next`, `-next2`, ... under which none is taken there (a
+    checkout this script built holds its own)."""
+    taken = {e["name"] for key in ("configs", "workloads", "per_layer") for e in bench[key]}
+    for i in itertools.count(1):
+        suffix = "-next" + (str(i) if i > 1 else "")
+        out = {"suffix": suffix, "config": lite_room.CONFIG + suffix,
+               "sequence": lite_room.SEQUENCE + suffix, "bisect": lite_room.BISECT + suffix,
+               "replay": "hub-175.replay" + suffix, "header": "light_header_ms" + suffix,
+               "everywhere": "engine_wait_ms_per_block" + suffix}
+        if not taken & set(out.values()):
+            return out
+
+
+def build(dest, base=REPO):
     shutil.rmtree(dest, ignore_errors=True)
     os.makedirs(os.path.join(dest, "tests"))
-    for name in ("BENCHMARK.json", "pytest.ini", "tests/conftest.py"):
+    for name in ("pytest.ini", "tests/conftest.py"):
         shutil.copy(os.path.join(REPO, name), os.path.join(dest, name))
     for name in ("benchmarks", "tests/bench"):
         shutil.copytree(os.path.join(REPO, name), os.path.join(dest, name), ignore=LEFT_OUT)
+    for sub in ("configs", "traffic", "metrics"):
+        shutil.rmtree(os.path.join(dest, "benchmarks", sub))
+        shutil.copytree(os.path.join(base, "benchmarks", sub), os.path.join(dest, "benchmarks", sub))
     os.symlink(os.path.join(REPO, "tendermint_tpu"), os.path.join(dest, "tendermint_tpu"))
+    bench = bench_rules.load_bench(base)
+    n = names(bench)
+    suffix = n["suffix"]
 
     def write(obj, sub, name):
         path = os.path.join(dest, "benchmarks", sub, name + ".json")
@@ -52,31 +80,32 @@ def build(dest):
         with open(path, "w") as f:
             json.dump(obj, f, indent=1)
 
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    room = lite_room.appended(bench)
-    bench["configs"].append(room["configs"][-1])
-    bench["workloads"] += copy.deepcopy(lite_room.WORKLOADS)
-    config = lite_room.configuration()
-    config["traffic_heights"] = {"lite-sequence": 5000, "lite-bisect": 26000}
-    write(config, "configs", lite_room.CONFIG)
+    config = dict(lite_room.configuration(), name=n["config"])
+    config["traffic_heights"] = {name + suffix: h for name, h in HEIGHTS.items()}
+    write(config, "configs", n["config"])
+    bench["configs"].append(dict(
+        lite_room.CONFIG_ENTRY, name=n["config"], file=f"benchmarks/configs/{n['config']}.json"))
     for name, traffic in lite_room.TRAFFIC.items():
-        write(dict(traffic, gap=8 if name == "lite-bisect" else traffic["gap"]), "traffic", name)
+        gap = BISECT_GAP if name == "lite-bisect" else traffic["gap"]
+        write(dict(traffic, name=name + suffix, gap=gap), "traffic", name + suffix)
+    for w in lite_room.WORKLOADS:
+        bench["workloads"].append(dict(
+            w, name=w["name"] + suffix, config=n["config"], traffic=w["traffic"] + suffix))
     header = dict(
-        lite_room.METRICS["lite_header_ms_per_block"][0], name="light_header_ms",
+        lite_room.METRICS["lite_header_ms_per_block"][0], name=n["header"],
         params={"kind": "light.header", "block_span": "light.header", "fields": ["dur_ns"],
                 "scale": 1e-6})
     write(header, "metrics", header["name"])
-    bench["per_layer"].append(
-        dict({k: header[k] for k in lite_room.ENTRY_KEYS}, workloads=list(lite_room.CELLS)))
+    bench["per_layer"].append(dict(  # a list by kind: every lite cell in the tree, from the first
+        {k: header[k] for k in lite_room.ENTRY_KEYS},
+        workloads=bench_rules.cells_of(bench, dest, header["kinds"])))
     with open(os.path.join(REPO, "benchmarks", "metrics", "engine_wait_ms_per_block.json")) as f:
-        everywhere = dict(json.load(f), name="engine_wait_ms_per_block.later")
+        everywhere = dict(json.load(f), name=n["everywhere"])
     write(everywhere, "metrics", everywhere["name"])
     bench["per_layer"].append({k: everywhere[k] for k in lite_room.ENTRY_KEYS})
     with open(os.path.join(REPO, "benchmarks", "traffic", "replay.json")) as f:
-        write(dict(json.load(f), name="replay-next"), "traffic", "replay-next")
-    bench["workloads"].append(
-        dict(bench["workloads"][0], name="hub-175.replay-next", traffic="replay-next"))
+        write(dict(json.load(f), name="replay" + suffix), "traffic", "replay" + suffix)
+    bench["workloads"].append(dict(bench["workloads"][0], name=n["replay"], traffic="replay" + suffix))
     with open(os.path.join(dest, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f, indent=1)
     return dest

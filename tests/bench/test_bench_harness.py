@@ -10,6 +10,7 @@ run with JAX_PLATFORMS=cpu and never import JAX.
 import copy
 import dataclasses
 import gc
+import importlib
 import json
 import os
 import subprocess
@@ -271,6 +272,99 @@ def test_percentile_and_intervals():
     assert harness.end_to_end_value("setup_s", w, 9.0) == 9.0
 
 
+# -- the interval percentile: between answers, not between heights that share one ------------
+
+INTERVAL_METRICS = [  # the repository's metrics read by `block_interval_percentile`
+    m["name"] for m in BENCH["per_layer"]
+    if bench_rules.metric_file(REPO, m["name"])["reducer"] == "block_interval_percentile"
+]
+
+
+def interval_window(block_times, t_open_s=2.0):
+    return harness.Window(
+        cell=toy_cell(), seconds=block_times[-1] - t_open_s, t_open_ns=round(t_open_s * 1e9),
+        t_close_ns=round(block_times[-1] * 1e9), block_times=list(block_times),
+        block_heights=list(range(1, len(block_times) + 1)), events=[], deliver_spans=[], buffered=[],
+    )
+
+
+def every_interval_as_it_was_read(window):
+    """What the reader read until stamps that are equal became one edge: every
+    height an edge, the window's opening one first."""
+    edges = [window.t_open_ns / 1e9] + list(window.block_times)
+    return [(b - a) * 1e3 for a, b in zip(edges, edges[1:])]
+
+
+def replay_like_window(seed):
+    """Distinct stamps, as a replaying node's: one block a stamp, steady
+    intervals with a pipeline bubble now and then, from the seed."""
+    import random
+
+    rng = random.Random(seed)
+    t_open_ns, times_ns = 2 * 10**9 + rng.randrange(10**6), []
+    t = t_open_ns
+    for _ in range(500):
+        t += rng.randrange(11 * 10**6, 15 * 10**6) if rng.random() > 0.05 else rng.randrange(50, 61) * 10**6
+        times_ns.append(t)
+    return harness.Window(
+        cell=toy_cell(), seconds=(times_ns[-1] - t_open_ns) / 1e9, t_open_ns=t_open_ns,
+        t_close_ns=times_ns[-1], block_times=[x / 1e9 for x in times_ns],
+        block_heights=list(range(100, 600)), events=[], deliver_spans=[], buffered=[],
+    )
+
+
+@pytest.mark.parametrize("q", [50, 95, 100])
+@pytest.mark.parametrize("seed", [SEED, 7, 2_150_380_001])
+def test_the_interval_percentile_reads_a_window_of_distinct_stamps_to_the_bit_as_before(q, seed):
+    """Every replay window: no two blocks share a stamp.  The reader, the
+    end-to-end tail and the intervals the context prints read what they read
+    before, bit for bit, through every metric file of the reducer."""
+    window = replay_like_window(seed)
+    before = every_interval_as_it_was_read(window)
+    assert harness.block_intervals_ms(window) == before
+    reader = importlib.import_module("benchmarks.reducers.block_interval_percentile")
+    assert reader.read(window, {"q": q}) == harness.percentile(before, q)
+    assert INTERVAL_METRICS[:3] == [  # and what later PRs append
+        "block_interval_p95_ms.replay", "block_interval_p50_ms", "block_interval_p95_ms.disk"]
+    for name in INTERVAL_METRICS:
+        params = bench_rules.metric_file(REPO, name)["params"]
+        assert reader.read(window, params) == harness.percentile(before, params["q"])
+    assert harness.end_to_end_value("block_interval_p95_ms", window, 9.0) == harness.percentile(before, 95)
+
+
+def test_a_lite_window_at_gap_4_reads_the_time_between_answers_not_0():
+    """A light client's answer that made four heights the proxy's own stamps
+    all four at once (`sequence` mode, gap 4): three intervals of every four
+    were 0 and the median read 0.0.  Between answers: the requests' own
+    interval, 1000 / requests a second."""
+    answers = [2.0 + 0.0225 * (i + 1) + (0.004 if i % 3 == 0 else 0.0) for i in range(200)]
+    window = interval_window([t for t in answers for _ in range(4)])
+    assert window.blocks == 800
+    assert harness.percentile(every_interval_as_it_was_read(window), 50) == 0.0  # as it read
+    intervals = harness.block_intervals_ms(window)
+    assert len(intervals) == 200 and min(intervals) > 0
+    reader = importlib.import_module("benchmarks.reducers.block_interval_percentile")
+    p50 = reader.read(window, bench_rules.metric_file(REPO, "block_interval_p50_ms")["params"])
+    assert p50 == pytest.approx(22.5, abs=1e-6)
+    assert p50 == pytest.approx(1000 / (200 / window.seconds), rel=0.1)
+    # the same window one height an answer reads alike
+    assert reader.read(interval_window(answers), {"q": 50}) == pytest.approx(p50)
+
+
+def test_a_first_answer_that_shares_the_opening_edge_is_no_interval():
+    """The window opens on a stamp the first heights inside share: those are
+    the opening answer's own, and the first interval runs from it to the next
+    answer."""
+    window = interval_window([2.0, 2.0, 2.0, 2.03, 2.03, 2.05], t_open_s=2.0)
+    assert harness.block_intervals_ms(window) == pytest.approx([30.0, 20.0])
+    assert every_interval_as_it_was_read(window) == pytest.approx([0, 0, 0, 30.0, 0, 20.0])
+    # one answer, all of it on the opening edge: no interval, and nothing made up
+    alone = interval_window([2.0, 2.0], t_open_s=2.0)
+    assert harness.block_intervals_ms(alone) == []
+    reader = importlib.import_module("benchmarks.reducers.block_interval_percentile")
+    assert reader.read(alone, {"q": 50}) is None
+
+
 # -- whole runs on the CPU, the device stood in for -------------------------------
 
 
@@ -308,6 +402,9 @@ async def test_a_traced_run_reads_the_recorders_metrics_and_skips_the_devices(sc
     assert "breakdown" not in result and "busy_s" not in result["device"]
     # what the harness itself guarantees: a table-path dispatch for every block
     assert 1.0 <= result["metrics"]["dispatches_per_block"]["value"] <= 2.2
+    # the median and the context's intervals are one list: between arrivals
+    assert result["metrics"]["block_interval_p50_ms"]["value"] == result["context"][
+        "block_interval_ms"]["p50"]
     assert result["metrics"]["table_hit_share"]["value"] == 100.0
     # a static set hashes its set once: the two comparisons a block, no root
     # (the ring's readers read nothing while an earlier test's node still lives)
@@ -641,7 +738,7 @@ def test_a_lite_cell_reports_the_engines_eleven_and_its_own_and_none_of_the_ten(
     assert cell.not_listed == []  # nothing its rig could feed goes unread
 
 
-@pytest.mark.parametrize("at", [ROOM, LATER])
+@pytest.mark.parametrize("at", [ROOM, LATER, bench_rules.LANDED])
 @pytest.mark.parametrize("rule", sorted(bench_rules.RULES))
 def test_every_data_file_rule_holds_with_two_lite_cells_present(at, rule):
     bench_rules.RULES[rule](*bench_rules.at(at))
@@ -761,11 +858,62 @@ def test_a_checkout_as_later_prs_would_leave_it_breaks_no_rule(tmp_path):
     `benchmark` PR runs every test of this directory in such a checkout."""
     import next_pr
 
+    named = next_pr.names(BENCH)
     root = next_pr.build(str(tmp_path / "next"))
     bench = bench_rules.load_bench(root)
     assert bench["workloads"][:len(BENCH["workloads"])] == BENCH["workloads"]
     assert bench["per_layer"][:len(BENCH["per_layer"])] == BENCH["per_layer"]
     for rule in sorted(bench_rules.RULES):
         bench_rules.RULES[rule](bench, root)
-    assert harness.load_cell("hub-175.replay-next", root).not_listed == ten_of_replay(bench, root)
-    assert harness.load_cell(lite_room.BISECT, root).heights == 26000  # not the room's
+    assert harness.load_cell(named["replay"], root).not_listed == ten_of_replay(bench, root)
+    assert harness.load_cell(named["bisect"], root).heights == 26000  # not the room's
+
+
+def test_a_checkout_as_later_prs_would_leave_it_over_the_landed_lite_cells_breaks_no_rule(tmp_path):
+    """The same over `LANDED`, the tree the `model_config` PR leaves: the
+    next PR's lite metric lists the landed cells first, then its own, a list
+    by kind; nothing the room named is written over or appended twice."""
+    import next_pr
+
+    base_bench, base = bench_rules.at(bench_rules.LANDED)
+    named = next_pr.names(base_bench)
+    root = next_pr.build(str(tmp_path / "next"), base)
+    bench = bench_rules.load_bench(root)
+    for key in ("configs", "workloads", "per_layer"):
+        assert bench[key][:len(base_bench[key])] == base_bench[key], key
+        names = [e["name"] for e in bench[key]]
+        assert len(set(names)) == len(names), key
+    for rule in sorted(bench_rules.RULES):
+        bench_rules.RULES[rule](bench, root)
+    lite_cells = bench_rules.cells_of(bench, root, ["lite"])
+    assert set(lite_room.CELLS) < set(lite_cells)
+    assert lite_cells[-2:] == [named["sequence"], named["bisect"]]
+    header = next(m for m in bench["per_layer"] if m["name"] == named["header"])
+    assert header["workloads"] == lite_cells and bench_rules.listed_by_kind(header, bench, root)
+    assert bench_rules.load(root, "benchmarks", "configs", lite_room.CONFIG + ".json") == bench_rules.load(
+        base, "benchmarks", "configs", lite_room.CONFIG + ".json")
+    assert harness.load_cell(named["replay"], root).not_listed == ten_of_replay(bench, root)
+    assert harness.load_cell(named["bisect"], root).heights == 26000  # not the room's
+
+
+def test_the_landed_root_is_the_repository_whole_with_the_room_appended():
+    """Every entry of the repository's kept, in its place; the room's entries
+    after them, those the repository does not hold yet; and its files beside
+    the repository's, none of them written over."""
+    bench, root = bench_rules.at(bench_rules.LANDED)
+    if lite_room.holds_the_room(BENCH):
+        assert root == REPO
+        return
+    room = lite_room.appended(lite_room.as_pr_34_left_it(BENCH))
+    for key in ("configs", "workloads", "per_layer"):
+        assert bench[key][:len(BENCH[key])] == BENCH[key], key
+        ours = [e for e in room[key] if e["name"] not in {x["name"] for x in BENCH[key]}]
+        assert bench[key][len(BENCH[key]):] == ours, key
+    for key in ("command", "paths", "run_seconds", "end_to_end"):
+        assert bench[key] == BENCH[key]
+    assert [w["name"] for w in bench["workloads"]][-2:] == lite_room.CELLS
+    for sub in ("configs", "traffic", "metrics"):
+        for name in os.listdir(os.path.join(REPO, "benchmarks", sub)):
+            assert bench_rules.load(REPO, "benchmarks", sub, name) == bench_rules.load(
+                root, "benchmarks", sub, name), (sub, name)
+    assert bench_rules.cells_of(bench, root, ["lite"])[-2:] == lite_room.CELLS

@@ -545,3 +545,31 @@ def test_a_traced_window_is_read_by_the_metric_files_of_the_root_its_cell_came_f
     # the same cell loaded from the checkout's files would not find its own metric's file
     with pytest.raises(FileNotFoundError):
         harness.read_metrics(dataclasses.replace(cell, root=harness.ROOT), window, 9.0, True)
+
+
+@pytest.mark.parametrize("mode,gap", [("sequence", 4), ("sequence", 1), ("bisection", 4)])
+def test_the_stamps_median_reads_the_time_between_answers_at_any_gap(mode, gap):
+    """The window as the rig cuts it from the stamps its downstream client
+    notes: every height an answer made the proxy's own under the answer's
+    stamp (`gap` of them in `sequence`, the one asked for in `bisection`).
+    `block_interval_p50_ms` reads the requests' interval, 1000 / requests a
+    second, in each: at `sequence` gap 4 it read 0.0 while every height was
+    an edge."""
+    stamps, last, t_ns = harness.Stamps(), 1, 10**9
+    for i in range(400):
+        h = last + gap
+        t_ns += 20_000_000 + (5_000_000 if i % 4 == 0 else 0)
+        stamps.times_ns.extend([t_ns] * (gap if mode == "sequence" else 1))
+        stamps.heights.extend(range(last + 1, h + 1) if mode == "sequence" else [h])
+        last = h
+    first = (100 * gap if mode == "sequence" else 100) - 1  # the opening answer's last height
+    window = harness.cut_window(toy_cell(mode, gap), stamps, first, 4.0, [], [])
+    requests = len(set(window.block_times))
+    assert requests >= 150 and window.blocks == requests * (gap if mode == "sequence" else 1)
+    params = bench_rules.metric_file(REPO, "block_interval_p50_ms")["params"]
+    p50 = harness.read_metrics(dataclasses.replace(window.cell, per_layer=[
+        m for m in BENCH["per_layer"] if m["name"] == "block_interval_p50_ms"]), window, 9.0, True)
+    assert p50 == {"block_interval_p50_ms": {"value": pytest.approx(20.0), "unit": "ms"}}
+    assert p50["block_interval_p50_ms"]["value"] == harness.percentile(
+        harness.block_intervals_ms(window), params["q"])
+    assert 1000 / (requests / window.seconds) == pytest.approx(21.25, rel=0.01)
