@@ -348,7 +348,11 @@ def cmd_replay(args) -> int:
 
 
 def cmd_light(args) -> int:
-    """commands/lite.go — run a light-client proxy against a primary."""
+    """commands/lite.go — run a light-client proxy against a primary, on
+    the verify engine of the `[tpu]` table of `--home`'s config.toml (its
+    defaults where there is none), skipping verification (bisection)
+    unless `--sequential`."""
+    from .lite2.client import BISECTION, SEQUENCE
     from .lite2.proxy import run_proxy
 
     asyncio.run(
@@ -360,6 +364,8 @@ def cmd_light(args) -> int:
             trust_height=args.height,
             trust_hash=bytes.fromhex(args.hash),
             trusting_period_s=args.trusting_period,
+            mode=SEQUENCE if args.sequential else BISECTION,
+            tpu=_load_cfg(args.home).tpu,
         )
     )
     return 0
@@ -983,6 +989,10 @@ def build_parser() -> argparse.ArgumentParser:
     sp.add_argument("--height", type=int, required=True, help="trusted height")
     sp.add_argument("--hash", required=True, help="trusted header hash (hex)")
     sp.add_argument("--trusting-period", type=float, default=168 * 3600)
+    sp.add_argument(
+        "--sequential", action="store_true",
+        help="verify every header up to the one asked for (default: skipping verification)",
+    )
     sp.set_defaults(fn=cmd_light)
 
     sp = sub.add_parser(

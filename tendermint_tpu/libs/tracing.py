@@ -103,6 +103,18 @@ Event kinds currently emitted:
                       txs_indexed (taken in by the indexer since the block
                       before closed), index_lag (this height less the last
                       whose txs are all indexed)
+  light client (lite2/client.py, on the recorder lite2.proxy.start_proxy
+  gives it):
+    lite.header       SPAN, one per header made the client's own in
+                      sequence or bisection, id = height: fetch_ms (the
+                      provider's two calls: a round trip for the commit and
+                      one per 100 validators, three at 175, decoding
+                      included), decode_ms (0: no provider times it apart),
+                      set_hash_ms, verify_ms (the verifier's rules and the
+                      commit checks, engine call included), cross_check_ms
+                      (the witnesses, on the header asked for; else 0),
+                      store_ms, mode; ok=False and error on a header that
+                      failed.  The verify.commit spans open under it
     txindex.cut       height, reason, indexed_through
                                                the indexer's subscription was
                                                cancelled under it (a full buffer:
@@ -240,10 +252,10 @@ def live_recorders() -> List["FlightRecorder"]:
 
 
 _monotonic_ns = time.monotonic_ns  # the stopwatch's one clock read, bound once
-#: the spans whose event carries its `stamps`: a replay block and its commit
-#: check, which benchmarks/stages.py files device idle time under; every
-#: other span keeps its laps as fields alone
-STAMPED_KINDS = frozenset(("fastsync.block", "verify.commit"))
+#: the spans whose event carries its `stamps`: a replay block, a light
+#: client's header and their commit checks, which benchmarks/stages.py files
+#: device idle time under; every other span keeps its laps as fields alone
+STAMPED_KINDS = frozenset(("fastsync.block", "lite.header", "verify.commit"))
 _TRACE_ANNOTATION = None  # jax.profiler.TraceAnnotation, once jax has been imported
 
 

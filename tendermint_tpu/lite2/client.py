@@ -9,6 +9,20 @@ replaceProvider:1037, pruning via max_retained_headers, expiry checks.
 Every header acceptance costs one or two whole-commit batch
 verifications on the device — the serial per-signature loop of
 types/validator_set.go:641-668 never runs here.
+
+Each header the client makes its own in `sequence` or `bisection` is one
+`lite.header` span on the client's recorder (`id` its height), whose
+fields say where its time went: `fetch_ms` (the provider's two calls, the
+header's and the set's: one round trip for the commit and one for each 100
+validators, three at 175, their JSON decoded to objects included),
+`decode_ms` (0: no provider times the decoding apart from the round trip),
+`set_hash_ms` (the set's hash, which the header must name),
+`verify_ms` (the verifier's rules and the commit checks, engine call
+included), `cross_check_ms` (the witnesses asked about the header the caller
+asked for; 0 on the steps before it), `store_ms` and `mode`.  In bisection a
+jump that the trusted set cannot vouch for is part of the span of the
+header made own next.  A header that fails carries `ok` False and `error`.
+The commit checks' `verify.commit` spans open under it.
 """
 
 from __future__ import annotations
@@ -18,6 +32,7 @@ import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
+from ..libs import tracing
 from ..libs.log import get_logger
 from ..types import SignedHeader
 from ..types.validator import ValidatorSet
@@ -88,6 +103,7 @@ class Client:
         witness_timeout_s: float = 5.0,
         witness_error_threshold: int = 3,
         on_witness_demoted=None,
+        recorder=None,
     ):
         """`commit_preverify` is an optional async hook
         `(signed_header, [validator_sets]) -> batch_verify | None` invoked
@@ -95,7 +111,8 @@ class Client:
         pre-verifies the whole commit through the node's shared
         AsyncBatchVerifier (one engine flush per commit — the same ingress
         consensus votes ride) and returns a cache-lookup batch_verify for
-        the synchronous verify_commit path."""
+        the synchronous verify_commit path.  `recorder` (a FlightRecorder)
+        takes the `lite.header` spans; none, none are written."""
         if mode not in (SEQUENCE, BISECTION):
             raise ValueError(f"unknown verification mode {mode!r}")
         trust_options.validate()
@@ -119,6 +136,7 @@ class Client:
         self.demoted_witnesses: List[Provider] = []
         self.on_witness_demoted = on_witness_demoted
         self._witness_errors: Dict[int, int] = {}  # id(provider) -> consecutive errors
+        self.recorder = recorder if recorder is not None else tracing.NOP
         self.log = get_logger("lite2")
         self._initialized = False
 
@@ -198,15 +216,13 @@ class Client:
         # concurrent winner legitimately persisted in the meantime.
         saved: Set[int] = set()
         try:
-            if height < self.store.first_height():
+            if height < self.store.first_height() or height <= latest_trusted_h:
                 sh = await self._backwards(height, now, saved)
-            elif height <= latest_trusted_h:
-                sh = await self._backwards(height, now, saved)
+                await self._compare_with_witnesses(sh)
             elif self.mode == SEQUENCE:
                 sh = await self._sequence(height, now, saved)
             else:
                 sh = await self._bisection(height, now, saved)
-            await self._compare_with_witnesses(sh)
         except DivergedHeaderError:
             # a strategy-phase divergence (backwards hash-chain break) rolls
             # back exactly like a witness-phase one: nothing a lying primary
@@ -260,18 +276,63 @@ class Client:
 
     # -- verification strategies ------------------------------------------
 
+    def _header_span(self, height: int) -> tracing.Span:
+        """The `lite.header` span of the header the strategy works on next,
+        every stage's field written from 0."""
+        return self.recorder.span(
+            "lite.header", id=height, mode=self.mode, fetch_ms=0.0, decode_ms=0.0,
+            set_hash_ms=0.0, verify_ms=0.0, cross_check_ms=0.0, store_ms=0.0,
+        )
+
+    @staticmethod
+    def _failed(span: tracing.Span, exc: Exception) -> None:
+        span.set(ok=False, error=str(exc))
+        span.end()
+
+    async def _fetch(self, h: int, span: tracing.Span) -> Tuple[SignedHeader, ValidatorSet]:
+        """The primary's header and set for height h, the whole of both
+        provider calls under `span`'s `fetch_ms`."""
+        pair = (await self.primary.signed_header(h), await self.primary.validator_set(h))
+        span.lap("fetch_ms")
+        return pair
+
+    @staticmethod
+    def _hash_set(vals: ValidatorSet, span: tracing.Span) -> None:
+        """The set's hash, which the verifier holds the header's
+        `validators_hash` to (kept on the set: the verifier reads it free)."""
+        vals.hash()
+        span.lap("set_hash_ms")
+
+    async def _make_own(self, sh: SignedHeader, vals: ValidatorSet, target: int,
+                        saved: Optional[Set[int]], span: tracing.Span) -> None:
+        """A verified header into the store, cross-checked with every witness
+        first where it is the one the caller asked for (lite2/client.go
+        verifyHeader: compare, then save); ends its span."""
+        if sh.height == target:
+            await self._compare_with_witnesses(sh)
+            span.lap("cross_check_ms")
+        self._persist(sh, vals, saved)
+        span.lap("store_ms")
+        span.end()
+
     async def _sequence(self, height: int, now: int, saved: Optional[Set[int]] = None) -> SignedHeader:
         """lite2/client.go:621 — verify every header one by one."""
         trusted_sh = self.store.signed_header(self.store.latest_height())
         for h in range(trusted_sh.height + 1, height + 1):
-            sh = await self.primary.signed_header(h)
-            vals = await self.primary.validator_set(h)
-            verify_adjacent(
-                self.chain_id, trusted_sh, sh, vals,
-                self.trust_options.period_ns, now, self.max_clock_drift_ns,
-                batch_verify=await self._bv(sh, [vals]),
-            )
-            self._persist(sh, vals, saved)
+            span = self._header_span(h)
+            try:
+                sh, vals = await self._fetch(h, span)
+                self._hash_set(vals, span)
+                verify_adjacent(
+                    self.chain_id, trusted_sh, sh, vals,
+                    self.trust_options.period_ns, now, self.max_clock_drift_ns,
+                    batch_verify=await self._bv(sh, [vals]),
+                )
+                span.lap("verify_ms")
+                await self._make_own(sh, vals, height, saved, span)
+            except Exception as exc:
+                self._failed(span, exc)
+                raise
             trusted_sh = sh
         return trusted_sh
 
@@ -290,49 +351,54 @@ class Client:
         fetched: Dict[int, Tuple[SignedHeader, ValidatorSet]] = {}
 
         async def fetch(h: int) -> Tuple[SignedHeader, ValidatorSet]:
+            span.id = h  # the height worked on, for the spans opened under it
             pair = fetched.get(h)
             if pair is None:
-                pair = (
-                    await self.primary.signed_header(h),
-                    await self.primary.validator_set(h),
-                )
-                fetched[h] = pair
+                pair = fetched[h] = await self._fetch(h, span)
             return pair
 
-        target_sh, target_vals = await fetch(height)
-        untrusted_sh, untrusted_vals = target_sh, target_vals
+        span = self._header_span(height)
+        try:
+            target_sh, target_vals = await fetch(height)
+            untrusted_sh, untrusted_vals = target_sh, target_vals
 
-        for _ in range(1000):  # loop guard vs a byzantine primary
-            if untrusted_sh.height == trusted_sh.height + 1:
-                verify_adjacent(
-                    self.chain_id, trusted_sh, untrusted_sh, untrusted_vals,
-                    self.trust_options.period_ns, now, self.max_clock_drift_ns,
-                    batch_verify=await self._bv(untrusted_sh, [untrusted_vals]),
-                )
-                verified = True
-            else:
-                try:
-                    verify_non_adjacent(
-                        self.chain_id, trusted_sh, trusted_vals, untrusted_sh, untrusted_vals,
+            for _ in range(1000):  # loop guard vs a byzantine primary
+                self._hash_set(untrusted_vals, span)
+                if untrusted_sh.height == trusted_sh.height + 1:
+                    verify_adjacent(
+                        self.chain_id, trusted_sh, untrusted_sh, untrusted_vals,
                         self.trust_options.period_ns, now, self.max_clock_drift_ns,
-                        self.trust_level,
-                        batch_verify=await self._bv(untrusted_sh, [untrusted_vals, trusted_vals]),
+                        batch_verify=await self._bv(untrusted_sh, [untrusted_vals]),
                     )
                     verified = True
-                except ErrNewValSetCantBeTrusted:
-                    verified = False
-            if verified:
-                self._persist(untrusted_sh, untrusted_vals, saved)
-                trusted_sh, trusted_vals = untrusted_sh, untrusted_vals
-                if untrusted_sh.height == height:
-                    return untrusted_sh
-                untrusted_sh, untrusted_vals = target_sh, target_vals
-            else:
-                pivot = (trusted_sh.height + untrusted_sh.height) // 2
-                if pivot == trusted_sh.height:
-                    raise LightClientError("bisection cannot make progress")
-                untrusted_sh, untrusted_vals = await fetch(pivot)
-        raise LightClientError("bisection exceeded iteration bound")
+                else:
+                    try:
+                        verify_non_adjacent(
+                            self.chain_id, trusted_sh, trusted_vals, untrusted_sh, untrusted_vals,
+                            self.trust_options.period_ns, now, self.max_clock_drift_ns,
+                            self.trust_level,
+                            batch_verify=await self._bv(untrusted_sh, [untrusted_vals, trusted_vals]),
+                        )
+                        verified = True
+                    except ErrNewValSetCantBeTrusted:
+                        verified = False
+                span.lap("verify_ms")
+                if verified:
+                    await self._make_own(untrusted_sh, untrusted_vals, height, saved, span)
+                    trusted_sh, trusted_vals = untrusted_sh, untrusted_vals
+                    if untrusted_sh.height == height:
+                        return untrusted_sh
+                    span = self._header_span(height)
+                    untrusted_sh, untrusted_vals = target_sh, target_vals
+                else:
+                    pivot = (trusted_sh.height + untrusted_sh.height) // 2
+                    if pivot == trusted_sh.height:
+                        raise LightClientError("bisection cannot make progress")
+                    untrusted_sh, untrusted_vals = await fetch(pivot)
+            raise LightClientError("bisection exceeded iteration bound")
+        except Exception as exc:
+            self._failed(span, exc)
+            raise
 
     async def _backwards(self, height: int, now: int, saved: Optional[Set[int]] = None) -> SignedHeader:
         """lite2/client.go:884 — walk the LastBlockID hash-chain down from

@@ -4,11 +4,17 @@ Reference parity: lite2/proxy/proxy.go + lite2/rpc/client.go (`tendermint
 lite`): every header/commit the proxy serves has been light-verified
 against the trust root; blocks are checked against their verified header
 before forwarding.
+
+`start_proxy` starts what `tendermint_tpu light` runs: the client, the
+verify engine the node runs too (crypto/engine.py, from a `[tpu]` config),
+and the proxy, all reporting to one flight recorder.
 """
 
 from __future__ import annotations
 
+import asyncio
 import json
+import time
 from typing import Optional
 
 from aiohttp import web
@@ -40,6 +46,11 @@ class LightProxy:
         self.log = get_logger("lite2.proxy")
         self._runner: Optional[web.AppRunner] = None
         self.listen_addr = ""
+        # set by start_proxy: what the client's spans and the engine report
+        # to, and the engine (None where [tpu] is off)
+        self.flight_recorder = None
+        self.batch_verifier = None
+        self.table_cache = None
 
     # -- verified handlers -------------------------------------------------
 
@@ -108,8 +119,11 @@ class LightProxy:
         self.log.info("light proxy listening", laddr=self.listen_addr)
 
     async def stop(self) -> None:
+        from ..crypto.engine import stop_engine
+
         if self._runner is not None:
             await self._runner.cleanup()
+        stop_engine(self.batch_verifier, self.table_cache)
 
     async def _dispatch(self, method: str, params: dict, req_id) -> dict:
         name = self.ROUTES.get(method)
@@ -154,7 +168,7 @@ class LightProxy:
         )
 
 
-async def run_proxy(
+async def start_proxy(
     chain_id: str,
     primary_addr: str,
     witness_addrs,
@@ -162,19 +176,48 @@ async def run_proxy(
     trust_height: int,
     trust_hash: bytes,
     trusting_period_s: float,
-) -> None:
-    """CLI entry (`light` command) — runs until cancelled."""
-    import asyncio
+    mode: str = BISECTION,
+    now_fn=time.time_ns,
+    tpu=None,
+) -> LightProxy:
+    """Build the client over the primary and its witnesses, start the verify
+    engine of `tpu` (a `config.TPUConfig`; default its defaults) on a flight
+    recorder of the proxy's own, and start the proxy; returns it with
+    `flight_recorder`, `batch_verifier` and `table_cache` set."""
+    from ..config import TPUConfig
+    from ..crypto.engine import start_engine
+    from ..libs.tracing import FlightRecorder
 
+    recorder = FlightRecorder()
     client = Client(
         chain_id,
         TrustOptions(int(trusting_period_s * 1e9), trust_height, trust_hash),
         HTTPProvider(chain_id, primary_addr),
         witnesses=[HTTPProvider(chain_id, w) for w in witness_addrs],
-        mode=BISECTION,
+        mode=mode,
+        now_fn=now_fn,
+        recorder=recorder,
     )
     proxy = LightProxy(client, laddr)
-    await proxy.start()
+    proxy.flight_recorder = recorder
+    proxy.batch_verifier, proxy.table_cache = start_engine(
+        tpu if tpu is not None else TPUConfig(), recorder, log=proxy.log
+    )
+    if proxy.batch_verifier is not None:
+        # no AsyncBatchVerifier here to switch it on, as the node's does
+        proxy.batch_verifier.start_warmup()  # compiles on its own thread; host path until warm
+    try:
+        await proxy.start()
+    except BaseException:
+        await proxy.stop()
+        raise
+    return proxy
+
+
+async def run_proxy(**settings) -> None:
+    """CLI entry (`light` command): start_proxy(**settings), then run until
+    cancelled."""
+    proxy = await start_proxy(**settings)
     try:
         while True:
             await asyncio.sleep(3600)

@@ -291,42 +291,14 @@ class Node(Service):
         # verify_commit in block validation) must already see the device
         # path.  This is the BASELINE north-star wiring: the node runs its
         # own engine, not the serial host fallback.
-        if cfg.tpu.enabled:
-            from .crypto.batch_verifier import AsyncBatchVerifier, BatchVerifier, TableCache
+        from .crypto.engine import start_engine
 
-            # Mesh probe ([tpu] mesh = auto|on|off, mesh_devices caps the
-            # shard count): sharding degrades to single-device — never a
-            # startup failure — and the decision (with the jax backend and
-            # device kind it found) is attributed right next to the
-            # host-crypto tier, so an operator can read one log line, and a
-            # tool one `verify.engine` event, and know which engine this
-            # node actually runs.
-            mesh, shards, mesh_reason = _crypto_backend.resolve_mesh(
-                cfg.tpu.mesh, cfg.tpu.mesh_devices
-            )
-            self.metrics_provider.verify.shards.set(shards)
-            engine = dict(
-                shards=shards,
-                mesh=mesh_reason,
-                host_tier=_crypto_backend.active_tier(),
-            )
-            self.log.info("verify engine", **engine)
-            self.flight_recorder.record(
-                "verify.engine",
-                ok=not mesh_reason.startswith(_crypto_backend.MESH_PROBE_FAILED),
-                **engine,
-            )
-            self.batch_verifier = BatchVerifier(
-                mesh=mesh,
-                min_device_batch=cfg.tpu.min_device_batch,
-                metrics=self.metrics_provider.verify,
-                recorder=self.flight_recorder,
-                chunk_size=cfg.tpu.chunk_size,
-                chunk_depth=cfg.tpu.chunk_depth,
-            ).install()
-            # steady-state commit path: per-valset device tables (HBM rows,
-            # replicated across the mesh)
-            self.table_cache = TableCache(self.batch_verifier).install()
+        self.batch_verifier, self.table_cache = start_engine(
+            cfg.tpu, self.flight_recorder, metrics=self.metrics_provider.verify, log=self.log
+        )
+        if self.batch_verifier is not None:
+            from .crypto.batch_verifier import AsyncBatchVerifier
+
             self.async_verifier = AsyncBatchVerifier(
                 self.batch_verifier,
                 max_batch=cfg.tpu.max_batch,
@@ -338,7 +310,7 @@ class Node(Service):
             if cfg.tpu.bls_jax_aggregation:
                 from .crypto.bls import scheme as _bls_scheme
 
-                _bls_scheme.set_jax_aggregation(True, mesh=mesh)
+                _bls_scheme.set_jax_aggregation(True, mesh=self.batch_verifier.mesh)
         # remote signer: wait for the external signer to dial in BEFORE
         # consensus needs a pubkey (node/node.go:612-618)
         if isinstance(self.priv_validator, Service) and not self.priv_validator.is_running:
@@ -934,18 +906,11 @@ class Node(Service):
             await self.priv_validator.stop()
         if self.async_verifier is not None:
             await self.async_verifier.stop()
-        if self.batch_verifier is not None:
-            from .crypto import batch as batch_hook
+        from .crypto.engine import stop_engine
 
-            # uninstall only if the process-wide hook is still ours — another
-            # live node may have installed its own engine meanwhile
-            if batch_hook.get_verifier() == self.batch_verifier.verify:
-                batch_hook.set_verifier(None)
-            if (
-                self.table_cache is not None
-                and batch_hook.get_indexed_verifier() == self.table_cache.verify_indexed
-            ):
-                batch_hook.set_indexed_verifier(None)
+        # uninstall only if the process-wide hooks are still ours: another
+        # live node may have installed its own engine meanwhile
+        stop_engine(self.batch_verifier, self.table_cache)
         if self.flight_spool is not None:
             # final synced flush AFTER everything above recorded its last
             # events; an orderly stop leaves a complete spool
