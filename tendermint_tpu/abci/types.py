@@ -28,6 +28,17 @@ class Event:
     attributes: List[dict] = field(default_factory=list)  # {"key": bytes, "value": bytes}
 
 
+def event_record(ev) -> Optional[dict]:
+    """`asdict(ev)` for an exact `Event`, built without its walk: the same
+    keys in the same order, the attribute list and each attribute dict copied,
+    their bytes and str shared (immutable).  None for anything else (a
+    subclass, or the plain dict of the handshake's stored responses), which
+    only `asdict` reads right."""
+    if type(ev) is not Event:
+        return None
+    return {"type": ev.type, "attributes": [dict(a) for a in ev.attributes]}
+
+
 @dataclass
 class ValidatorUpdate:
     pub_key_type: str = "ed25519"
@@ -274,6 +285,29 @@ class ResponseDeliverTx:
     @property
     def is_ok(self) -> bool:
         return self.code == CODE_TYPE_OK
+
+
+def deliver_tx_record(r) -> Optional[dict]:
+    """`asdict(r)` for an exact `ResponseDeliverTx` whose events are exact
+    `Event`s, read field by field in declared order: the same keys in the same
+    order, so the same bytes once encoded, and none of `r`'s lists or dicts
+    handed out.  None for anything else (`event_record`): the caller takes
+    `asdict`."""
+    if type(r) is not ResponseDeliverTx:
+        return None
+    events = [event_record(ev) for ev in r.events]
+    if None in events:
+        return None
+    return {
+        "code": r.code,
+        "data": r.data,
+        "log": r.log,
+        "info": r.info,
+        "gas_wanted": r.gas_wanted,
+        "gas_used": r.gas_used,
+        "events": events,
+        "codespace": r.codespace,
+    }
 
 
 @dataclass

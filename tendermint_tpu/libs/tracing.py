@@ -69,7 +69,11 @@ Event kinds currently emitted:
                       inside deliver_ms, as stamps alone, begin_block,
                       deliver_txs (the DeliverTx x n loop), end_block,
                       save_responses, update_state, mempool_lock (the
-                      mempool's lock and flush), abci_commit; with the in-process ABCI
+                      mempool's lock and flush), abci_commit; inside
+                      save_responses, responses_generic (how many DeliverTx
+                      responses were not of the exact classes and took
+                      asdict's walk: 0 from an app in process or behind a
+                      socket or gRPC client); with the in-process ABCI
                       client, app_ms (inside the app's handlers over the four
                       ABCI calls) and abci_overhead_ms (the four calls' stamps
                       less app_ms: the client's lock, awaits and request and
@@ -774,16 +778,17 @@ def format_budget(budget: Optional[dict]) -> str:
 #: time and the client's inside deliver_ms, validate_block's inside validate_ms
 #: (commit_hashes and set_hashes are counts of roots built, not milliseconds),
 #: the store's counts of encodings made (commit_encodes inside store_ms,
-#: set_encodes inside save_state_ms), the two commit verifications inside
-#: verify_ms and validate_ms, the engine's calls inside those, and what the
-#: receive path measured before the block was queued.
+#: set_encodes inside save_state_ms) and of the responses the save walked
+#: with asdict (responses_generic inside deliver_ms), the two commit
+#: verifications inside verify_ms and validate_ms, the engine's calls inside
+#: those, and what the receive path measured before the block was queued.
 REPLAY_ROWS = (
     ("fastsync.block", ("wait_ms", "parts_ms", "verify_ms", "store_ms", "apply_ms")),
     ("fastsync.block", ("validate_ms", "abci_req_ms", "deliver_ms", "mempool_ms",
                         "save_state_ms", "events_ms")),
     ("fastsync.block", ("app_ms", "abci_overhead_ms")),
     ("fastsync.block", ("basic_ms", "commit_hashes", "set_hash_ms", "set_hashes", "median_ms")),
-    ("fastsync.block", ("commit_encodes", "set_encodes")),
+    ("fastsync.block", ("commit_encodes", "set_encodes", "responses_generic")),
     ("verify.commit", ("sign_bytes_ms", "engine_ms", "tally_ms")),
     ("verify.dispatch", ("rows_ms", "host_prep_ms", "pack_ms", "launch_ms", "put_ms",
                          "fetch_ms")),

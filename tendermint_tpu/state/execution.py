@@ -7,7 +7,7 @@ updateState:384, fireEvents:449, ExecCommitBlock:488).
 
 from __future__ import annotations
 
-from dataclasses import replace
+from dataclasses import asdict, replace
 from typing import List, Optional, Tuple
 
 from ..abci import types as abci
@@ -357,11 +357,24 @@ def _abci_events_to_map(events: List[abci.Event]) -> dict:
 
 
 def _responses_to_dict(responses: dict) -> dict:
-    from dataclasses import asdict
-
+    """The block's ABCI responses as `save_abci_responses` stores them, each
+    what `asdict` gives.  A DeliverTx response is read field by field
+    (`abci.deliver_tx_record`); one that is not of the exact classes takes
+    `asdict`'s walk and is counted as `responses_generic` on the open span: 0
+    from an app in process or behind a socket or gRPC client, all of them from
+    the handshake's stored responses (events as plain dicts)."""
+    deliver_txs = []
+    generic = 0
+    for r in responses["deliver_txs"]:
+        record = abci.deliver_tx_record(r)
+        if record is None:
+            record = asdict(r)
+            generic += 1
+        deliver_txs.append(record)
+    tracing.annotate(responses_generic=generic)
     return {
         "begin_block": asdict(responses["begin_block"]),
-        "deliver_txs": [asdict(r) for r in responses["deliver_txs"]],
+        "deliver_txs": deliver_txs,
         "end_block": asdict(responses["end_block"]),
     }
 

@@ -609,10 +609,11 @@ class TestReplayStamps:
             setattr(app, name, timed)
         return spent
 
-    async def _replayed(self, slow_ms=0.0):
+    async def _replayed(self, slow_ms=0.0, update_ms=0.0):
         """Five blocks through `_try_sync` on a kvstore whose DeliverTx takes
-        `slow_ms` more; the blocks' events, each with what the app's own
-        clock and the client's counter read for it."""
+        `slow_ms` more, and a mempool whose update takes `update_ms`; the
+        blocks' events, each with what the app's own clock and the client's
+        counter read for it."""
         import time as _time
 
         from tendermint_tpu.abci.client import LocalClient
@@ -639,7 +640,13 @@ class TestReplayStamps:
             return counted[-1][0]
 
         client.app_lap = lap
-        ex = BlockExecutor(StateStore(MemDB()), client, NopMempool())
+        mempool = NopMempool()
+
+        async def update(*a, **kw):
+            _time.sleep(update_ms / 1e3)
+
+        mempool.update = update
+        ex = BlockExecutor(StateStore(MemDB()), client, mempool)
         reactor = await TestSetHashStage()._try_sync(pvs, wire, ex)
         events = [e for e in reactor.recorder.events() if e["kind"] == "fastsync.block"]
         return events, counted[1::2]  # the second of the two reads a block: at its end
@@ -682,14 +689,23 @@ class TestReplayStamps:
     async def test_deliver_ms_runs_from_beginblocks_call_to_commits_return(self):
         """The same interval as before the stamps: from where BeginBlock's
         request was built to Commit's return; the two laps it is taken in
-        meet at EndBlock's return."""
-        events, _ = await self._replayed()
+        meet at EndBlock's return.  Each lap ends at the return it names:
+        after it, and with no stamp begun from that return to the lap's end
+        (the clock read between them may be some microseconds later on a
+        loaded host; the stage after it would begin at the return itself).
+        The mempool's update, 2 ms here, lies after the second lap."""
+        events, _ = await self._replayed(update_ms=2.0)
         for ev in events:
             by = {n: (s, e) for n, s, e in ev["stamps"]}
             first, second = [(s, e) for n, s, e in ev["stamps"] if n == "deliver_ms"]
+            starts = [s for _, s, _ in ev["stamps"]]
             assert first[0] == by["abci_req_ms"][1] == by["begin_block"][0]
-            assert 0 <= first[1] - by["end_block"][1] <= 2 and second[0] == first[1]
-            assert 0 <= second[1] - by["abci_commit"][1] <= 2
+            assert 0 <= first[1] - by["end_block"][1] and second[0] == first[1]
+            assert not [s for s in starts if by["end_block"][1] <= s < first[1]]
+            assert 0 <= second[1] - by["abci_commit"][1]
+            assert not [s for s in starts if by["abci_commit"][1] <= s < second[1]]
+            assert by["mempool_ms"][0] == second[1]
+            assert by["mempool_ms"][1] - by["mempool_ms"][0] >= 2000 - 1
             assert ev["deliver_ms"] == pytest.approx((second[1] - first[0]) / 1e3, abs=0.004)
 
     @pytest.mark.parametrize("slow_ms", [0.0, 2.0])
