@@ -776,7 +776,26 @@ class ConsensusReactor(Reactor):
     async def _receive_vote_batch(self, peer, ps: PeerRoundState, msg: dict) -> None:
         """Decode a byte-capped vote_batch and verify it as ONE
         AsyncBatchVerifier call — the receive-side half of the batched
-        gossip path (one flush, one host-prep pass for the whole frame)."""
+        gossip path (one flush, one host-prep pass for the whole frame).
+
+        Each frame that decodes to votes leaves one `consensus.vote_batch`
+        span, id = their height: decode_ms (decode, validate_basic, dedup,
+        resolve and sign-bytes), verify_ms, add_ms, n (fresh votes verified),
+        dup; ok=False on a frame refused after it decoded.  The span times
+        the frame and holds nothing: this task leaves it at once, so the
+        events recorded meanwhile (gossip.vote_batch_recv) keep their own
+        fields."""
+        span = self.cs.recorder.begin("consensus.vote_batch", n=0, dup=0)
+        span.leave()
+        try:
+            await self._take_vote_batch(peer, ps, msg, span)
+        finally:
+            if span.id is None:
+                span.drop()  # no votes decoded
+            else:
+                span.end()
+
+    async def _take_vote_batch(self, peer, ps: PeerRoundState, msg: dict, span) -> None:
         blobs = msg.get("votes")
         if not isinstance(blobs, list) or len(blobs) > MAX_VOTE_BATCH_ENTRIES:
             await self.switch.stop_peer_for_error(peer, "malformed vote_batch")
@@ -794,6 +813,8 @@ class ConsensusReactor(Reactor):
             votes.append(vote)
         if not votes:
             return
+        span.id = votes[0].height
+        span.set(dup=len(votes))
         hp = self._trace_recv("vote_batch", peer, msg, votes[0].height)
         if hp is not None:
             # per-vote content hop: our own relay of these votes stamps
@@ -809,6 +830,7 @@ class ConsensusReactor(Reactor):
                 height, round_, vtype = int(msg["h"]), int(msg["r"]), int(msg["t"])
                 theirs = BitArray.from_bytes(have)
             except Exception:
+                span.set(ok=False)
                 await self.switch.stop_peer_for_error(peer, "malformed vote_batch have")
                 return
             n_vals = self._num_validators(height)
@@ -834,11 +856,14 @@ class ConsensusReactor(Reactor):
             if resolved is None:
                 continue  # height not resolvable against known sets; drop
             if resolved is False:
+                span.set(ok=False)
                 await self.switch.stop_peer_for_error(
                     peer, "vote validator address mismatch in batch"
                 )
                 return
             keep.append((vote, *resolved))
+        span.set(n=len(keep), dup=len(votes) - len(keep))
+        span.lap("decode_ms")
         if not keep:
             return
         # provenance: the relay hop (peer) plus fresh-vs-already-held
@@ -870,14 +895,18 @@ class ConsensusReactor(Reactor):
                         *self.async_verifier.verify_many(entries)
                     )
             except Exception:
+                span.set(ok=False)
                 return
             for (i, _, _, _), ok in zip(engine, res):
                 results[i] = bool(ok)
+        span.lap("verify_ms")
         if not all(results):
+            span.set(ok=False)
             await self.switch.stop_peer_for_error(peer, "invalid vote signature in batch")
             return
         for vote, _, _ in keep:
             await self.cs.add_vote_input(vote, peer.id, verified=True)
+        span.lap("add_ms")
 
     async def _handle_vote_set_maj23(self, peer, msg: dict) -> None:
         """reactor.go:258 — record peer claim, respond with our bits."""

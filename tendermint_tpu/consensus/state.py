@@ -147,6 +147,8 @@ class ConsensusState(Service):
 
         self.metrics = ConsensusMetrics()  # nop; node swaps in prometheus
         self.recorder = tracing.NOP  # node swaps in its FlightRecorder
+        # the `consensus.height` span of rs.height (_open_height_span)
+        self._height_span: Optional[tracing.Span] = None
         self._total_txs = 0
         # Pluggable time source (chaos/clock.py): every wall-clock and
         # monotonic read in the state machine goes through this object, so
@@ -352,6 +354,8 @@ class ConsensusState(Service):
 
     async def _receive_routine(self) -> None:
         """state.go:602 — the single serialization point."""
+        if self._height_span is None:
+            self._open_height_span()
         try:
             while True:
                 # Queue.get returns without yielding when non-empty; the loop
@@ -368,9 +372,10 @@ class ConsensusState(Service):
                     kind = mi["type"]
                     if kind == "timeout":
                         ti: TimeoutInfo = mi["ti"]
-                        self.wal.write(
+                        self._walled(
+                            self.wal.write,
                             {"type": "timeout", "height": ti.height, "round": ti.round,
-                             "step": ti.step, "duration": ti.duration}
+                             "step": ti.step, "duration": ti.duration},
                         )
                         await self._handle_timeout(ti)
                     elif kind == "txs_available":
@@ -379,11 +384,11 @@ class ConsensusState(Service):
                         internal = not mi.get("peer_id")
                         wal_rec = {"type": "msg", "peer_id": mi.get("peer_id", ""), "msg": _wire_msg(mi)}
                         if internal:
-                            self.wal.write_sync(wal_rec)  # own msgs fsync (state.go:650)
+                            self._walled(self.wal.write_sync, wal_rec)  # own msgs fsync (state.go:650)
                             if kind == "vote":
                                 fail_point("own-vote-walled")
                         else:
-                            self.wal.write(wal_rec)
+                            self._walled(self.wal.write, wal_rec)
                         await self._handle_msg(mi)
         except asyncio.CancelledError:
             raise
@@ -413,6 +418,39 @@ class ConsensusState(Service):
             except OSError:
                 pass  # a dying disk may refuse even the close flush
             self._done.set()
+
+    def _walled(self, write, record: dict) -> None:
+        """WAL one input before it is handled; the write's time is the
+        height's (`wal_ms` on its span)."""
+        t0 = time.monotonic_ns()
+        write(record)
+        span = self._height_span
+        if span is not None:
+            span.fields["wal_ms"] += (time.monotonic_ns() - t0) / 1e6
+
+    def _open_height_span(self) -> None:
+        """One `consensus.height` span for each height entered, `id` the
+        height, from its entry to the moment its block is applied (NewBlock
+        published).  It is opened in the receive routine's task, so the
+        block's commit checks are its children and apply_block's stages land
+        in it (tracing.annotate), on the pipelined delivery lane too: that
+        task copies this one's context.  Laps, in the order they come:
+        `votes_ms` (+2/3 precommits for a block), `parts_ms` (the proposal
+        block complete), `apply_ms`; fields `votes_added` (this height's) and
+        `wal_ms` (the WAL writes of its inputs)."""
+        self._height_span = self.recorder.begin(
+            "consensus.height", id=self.rs.height, votes_added=0, wal_ms=0.0
+        )
+
+    def _lap(self, name: str) -> None:
+        if self._height_span is not None:
+            self._height_span.lap(name)
+
+    @staticmethod
+    def _end_height_span(span) -> None:
+        if span is not None:
+            span.lap("apply_ms")
+            span.end()
 
     def _storage_halt(self, err: OSError) -> None:
         kind = errno.errorcode.get(err.errno, "OSError") if err.errno else "OSError"
@@ -874,6 +912,7 @@ class ConsensusState(Service):
         if rs.height != height or rs.step >= RoundStep.COMMIT:
             return
         self.log.debug("enterCommit", height=height, commit_round=commit_round)
+        self._lap("votes_ms")
         try:
             block_id, ok = rs.votes.precommits(commit_round).two_thirds_majority()
             if not ok:
@@ -1038,6 +1077,7 @@ class ConsensusState(Service):
         state_copy = self.sm_state.copy()
         bid = BlockID(block.hash(), block_parts.header())
         self.recorder.record("deliver.start", height=block.height)
+        span, self._height_span = self._height_span, None
 
         if not self.config.pipeline_delivery or self.replay_mode:
             # serial path (A/B off switch + WAL replay): the reference's
@@ -1046,9 +1086,13 @@ class ConsensusState(Service):
                 state_copy, bid, block
             )
             self.recorder.record("deliver.end", height=block.height)
+            self._end_height_span(span)
             fail_point("finalize-applied")
             self._prune_if_requested(retain_height)
             self.update_to_state(new_state)
+            if not self.replay_mode:
+                self._open_height_span()
+            await self._new_step()
             self.schedule_round0()
             return
 
@@ -1062,12 +1106,16 @@ class ConsensusState(Service):
         provisional = provisional_next_state(state_copy, bid, block)
         self._delivery_height = block.height
         self._delivery_task = self.spawn(
-            self._deliver_block(state_copy, bid, block), "deliver"
+            self._deliver_block(state_copy, bid, block, span), "deliver"
         )
+        if span is not None:
+            span.leave()  # the lane ends it; the next height's is no child of it
         self.update_to_state(provisional)
+        self._open_height_span()
+        await self._new_step()
         self.schedule_round0()
 
-    async def _deliver_block(self, state_copy, block_id, block) -> tuple:
+    async def _deliver_block(self, state_copy, block_id, block, span=None) -> tuple:
         """The pipelined delivery lane: apply_block (begin/deliver_tx/
         end/commit + state save + event publication) off the receive
         routine.  Resolves to a ("ok"|"err", payload) pair instead of
@@ -1083,6 +1131,7 @@ class ConsensusState(Service):
         except BaseException as e:
             return ("err", e)
         self.recorder.record("deliver.end", height=block.height)
+        self._end_height_span(span)
         fail_point("finalize-applied")
         if self.config.pipeline_speculative_assembly:
             self._speculate_proposal(new_state)
@@ -1268,6 +1317,7 @@ class ConsensusState(Service):
                 )
                 raise PartSetError(f"proposal block does not decode: {e!r}") from e
             rs.proposal_block = block
+            self._lap("parts_ms")
             # cross-node timeline: when THIS node first held the whole
             # proposal — the per-node part-coverage point tracemerge
             # aggregates into coverage p50/p90 across the net
@@ -1363,6 +1413,8 @@ class ConsensusState(Service):
         added = rs.votes.add_vote(vote, peer_id, verify=not verified)
         if not added:
             return False
+        if self._height_span is not None:
+            self._height_span.fields["votes_added"] += 1
         await self._publish_vote(vote)
 
         if vote.type == PREVOTE_TYPE:

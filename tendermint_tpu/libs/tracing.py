@@ -20,6 +20,17 @@ Event kinds currently emitted:
                                                fully assembled on this node
                                                (src delivered the last part)
     commit            height, txs, block       block finalized (hash prefix)
+    consensus.height  SPAN, one per height this node enters and commits, id =
+                      height: from its entry (update_to_state) to its block
+                      applied (NewBlock published), on the pipelined delivery
+                      lane too.  Laps in the order they come: votes_ms (to
+                      +2/3 precommits for a block: enterCommit), parts_ms (to
+                      the proposal block complete), apply_ms (to apply_block's
+                      return); votes_added (votes added to this height's
+                      sets), wal_ms (the WAL writes of its inputs); and what
+                      apply_block hands fastsync.block (validate_ms,
+                      deliver_ms, ... and their stamps).  The verify.commit
+                      spans of its block's checks open under it
   verify engine (crypto/batch_verifier.py):
     verify.enqueue    pending                  vote entered the batcher
     verify.enqueue_batch  n, pending           whole vote_batch entered as one arrival
@@ -132,6 +143,13 @@ Event kinds currently emitted:
     gossip.votes      mode, n, bytes, peer     vote send: mode batch|single
     gossip.vote_batch_recv  n, dup, peer       decoded batch entered the verifier
                                                (n fresh votes, dup already-held)
+    consensus.vote_batch  SPAN, one per vote_batch frame received that
+                      decodes to votes, id = their height: n (fresh votes
+                      sent to the engine), dup, decode_ms (decode,
+                      validate_basic, dedup, resolve and sign-bytes),
+                      verify_ms (the engine's call, or the keys' own verify),
+                      add_ms (the add_vote_input loop); ok=False on a frame
+                      refused after it decoded.  Nothing nests in it
     gossip.part_burst n, peer[, catchup]       block parts sent in one burst
     gossip.hop        frame, peer, origin, hop[, h, lat_ms, clamped]
                                                wire-level trace context decoded
@@ -256,10 +274,11 @@ def live_recorders() -> List["FlightRecorder"]:
 
 
 _monotonic_ns = time.monotonic_ns  # the stopwatch's one clock read, bound once
-#: the spans whose event carries its `stamps`: a replay block, a light
-#: client's header and their commit checks, which benchmarks/stages.py files
-#: device idle time under; every other span keeps its laps as fields alone
-STAMPED_KINDS = frozenset(("fastsync.block", "lite.header", "verify.commit"))
+#: the spans whose event carries its `stamps`: a replay block, a height
+#: committed by consensus, a light client's header and their commit checks,
+#: which benchmarks/stages.py files device idle time under; every other span
+#: keeps its laps as fields alone
+STAMPED_KINDS = frozenset(("fastsync.block", "consensus.height", "lite.header", "verify.commit"))
 _TRACE_ANNOTATION = None  # jax.profiler.TraceAnnotation, once jax has been imported
 
 
@@ -372,6 +391,13 @@ class Span(Laps):
 
     def drop(self) -> None:
         self._close()
+
+    def leave(self) -> None:
+        """This task stops being inside the span, which stays open for the
+        task it was handed to (one that copied this task's context while the
+        span was current): a span opened here next is no child of it."""
+        if _CURRENT.get() is self:
+            _CURRENT.set(self._outer)
 
     def __enter__(self) -> "Span":
         return self

@@ -309,6 +309,47 @@ class TestSpans:
         assert abs(start_ns - (block["t_ns"] - block["dur_ns"])) < 1e6
 
 
+class TestSpanHandedOver:
+    """`Span.leave`: a task hands an open span to a task it starts (which
+    copies its context) and goes on outside it (consensus.height on the
+    pipelined delivery lane)."""
+
+    async def test_the_lane_ends_it_and_what_this_task_opens_next_is_a_root(self):
+        r = FlightRecorder(size=64)
+        first = r.begin("consensus.height", id=5)
+
+        async def lane():
+            await asyncio.sleep(0.005)
+            with tracing.child_span("verify.commit", height=4):
+                pass
+            tracing.annotate(deliver_ms=1.5)
+            first.end()
+
+        task = asyncio.get_running_loop().create_task(lane())
+        first.leave()
+        assert tracing.current_span() is None
+        second = r.begin("consensus.height", id=6)
+        r.record("step", height=6)
+        await task
+        second.end()
+        by_kind = [(ev["kind"], ev["id"], ev["parent"]) for ev in r.events()]
+        assert ("step", 6, "consensus.height") in by_kind
+        assert ("verify.commit", 5, "consensus.height") in by_kind
+        heights = {ev["id"]: ev for ev in r.events() if ev["kind"] == "consensus.height"}
+        assert heights[5]["parent"] is None and heights[6]["parent"] is None
+        assert heights[5]["deliver_ms"] == 1.5 and "deliver_ms" not in heights[6]
+
+    def test_a_span_left_at_once_times_its_laps_and_holds_no_event(self):
+        r = FlightRecorder(size=64)
+        span = r.begin("consensus.vote_batch", n=0)
+        span.leave()
+        r.record("gossip.vote_batch_recv", n=3)
+        span.lap("decode_ms")
+        span.end()
+        recv, frame = r.events()
+        assert "parent" not in recv and frame["parent"] is None and "decode_ms" in frame
+
+
 class TestSampling:
     def test_one_in_n_with_factor_recorded(self):
         r = FlightRecorder(size=256, sample_high_rate=4)
